@@ -109,6 +109,8 @@ def run(out_path: str = "BENCH_engine.json") -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_engine.json")
     run(ap.parse_args().out)

@@ -202,6 +202,8 @@ def run(out_path: str = "BENCH_pan.json") -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_pan.json")
     run(ap.parse_args().out)

@@ -84,14 +84,19 @@ def run(out_path: str = "BENCH_ring.json") -> dict:
                      "cps": r.cps,
                      "traces_after_2nd_bucket_search": eng.stats.traces})
 
+    dev = jax.devices()[0]
     result = {
         "shape": {"n": N, "s": S, "k": K},
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": avail},
         "devices_available": avail,
         "backend": eng.backend,
         "runs": rows,
     }
 
-    tab = BenchTable(f"ring engine (n={N}, s={S}, k={K})",
+    tab = BenchTable(f"ring engine (n={N}, s={S}, k={K}) on "
+                     f"{avail} {dev.platform} device(s) "
+                     f"({dev.device_kind})",
                      ["plan", "ndev", "cold_s", "warm_s",
                       "tile_lanes", "traces"])
     for row in rows:
@@ -106,6 +111,8 @@ def run(out_path: str = "BENCH_ring.json") -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_ring.json")
     run(ap.parse_args().out)

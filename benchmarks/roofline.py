@@ -222,4 +222,6 @@ def run(small: bool = True) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

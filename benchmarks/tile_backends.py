@@ -85,6 +85,8 @@ def run(small: bool = True, out_path: str = "BENCH_tiles.json") -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="sweep all shapes (slower)")

@@ -28,7 +28,8 @@ from repro.core import DiscordEngine, SearchSpec             # noqa: E402
 from repro.data import ecg_like, with_implanted_anomalies    # noqa: E402
 
 ndev = len(jax.devices())
-print(f"devices: {ndev}")
+print(f"devices: {ndev} x {jax.devices()[0].platform} "
+      f"({jax.devices()[0].device_kind})")
 x, planted = with_implanted_anomalies(
     ecg_like(20_000, period=160, noise=0.03, seed=3),
     n_anomalies=3, length=128, amp=0.6, seed=3)

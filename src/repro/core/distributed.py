@@ -47,15 +47,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
+from ..kernels.common import default_interpret
 from ..parallel.sharding import SERIES_AXIS as AXIS, series_mesh
 from .result import DiscordResult
 from .tiles import (TileBlock, resolve_backend, tile_d2, tile_mins,
                     topk_nonoverlapping)
-
-# older jax has no lax.pvary (newer strict-replication checker needs it)
-_pvary = getattr(lax, "pvary", lambda x, axes: x)
 
 #: legacy name of :func:`repro.parallel.sharding.series_mesh`
 data_mesh = series_mesh
@@ -93,34 +90,40 @@ def _pack_blocks(series: np.ndarray, s: int, ndev: int):
 # ----------------------------------------------------------------------
 # 1) ring matrix profile
 # ----------------------------------------------------------------------
-def _ring_mp_shard(qwin, qmu, qsig, qid, s: int, n: int, ndev: int,
-                   backend: str):
-    """Per-shard body: local queries fixed; candidates orbit the ring."""
-    me = lax.axis_index(AXIS)
+def _ring_row_mins(q, c, s: int, n: int, backend: str, block: int):
+    """Row (min d2, argmin id) of the local query set ``q`` against
+    the visiting candidate set ``c``.  On ``pallas`` each set is
+    ``(chunks, mu, sig, ids)`` for the mpblock kernel, which builds
+    the window tiles in VMEM; elsewhere ``(windows, mu, sig, ids)``
+    through the tile registry."""
+    if backend == "pallas":
+        from ..kernels.mpblock.kernel import mp_block_pallas
+        return mp_block_pallas(*q, *c, s=s, n_valid=n, block=block,
+                               interpret=default_interpret())
+    m = tile_mins(_tile_d2(*q, *c, s, n, backend), q[3], c[3])
+    return m.row_min, m.row_arg
+
+
+def _ring_mp_shard(qset, qmu, qsig, qid, s: int, n: int, ndev: int,
+                   backend: str, block: int):
+    """Per-shard body: local queries fixed; candidates orbit the ring.
+    ``qset`` is the shard's chunks on ``pallas`` and its windows
+    elsewhere (see :func:`_ring_row_mins`)."""
+    q = (qset, qmu, qsig, qid)
     perm = [(i, (i + 1) % ndev) for i in range(ndev)]
 
     def hop(carry, _):
-        cwin, cmu, csig, cid, best, barg = carry
-        d2 = _tile_d2(qwin, qmu, qsig, qid, cwin, cmu, csig, cid, s, n,
-                      backend)
-        m = tile_mins(d2, qid, cid)        # col outputs DCE'd, unused
-        tmin, targ = m.row_min, m.row_arg
+        c, best, barg = carry
+        tmin, targ = _ring_row_mins(q, c, s, n, backend, block)
         take = tmin < best
         best = jnp.where(take, tmin, best)
         barg = jnp.where(take, targ, barg)
-        cwin = lax.ppermute(cwin, AXIS, perm)
-        cmu = lax.ppermute(cmu, AXIS, perm)
-        csig = lax.ppermute(csig, AXIS, perm)
-        cid = lax.ppermute(cid, AXIS, perm)
-        return (cwin, cmu, csig, cid, best, barg), None
+        c = tuple(lax.ppermute(a, AXIS, perm) for a in c)
+        return (c, best, barg), None
 
-    init = (qwin, qmu, qsig, qid,
-            _pvary(jnp.full(qwin.shape[0], jnp.inf, jnp.float32),
-                   (AXIS,)),
-            _pvary(jnp.full(qwin.shape[0], -1, jnp.int32), (AXIS,)))
-    (_w, _mu, _sg, _id, best, barg), _ = lax.scan(hop, init, None,
-                                                  length=ndev)
-    del _w, _mu, _sg, _id, me
+    init = (q, jnp.full(qid.shape[0], jnp.inf, jnp.float32),
+            jnp.full(qid.shape[0], -1, jnp.int32))
+    (_c, best, barg), _ = lax.scan(hop, init, None, length=ndev)
     return best, barg
 
 
@@ -177,10 +180,9 @@ def _drag_shard(qwin, qmu, qsig, qid, r: float, s: int, n: int,
         return (cwin, cmu, csig, cid, best, barg, alive), None
 
     init = (qwin, qmu, qsig, qid,
-            _pvary(jnp.full(qwin.shape[0], jnp.inf, jnp.float32),
-                   (AXIS,)),
-            _pvary(jnp.full(qwin.shape[0], -1, jnp.int32), (AXIS,)),
-            _pvary(jnp.ones(qwin.shape[0], bool), (AXIS,)))
+            jnp.full(qwin.shape[0], jnp.inf, jnp.float32),
+            jnp.full(qwin.shape[0], -1, jnp.int32),
+            jnp.ones(qwin.shape[0], bool))
     carry, _ = lax.scan(hop, init, None, length=ndev)
     _, _, _, _, best, barg, alive = carry
     return best, barg, alive
@@ -218,10 +220,10 @@ def drag_discords(series, s: int, k: int = 1, *, r: Optional[float] = None,
         # the alive set fits) — the shard body is a new closure each
         # round, so no engine plan cache can hold it.
         # analysis: ignore[untracked-jit]
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(AXIS, None), P(AXIS), P(AXIS), P(AXIS)),
-            out_specs=(P(AXIS), P(AXIS), P(AXIS)), check_rep=False))
+            out_specs=(P(AXIS), P(AXIS), P(AXIS)), check_vma=False))
         d2, arg, alive = f(*args)
         d = np.sqrt(np.asarray(d2)[:n])
         alive = np.asarray(alive)[:n]

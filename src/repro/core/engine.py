@@ -688,15 +688,14 @@ class DiscordEngine:
             (d2 (2, block), ngh).
 
         Exact f32 re-sweep of a *pair* of query blocks against every
-        candidate — the same ``TileEngine`` block row the
-        ``("profile", ...)`` plan's ``lax.map`` body computes, re-run
-        verbatim so refined rows are bit-identical to a full profile
-        sweep's.  The block starts are traced operands
-        (``contiguous_block`` slices dynamically), so one compiled
-        plan refines any pair: zero retraces across the escalation
-        loop.  The fixed trip count of 2 is load-bearing: XLA unrolls
-        trip-count-1 loops into the enclosing computation and re-fuses
-        the math into ulp-different results (observed in raw mode),
+        candidate — ``TileEngine.block_rows``, the computation the
+        ``("profile", ...)`` plan runs over all blocks, so refined rows
+        are bit-identical to a full profile sweep's.  The block starts
+        are traced operands, so one compiled plan refines any pair:
+        zero retraces across the escalation loop.  The fixed trip count
+        of 2 is load-bearing: XLA unrolls trip-count-1 loops into the
+        enclosing computation and re-fuses the math into ulp-different
+        results (observed in raw mode),
         while any preserved loop compiles the shared scan body
         identically — callers duplicate a start to pad odd refinement
         sets, and buckets with fewer than two blocks take the exact
@@ -710,15 +709,7 @@ class DiscordEngine:
                 eng = TileEngine(series_pad, s, block=spec.block,
                                  backend=be, znorm=spec.znorm,
                                  n_valid=n_valid)
-                cand = eng.all_windows()
-
-                def one_block(b):
-                    q = eng.contiguous_block(b)
-                    d2 = eng.d2(q, cand)
-                    return (jnp.min(d2, axis=1),
-                            jnp.argmin(d2, axis=1).astype(jnp.int32))
-
-                return lax.map(one_block, b2)
+                return eng.block_rows(b2)
             return fn
         return self._get_plan(("qsweep_refine", s, Lb), build)
 
@@ -742,18 +733,17 @@ class DiscordEngine:
         body = self._qsweep_bound_body(s)
 
         def build():
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from .distributed import AXIS
 
             def shard_body(starts, series_pad, n_valid):
                 return body(series_pad, n_valid[0], starts)
 
-            sweep = shard_map(
+            sweep = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(P(AXIS), P(None), P(None)),
                 out_specs=(P(AXIS, None), P(AXIS, None)),
-                check_rep=False)
+                check_vma=False)
 
             def fn(series_pad, n_valid):
                 self.stats.traces += 1
@@ -858,16 +848,24 @@ class DiscordEngine:
         and ``n_sh = per * ndev`` the mesh-wide padded count."""
         return plan_shard_geom(s, Lb, self.spec.block, ndev)
 
-    def _sharded_blocks(self, eng: TileEngine, n_pad: int, n_sh: int):
+    def _sharded_blocks(self, eng: TileEngine, n_pad: int, n_sh: int,
+                        chunked: bool = False):
         """All (bucket-padded) windows of ``eng``, further padded to
         the mesh-wide count ``n_sh`` with masked lanes (ids -1) so the
-        per-device shards split evenly and stay block-aligned."""
-        blk = eng.all_windows()          # padding ids already masked
+        per-device shards split evenly and stay block-aligned.
+        ``chunked`` carries the windows as the mpblock kernel's
+        per-block series chunks instead of materialized rows."""
         pad = n_sh - n_pad
-        return (jnp.pad(blk.win, ((0, pad), (0, 0))),
-                jnp.pad(blk.mu, (0, pad)),
-                jnp.pad(blk.sig, (0, pad), constant_values=1.0),
-                jnp.pad(blk.ids, (0, pad), constant_values=-1))
+        if chunked:
+            first = jnp.pad(eng.block_chunks(),
+                            ((0, pad // eng.block), (0, 0)))
+        else:
+            first = jnp.pad(eng.all_windows().win, ((0, pad), (0, 0)))
+        ids = eng._mask_ids(jnp.arange(n_pad, dtype=jnp.int32))
+        return (first,
+                jnp.pad(eng.mu_pad, (0, pad)),
+                jnp.pad(eng.sig_pad, (0, pad), constant_values=1.0),
+                jnp.pad(ids, (0, pad), constant_values=-1))
 
     def _ring_plan(self, s: int, Lb: int):
         """(series_pad (Lb,), n_valid) -> (d2 (n_sh,), neighbor).
@@ -887,23 +885,24 @@ class DiscordEngine:
         n_pad, per, n_sh = self._shard_geom(s, Lb, ndev)
 
         def build():
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from .distributed import AXIS, _ring_mp_shard
 
             body = functools.partial(_ring_mp_shard, s=s, n=n_sh,
-                                     ndev=ndev, backend=be)
-            sweep = shard_map(
+                                     ndev=ndev, backend=be,
+                                     block=spec.block)
+            sweep = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(AXIS, None), P(AXIS), P(AXIS), P(AXIS)),
-                out_specs=(P(AXIS), P(AXIS)), check_rep=False)
+                out_specs=(P(AXIS), P(AXIS)), check_vma=False)
 
             def fn(series_pad, n_valid):
                 self.stats.traces += 1
                 eng = TileEngine(series_pad, s, block=spec.block,
                                  backend=be, znorm=spec.znorm,
                                  n_valid=n_valid)
-                return sweep(*self._sharded_blocks(eng, n_pad, n_sh))
+                return sweep(*self._sharded_blocks(
+                    eng, n_pad, n_sh, chunked=be == "pallas"))
             return fn
         return self._get_plan(("ring", s, Lb, (ndev,)), build)
 
@@ -919,18 +918,17 @@ class DiscordEngine:
         ndev = int(mesh.devices.size)
 
         def build():
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from .distributed import AXIS
 
             def shard_body(sub, n_valid):
                 return self._profile_each(s, sub, n_valid[0])
 
-            sweep = shard_map(
+            sweep = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(P(AXIS, None), P(None)),
                 out_specs=(P(AXIS, None), P(AXIS, None)),
-                check_rep=False)
+                check_vma=False)
 
             def fn(stack, n_valid):
                 self.stats.traces += 1
@@ -953,7 +951,6 @@ class DiscordEngine:
         n_pad, per, n_sh = self._shard_geom(s, Lb, ndev)
 
         def build():
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from .distributed import AXIS, _tile_d2
 
@@ -965,13 +962,13 @@ class DiscordEngine:
                         jnp.min(d2, axis=0),
                         qid[jnp.argmin(d2, axis=0)])
 
-            sweep = shard_map(
+            sweep = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(P(None, None), P(None), P(None), P(None),
                           P(AXIS, None), P(AXIS), P(AXIS), P(AXIS)),
                 out_specs=(P(AXIS, None), P(AXIS, None),
                            P(AXIS), P(AXIS)),
-                check_rep=False)
+                check_vma=False)
 
             def fn(series_pad, q0, n_valid):
                 self.stats.traces += 1
@@ -1012,7 +1009,6 @@ class DiscordEngine:
         n_pad, nb_p = self._pan_row_geom(ladder, Lb, ndev)
 
         def build():
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from .distributed import AXIS
 
@@ -1022,11 +1018,11 @@ class DiscordEngine:
                                  n_valid=n_valid0[0])
                 return peng.rows(starts)
 
-            sweep = shard_map(
+            sweep = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(P(AXIS), P(None), P(None)),
                 out_specs=(P(AXIS, None, None), P(AXIS, None, None)),
-                check_rep=False)
+                check_vma=False)
 
             def fn(series_pad, n_valid0):
                 self.stats.traces += 1
@@ -1112,7 +1108,6 @@ class DiscordEngine:
         n_pad, per, n_sh = self._shard_geom(ladder[0], Lb, ndev)
 
         def build():
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from .distributed import AXIS
 
@@ -1125,12 +1120,12 @@ class DiscordEngine:
                 rd2, rng, cd2, cng = peng.tail(qids, dev * per, per)
                 return rd2[None], rng[None], cd2, cng
 
-            sweep = shard_map(
+            sweep = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(P(None), P(None), P(None)),
                 out_specs=(P(AXIS, None, None), P(AXIS, None, None),
                            P(None, AXIS), P(None, AXIS)),
-                check_rep=False)
+                check_vma=False)
 
             def fn(series_pad, q0, n_valid0):
                 self.stats.traces += 1
@@ -1236,18 +1231,17 @@ class DiscordEngine:
         ndev = int(mesh.devices.size)
 
         def build():
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from .distributed import AXIS
 
             def shard_body(sub, n_valid):
                 return self._pan_each(ladder, sub, n_valid[0])
 
-            sweep = shard_map(
+            sweep = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(P(AXIS, None), P(None)),
                 out_specs=(P(AXIS, None, None), P(AXIS, None, None)),
-                check_rep=False)
+                check_vma=False)
 
             def fn(stack, n_valid):
                 self.stats.traces += 1

@@ -3,8 +3,9 @@
 All tile math routes through the shared distance-tile engine
 (``core/tiles.TileEngine``), so the backend is pluggable:
   * ``xla``    — blocked lax.map sweep (fast on CPU, used by benches)
-  * ``pallas`` — kernels/mpblock (series-resident Hankel tiles; the TPU
-                 target, validated in interpret mode)
+  * ``pallas`` — kernels/mpblock (window tiles rebuilt in VMEM from
+                 per-block series chunks; the TPU target, validated in
+                 interpret mode)
   * ``numpy``  — host reference (parity tests)
 ``backend="jnp"`` is kept as a legacy alias of ``xla``.
 
@@ -60,16 +61,10 @@ def discords_via_matrix_profile(series, s: int, k: int = 1, *,
     prof = np.asarray(d, np.float64)
     n = prof.shape[0]
     pos, vals = topk_nonoverlapping(prof, k, s)
-    # swept tile lanes, counted as actually evaluated (docs/cps.md):
-    # the static-shape pallas path runs the mpblock upper-triangle
-    # kernel (tile (i, j) only for j >= i); every other backend sweeps
-    # the full block-aligned grid
-    nb = -(-n // block)
-    n_pad = nb * block
-    if backend == "pallas":
-        lanes = nb * (nb + 1) // 2 * block * block
-    else:
-        lanes = n_pad * n_pad
+    # swept tile lanes (docs/cps.md): every backend, the pallas mpblock
+    # kernel included, sweeps the full block-aligned grid
+    n_pad = -(-n // block) * block
+    lanes = n_pad * n_pad
     return DiscordResult(positions=pos, nnds=vals,
                          calls=lanes,
                          n=n, s=s, method=f"scamp[{backend}]",

@@ -232,6 +232,13 @@ class TileEngine:
             lax.dynamic_slice(self.sig_pad, (c0,), (self.block,)),
             self._mask_ids(c0 + jnp.arange(self.block, dtype=jnp.int32)))
 
+    def block_chunks(self) -> jnp.ndarray:
+        """(nb, W) reversed series chunks, one per window block — the
+        mpblock kernel's compact stand-in for :meth:`all_windows`
+        (``kernels/mpblock/kernel.py``)."""
+        from ..kernels.mpblock.kernel import block_chunks
+        return block_chunks(self.series_pad, self.nb, self.block, self.s)
+
     def all_windows(self) -> TileBlock:
         """Every (padded) window, materialized — candidate side of the
         blocked full-profile sweep."""
@@ -281,31 +288,46 @@ class TileEngine:
     def profile(self, *, backend: Optional[str] = None,
                 interpret: Optional[bool] = None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Exact matrix profile (d2, neighbor) of the whole series.
+        """Exact matrix profile (d2, neighbor) of the whole series: the
+        :meth:`block_rows` of every query block."""
+        starts = jnp.arange(self.nb, dtype=jnp.int32) * self.block
+        d2b, argb = self.block_rows(starts, backend=backend,
+                                    interpret=interpret)
+        return d2b.reshape(-1)[:self.n], argb.reshape(-1)[:self.n]
 
-        ``pallas`` dispatches to the mpblock upper-triangle kernel
-        (series-resident Hankel tiles, row+col accumulators); other
-        backends run a blocked row sweep through the registry.
-        ``interpret`` overrides the pallas interpret-mode auto-detect
-        (debug hook; ignored by the other backends).
+    def block_rows(self, starts, *, backend: Optional[str] = None,
+                   interpret: Optional[bool] = None
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """Row (min d2, neighbor) of the query blocks at the (traced,
+        block-aligned) ``starts`` against every window, each
+        ``(len(starts), block)``.  A row is computed the same way
+        whichever blocks are listed with it, so re-sweeping a few
+        blocks reproduces the full profile's rows bit for bit.
 
-        The mpblock kernel bakes ``n_valid`` in as a static parameter
-        and only speaks Eq. (3), so engines built with a dynamic
-        ``n_valid`` (plan-cache bucketing) or ``znorm=False`` take the
-        generic blocked sweep on every backend, pallas included.
+        ``pallas`` dispatches to the mpblock kernel (window tiles built
+        in VMEM from per-block series chunks); other backends run a
+        blocked row sweep through the registry.  ``interpret``
+        overrides the pallas interpret-mode auto-detect (debug hook;
+        ignored by the other backends).  The kernel only speaks
+        Eq. (3), so ``znorm=False`` engines take the blocked sweep on
+        every backend.
         """
         backend = resolve_backend(backend or self.backend)
-        if backend == "pallas" and self.znorm and not self._dyn:
+        if backend == "pallas" and self.znorm:
             from ..kernels.mpblock.kernel import mp_block_pallas
             if interpret is None:
                 interpret = default_interpret()
-            rmin, rarg, cmin, carg = mp_block_pallas(
-                self.series_pad, self.mu_pad, self.sig_pad, s=self.s,
-                n_valid=self.n, block=self.block, interpret=interpret)
-            take_row = rmin <= cmin
-            d2 = jnp.where(take_row, rmin, cmin)
-            arg = jnp.where(take_row, rarg, carg)
-            return d2[:self.n], arg[:self.n].astype(jnp.int32)
+            nb, blk = self.nb, self.block
+            chunks = self.block_chunks()
+            ids = self._mask_ids(jnp.arange(nb * blk, dtype=jnp.int32))
+            rows = starts // blk
+            d2, arg = mp_block_pallas(
+                chunks[rows], self.mu_pad.reshape(nb, blk)[rows].ravel(),
+                self.sig_pad.reshape(nb, blk)[rows].ravel(),
+                ids.reshape(nb, blk)[rows].ravel(),
+                chunks, self.mu_pad, self.sig_pad, ids, s=self.s,
+                n_valid=self.n, block=blk, interpret=interpret)
+            return d2.reshape(-1, blk), arg.reshape(-1, blk)
 
         cand = self.all_windows()
 
@@ -315,9 +337,7 @@ class TileEngine:
             return (jnp.min(d2, axis=1),
                     jnp.argmin(d2, axis=1).astype(jnp.int32))
 
-        starts = jnp.arange(self.nb, dtype=jnp.int32) * self.block
-        d2b, argb = lax.map(one_block, starts)
-        return d2b.reshape(-1)[:self.n], argb.reshape(-1)[:self.n]
+        return lax.map(one_block, starts)
 
 
 # ----------------------------------------------------------------------

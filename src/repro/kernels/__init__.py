@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the paper's compute hot spots.
 
 zdist   — blocked z-norm min-distance (HST inner loop), MXU tiles
-mpblock — exact matrix profile, series-resident Hankel build (SCAMP)
+mpblock — exact matrix profile, window tiles built in VMEM (SCAMP)
 paa     — fused PAA + SAX digitization (bandwidth-bound)
 
 Each package: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd

@@ -5,6 +5,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+#: precision of every f32 tile contraction: the operands stay f32 on the
+#: TPU's MXU, whose default would round them to bf16 (no effect on CPU)
+F32_DOT = jax.lax.Precision.HIGHEST
+
 
 def ceil_div(a: int, b: int) -> int:
     return -(-a // b)
@@ -93,20 +97,27 @@ def windows_jnp(series, s: int):
 
 def znorm_d2_formula(dots, s, mu_q, sig_q, mu_c, sig_c):
     """Eq. (3) squared distance from raw dot products (broadcasting)."""
-    corr = (dots - s * mu_q[:, None] * mu_c[None, :]) / (
-        s * sig_q[:, None] * sig_c[None, :])
+    return znorm_d2_cols(dots, s, mu_q[:, None], sig_q[:, None],
+                         mu_c[None, :], sig_c[None, :])
+
+
+def znorm_d2_cols(dots, s, mu_q, sig_q, mu_c, sig_c):
+    """:func:`znorm_d2_formula` on stats already shaped to broadcast
+    against the (Bq, Bc) tile: query stats (Bq, 1), candidate stats
+    (1, Bc).  Pallas TPU kernels carry their stats in these 2-D
+    layouts, since Mosaic does not accept 1-D per-window blocks."""
+    corr = (dots - s * mu_q * mu_c) / (s * sig_q * sig_c)
     return jnp.maximum(2.0 * s * (1.0 - corr), 0.0)
 
 
 def exclusion_mask(qid, cid, s: int, n_valid: int):
-    """Self-match band + padding lanes (ids outside [0, n_valid)).
+    """Self-match band + padding lanes (ids outside [0, n_valid)) of
+    1-D id vectors."""
+    return exclusion_mask_cols(qid[:, None], cid[None, :], s, n_valid)
 
-    Pure jnp on 1-D id vectors, so it is usable both at the XLA level
-    and inside Pallas kernel bodies (ids loaded from refs; TPU's 2-D
-    iota restriction doesn't apply here).
-    """
-    qi = qid[:, None]
-    cj = cid[None, :]
+
+def exclusion_mask_cols(qi, cj, s: int, n_valid: int):
+    """:func:`exclusion_mask` on ids shaped (Bq, 1) and (1, Bc)."""
     return ((jnp.abs(qi - cj) < s) | (qi < 0) | (qi >= n_valid)
             | (cj < 0) | (cj >= n_valid))
 

@@ -1,20 +1,31 @@
-"""Pallas TPU kernel: matrix-profile tiles with in-kernel window build.
+"""Pallas TPU kernels: matrix-profile tiles with in-kernel window build.
 
-This is the HBM-optimal formulation of the paper's distance hot spot
-(DESIGN.md §3): instead of materializing the (N, s) window matrix —
-which multiplies HBM traffic by s — the *raw series chunk stays resident
-in VMEM* and each grid step builds its (s, block) Hankel tiles on the
-fly from ``s`` static shifted slices at a dynamic offset, then contracts
-them on the MXU.
+The distance hot spot without the (N, s) window matrix in HBM.  A
+``block`` of consecutive windows is carried as one short *chunk* of the
+raw series, ``W = chunk_width(block, s_pad)`` samples long (the block's
+``block + s - 1`` samples, rounded up to whole 128-lane tiles) and
+stored reversed.  The kernel rebuilds the block's (block, s_pad) window
+tile in VMEM with a single strided lane rotation (``pltpu.roll`` with
+``stride=1``: row ``b`` is the chunk rotated by ``b``), zeroes the lanes
+past ``s``, and contracts two such tiles on the MXU.  Window lanes come
+out in reversed sample order; both sides of every contraction use the
+same order, so the dot products are unchanged.
 
-Upper-triangle scheduling: tile (i, j) is computed only for j >= i; each
-tile folds into BOTH the row accumulator (queries i) and the column
-accumulator (candidates j) — d(a,b) = d(b,a) — so the full profile is
-``min(row_out, col_out)`` at the host, with half the MXU work.
+``mp_block_pallas`` runs the full (query block i, candidate block j)
+grid with ``j`` innermost.  Each grid step folds its tile's row
+(min, argmin) into output block ``i``, which only consecutive steps
+visit, so the accumulator never depends on an output block being read
+back from HBM.  Every tile of the square is computed: the d(a, b) =
+d(b, a) triangle would need a column accumulator revisited across the
+whole grid.
 
-VMEM budget: the series chunk + per-window stats are replicated per grid
-step; ops.py caps chunks at ~1M points (4 MB f32) and scans super-chunks
-for longer series.
+Residency: per grid step two chunks (1, W) and the per-window stats of
+both blocks are double-buffered in VMEM, the query tile is cached in a
+(block, s_pad) scratch for the whole row of the grid, and the (block, W)
+rotation staging plus the (block, block) distance tile are temporaries
+— about 3 MB at block=256, s_pad=384, independent of the series length.
+HBM holds the chunks (W / block times the series, 2.5x at those sizes)
+and the per-window stats.
 """
 from __future__ import annotations
 
@@ -22,102 +33,168 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..common import (ceil_div, exclusion_mask, pad_block_operands,
-                      pad_to, znorm_d2_formula)
+from ..common import (F32_DOT, ceil_div, exclusion_mask_cols,
+                      pad_block_operands, pad_to, znorm_d2_cols)
 
 BIG = float("inf")
+LANES = 128
+INT_MAX = jnp.iinfo(jnp.int32).max
 
 
-def _hankel_T(series_ref, start, block: int, s: int):
-    """(s, block) tile:  out[t, b] = series[start + b + t].
+def lane_pad(s: int) -> int:
+    """Window width ``s`` rounded up to whole 128-lane tiles."""
+    return ceil_div(s, LANES) * LANES
 
-    ``s`` static shifted slices at dynamic offset `start` — lowerable on
-    TPU (dynamic-start, static-size) and a contiguous read pattern.
+
+def chunk_width(block: int, s_pad: int) -> int:
+    """Samples per reversed chunk: ``block + s_pad - 1`` rounded up to
+    whole lane tiles, enough for every rotation of the window tile."""
+    return ceil_div(block + s_pad - 1, LANES) * LANES
+
+
+def reversed_chunk(seg, width: int):
+    """One reversed chunk from a natural-order series segment (zero
+    filled past its end)."""
+    return pad_to(seg, width)[:width][::-1]
+
+
+def block_chunks(series, nb: int, block: int, s: int):
+    """(nb, W) reversed chunks of ``nb`` consecutive window blocks:
+    row ``b`` is ``series[b*block : b*block + W]`` reversed, with zeros
+    past the end of ``series``."""
+    width = chunk_width(block, lane_pad(s))
+    x = jnp.pad(series, (0, max(0, nb * block + width - series.shape[0])))
+    idx = (jnp.arange(nb)[:, None] * block
+           + jnp.arange(width - 1, -1, -1)[None, :])
+    return x[idx]
+
+
+def _window_tile(chunk, block: int, s: int, s_pad: int):
+    """(block, s_pad) window tile of one reversed chunk ``(1, W)``.
+
+    Rolling row ``b`` right by ``b`` puts sample ``b + t`` of the
+    natural series at lane ``W - 1 - t``; the last ``s_pad`` lanes are
+    the tile, and lanes holding ``t >= s`` are zeroed (with ``where``,
+    so a non-finite pad sample cannot leak through ``0 * x``).
     """
-    cols = [pl.load(series_ref, (pl.dslice(start + t, block),))
-            for t in range(s)]
-    return jnp.stack(cols, axis=0)
+    width = chunk.shape[1]
+    rows = pltpu.roll(jnp.broadcast_to(chunk, (block, width)), 0, 1,
+                      stride=1, stride_axis=0)
+    tile = rows[:, width - s_pad:]
+    lane = lax.broadcasted_iota(jnp.int32, (block, s_pad), 1)
+    return jnp.where(lane >= s_pad - s, tile, 0.0)
 
 
-def _mp_tile_kernel(series_ref, mu_ref, sig_ref,
-                    rmin_ref, rarg_ref, cmin_ref, carg_ref, *,
-                    s: int, block: int, n_valid: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+def _mp_rows_kernel(qc_ref, qmu_ref, qsig_ref, qid_ref,
+                    cc_ref, cmu_ref, csig_ref, cid_ref,
+                    dmin_ref, darg_ref, qtile_ref, *,
+                    s: int, s_pad: int, block: int, n_valid: int):
+    @pl.when(pl.program_id(1) == 0)     # first step of query row i
+    def _init():
+        qtile_ref[...] = _window_tile(qc_ref[...], block, s, s_pad)
+        dmin_ref[...] = jnp.full((block, 1), BIG, jnp.float32)
+        darg_ref[...] = jnp.zeros((block, 1), jnp.int32)
 
-    @pl.when(j == i)          # first visit of row block i (j starts at i)
-    def _init_row():
-        rmin_ref[...] = jnp.full((block,), BIG, jnp.float32)
-        rarg_ref[...] = jnp.zeros((block,), jnp.int32)
+    ctile = _window_tile(cc_ref[...], block, s, s_pad)
+    dots = lax.dot_general(qtile_ref[...], ctile,
+                           (((1,), (1,)), ((), ())), precision=F32_DOT,
+                           preferred_element_type=jnp.float32)
+    d2 = znorm_d2_cols(dots, s, qmu_ref[...], qsig_ref[...],
+                       cmu_ref[...], csig_ref[...])
+    cid = cid_ref[...]
+    d2 = jnp.where(exclusion_mask_cols(qid_ref[...], cid, s, n_valid),
+                   BIG, d2)
+    tmin = jnp.min(d2, axis=1, keepdims=True)
+    # first minimizing candidate (argmin's tie rule, without argmin)
+    targ = jnp.min(jnp.where(d2 == tmin, cid, INT_MAX), axis=1,
+                   keepdims=True)
+    cur = dmin_ref[...]
+    take = tmin < cur
+    dmin_ref[...] = jnp.where(take, tmin, cur)
+    darg_ref[...] = jnp.where(take, targ, darg_ref[...])
 
-    @pl.when(i == 0)          # first visit of col block j
-    def _init_col():
-        cmin_ref[...] = jnp.full((block,), BIG, jnp.float32)
-        carg_ref[...] = jnp.zeros((block,), jnp.int32)
 
-    @pl.when(j >= i)
-    def _compute():
-        q0 = i * block
-        c0 = j * block
-        qT = _hankel_T(series_ref, q0, block, s)        # (s, bq)
-        cT = _hankel_T(series_ref, c0, block, s)        # (s, bc)
-        dots = jax.lax.dot_general(
-            qT, cT, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (bq, bc)
-        qmu = pl.load(mu_ref, (pl.dslice(q0, block),))
-        qsig = pl.load(sig_ref, (pl.dslice(q0, block),))
-        cmu = pl.load(mu_ref, (pl.dslice(c0, block),))
-        csig = pl.load(sig_ref, (pl.dslice(c0, block),))
-        d2 = znorm_d2_formula(dots, s, qmu, qsig, cmu, csig)
+def mp_block_pallas(q_chunks, qmu, qsig, qid, c_chunks, cmu, csig, cid,
+                    *, s: int, n_valid: int, block: int,
+                    interpret: bool = True):
+    """Row (min d2, argmin id) of every query window over every
+    candidate window, with the windows built in-kernel from chunks.
 
-        # mask stays inline: TPU Pallas requires >= 2-D iota, so the id
-        # grids can't go through the 1-D exclusion_mask helper
-        qi = q0 + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-        cj = c0 + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-        bad = (jnp.abs(qi - cj) < s) | (cj >= n_valid) | (qi >= n_valid)
-        d2 = jnp.where(bad, BIG, d2)
+    q_chunks (nbq, W) / c_chunks (nbc, W): reversed chunks from
+    :func:`block_chunks`.  qmu/qsig/qid (nbq*block,) and
+    cmu/csig/cid (nbc*block,): per-window stats and *global* ids (ids
+    outside [0, n_valid) are padding and never win).  The self-join
+    passes the same operands on both sides.  Returns (d2 (nbq*block,)
+    f32, neighbour id (nbq*block,) i32); a row with no unmasked
+    candidate keeps (+inf, 0).
+    """
+    nbq, width = q_chunks.shape
+    nbc = c_chunks.shape[0]
+    s_pad = lane_pad(s)
+    assert width == chunk_width(block, s_pad), (width, block, s_pad)
 
-        row_min = jnp.min(d2, axis=1)
-        row_arg = (c0 + jnp.argmin(d2, axis=1)).astype(jnp.int32)
-        col_min = jnp.min(d2, axis=0)
-        col_arg = (q0 + jnp.argmin(d2, axis=0)).astype(jnp.int32)
+    def rows(v):
+        return v.reshape(nbq, block, 1)
 
-        cur = rmin_ref[...]
-        take = row_min < cur
-        rmin_ref[...] = jnp.where(take, row_min, cur)
-        rarg_ref[...] = jnp.where(take, row_arg, rarg_ref[...])
+    def cols(v):
+        return v.reshape(nbc, 1, block)
 
-        cur = cmin_ref[...]
-        take = col_min < cur
-        cmin_ref[...] = jnp.where(take, col_min, cur)
-        carg_ref[...] = jnp.where(take, col_arg, carg_ref[...])
+    row_spec = pl.BlockSpec((None, block, 1), lambda i, j: (i, 0, 0))
+    col_spec = pl.BlockSpec((None, 1, block), lambda i, j: (j, 0, 0))
+    kernel = functools.partial(_mp_rows_kernel, s=s, s_pad=s_pad,
+                               block=block, n_valid=n_valid)
+    dmin, darg = pl.pallas_call(
+        kernel,
+        grid=(nbq, nbc),
+        in_specs=[
+            pl.BlockSpec((None, 1, width), lambda i, j: (i, 0, 0)),
+            row_spec, row_spec, row_spec,
+            pl.BlockSpec((None, 1, width), lambda i, j: (j, 0, 0)),
+            col_spec, col_spec, col_spec,
+        ],
+        out_specs=(row_spec, row_spec),
+        out_shape=(jax.ShapeDtypeStruct((nbq, block, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((nbq, block, 1), jnp.int32)),
+        scratch_shapes=[pltpu.VMEM((block, s_pad), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(q_chunks.reshape(nbq, 1, width), rows(qmu), rows(qsig), rows(qid),
+      c_chunks.reshape(nbc, 1, width), cols(cmu), cols(csig), cols(cid))
+    return dmin.reshape(-1), darg.reshape(-1)
 
 
 def _qvc_tile_kernel(q_ref, qmu_ref, qsig_ref, qid_ref,
                      chunk_ref, cmu_ref, csig_ref, cid_ref,
-                     d2_ref, *, s: int, s_pad: int, block: int,
-                     n_valid: int):
-    """Gathered query windows vs one contiguous candidate chunk.
+                     d2_ref, ctile_ref, *, s: int, s_pad: int,
+                     block: int, n_valid: int):
+    """Gathered query rows vs one contiguous candidate block.
 
-    The candidate (s_pad, block) Hankel tile is built *in-kernel* from
-    the raw chunk (same VMEM-resident trick as the full-profile
-    kernel), so the HBM side of the tile never materializes block*s
-    floats.  Rows s..s_pad-1 are zeros to match the queries' MXU lane
-    padding — zeros on both sides leave the dot products unchanged.
+    The candidate (block, s_pad) window tile is built *in-kernel* from
+    the block's reversed chunk (the same VMEM-resident build as the
+    full-profile kernel) on the first grid step and cached for the
+    rest, so the HBM side of the tile never materializes block*s
+    floats.  The queries arrive lane-reversed and zero-padded to match.
     """
-    hank = _hankel_T(chunk_ref, 0, block, s)             # (s, block)
-    cT = jnp.concatenate(
-        [hank, jnp.zeros((s_pad - s, block), jnp.float32)], axis=0) \
-        if s_pad > s else hank                           # (s_pad, block)
-    dots = jax.lax.dot_general(
-        q_ref[...], cT, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (bq, block)
-    d2 = znorm_d2_formula(dots, s, qmu_ref[...], qsig_ref[...],
-                          cmu_ref[...], csig_ref[...])
-    bad = exclusion_mask(qid_ref[...], cid_ref[...], s, n_valid)
+    @pl.when(pl.program_id(0) == 0)
+    def _build():
+        ctile_ref[...] = _window_tile(chunk_ref[...], block, s, s_pad)
+
+    dots = lax.dot_general(q_ref[...], ctile_ref[...],
+                           (((1,), (1,)), ((), ())), precision=F32_DOT,
+                           preferred_element_type=jnp.float32)
+    d2 = znorm_d2_cols(dots, s, qmu_ref[...], qsig_ref[...],
+                       cmu_ref[...], csig_ref[...])
+    bad = exclusion_mask_cols(qid_ref[...], cid_ref[...], s, n_valid)
     d2_ref[...] = jnp.where(bad, BIG, d2)
+
+
+#: query rows per grid step of the gathered-query kernel
+QVC_ROWS = 128
 
 
 def qvc_block_pallas(qwin, qmu, qsig, qid, chunk, cmu, csig, cid, *,
@@ -128,68 +205,42 @@ def qvc_block_pallas(qwin, qmu, qsig, qid, chunk, cmu, csig, cid, *,
     whose windows are built in-kernel; cmu/csig/cid (block,).
     Returns (Bq, block) f32 with +inf at masked lanes.
 
-    All operands are padded to MXU-aligned shapes (rows to 8, lanes to
-    128) before the kernel; padded ids are -1 so their lanes come back
-    +inf and are sliced off.
+    Query rows stream through VMEM ``QVC_ROWS`` at a time (8-row
+    aligned below that), so any Bq fits.  Rows pad to the step and the
+    block to 128 lanes before the kernel; padded ids are -1 so their
+    lanes come back +inf and are sliced off.
     """
     bq = qwin.shape[0]
     block = cmu.shape[0]
+    s_pad = lane_pad(s)
+    rows = QVC_ROWS if bq > QVC_ROWS else 8
     qwin, qmu, qsig, qid = pad_block_operands(qwin, qmu, qsig, qid,
-                                              rows=8, lanes=128)
-    blk_p = ceil_div(block, 128) * 128
-    # Hankel reads go up to chunk[(blk_p - 1) + (s - 1)]; round the
-    # buffer itself up to a lane multiple as well
-    chunk = pad_to(pad_to(chunk, blk_p + s - 1), 128)
-    cmu = pad_to(cmu, blk_p)
-    csig = pad_to(csig, blk_p, value=1.0)
-    cid = pad_to(cid, blk_p, value=-1)
-    kernel = functools.partial(_qvc_tile_kernel, s=s,
-                               s_pad=qwin.shape[1], block=blk_p,
-                               n_valid=n_valid)
+                                              rows=rows, lanes=LANES)
+    bq_p = qwin.shape[0]
+    blk_q = min(bq_p, QVC_ROWS)
+    blk_p = ceil_div(block, LANES) * LANES
+    width = chunk_width(blk_p, s_pad)
+    kernel = functools.partial(_qvc_tile_kernel, s=s, s_pad=s_pad,
+                               block=blk_p, n_valid=n_valid)
+    q_col = pl.BlockSpec((blk_q, 1), lambda i: (i, 0))
+    c_row = pl.BlockSpec((1, blk_p), lambda i: (0, 0))
     d2 = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((qwin.shape[0], blk_p),
-                                       jnp.float32),
-        interpret=interpret,
-    )(qwin, qmu, qsig, qid, chunk, cmu, csig, cid)
-    return d2[:bq, :block]
-
-
-def mp_block_pallas(series_pad, mu_pad, sig_pad, *, s: int, n_valid: int,
-                    block: int = 128, interpret: bool = True):
-    """Matrix profile of one series chunk.
-
-    series_pad: (L,) f32, L >= n_blocks*block + s (window overhang).
-    mu/sig_pad: (n_blocks*block,) per-window stats.
-    Returns (row_min_d2, row_arg, col_min_d2, col_arg), each (n_pad,).
-    """
-    n_pad = mu_pad.shape[0]
-    assert n_pad % block == 0
-    nb = n_pad // block
-    grid = (nb, nb)
-    kernel = functools.partial(
-        _mp_tile_kernel, s=s, block=block, n_valid=n_valid)
-    L = series_pad.shape[0]
-    out_shape = (
-        jax.ShapeDtypeStruct((n_pad,), jnp.float32),
-        jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-        jax.ShapeDtypeStruct((n_pad,), jnp.float32),
-        jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+        grid=(bq_p // blk_q,),
         in_specs=[
-            pl.BlockSpec((L,), lambda i, j: (0,)),     # series resident
-            pl.BlockSpec((n_pad,), lambda i, j: (0,)),  # mu resident
-            pl.BlockSpec((n_pad,), lambda i, j: (0,)),  # sig resident
+            pl.BlockSpec((blk_q, s_pad), lambda i: (i, 0)),
+            q_col, q_col, q_col,
+            pl.BlockSpec((1, width), lambda i: (0, 0)),
+            c_row, c_row, c_row,
         ],
-        out_specs=(
-            pl.BlockSpec((block,), lambda i, j: (i,)),
-            pl.BlockSpec((block,), lambda i, j: (i,)),
-            pl.BlockSpec((block,), lambda i, j: (j,)),
-            pl.BlockSpec((block,), lambda i, j: (j,)),
-        ),
-        out_shape=out_shape,
+        out_specs=pl.BlockSpec((blk_q, blk_p), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bq_p, blk_p), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((blk_p, s_pad), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(series_pad, mu_pad, sig_pad)
+    )(qwin[:, ::-1], qmu[:, None], qsig[:, None], qid[:, None],
+      reversed_chunk(chunk, width)[None, :],
+      pad_to(cmu, blk_p)[None, :], pad_to(csig, blk_p, value=1.0)[None, :],
+      pad_to(cid, blk_p, value=-1)[None, :])
+    return d2[:bq, :block]

@@ -24,12 +24,12 @@ def _paa_sax_kernel(boxsum_ref, mu_ref, sig_ref, words_ref, *,
                     breakpoints: tuple):
     i = pl.program_id(0)
     n0 = i * block
-    mu = pl.load(mu_ref, (pl.dslice(n0, block),))
-    sig = pl.load(sig_ref, (pl.dslice(n0, block),))
+    mu = mu_ref[pl.ds(n0, block)]
+    sig = sig_ref[pl.ds(n0, block)]
     inv_sig = 1.0 / sig
     words = jnp.zeros((block,), jnp.int32)
     for j in range(P):                                    # static unroll
-        seg = pl.load(boxsum_ref, (pl.dslice(n0 + j * w, block),)) / w
+        seg = boxsum_ref[pl.ds(n0 + j * w, block)] / w
         val = (seg - mu) * inv_sig
         digit = jnp.zeros((block,), jnp.int32)
         for bp in breakpoints:                            # alpha-1 compares

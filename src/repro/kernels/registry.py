@@ -13,7 +13,7 @@ is the registry of interchangeable implementations of that tile:
   * ``xla``    — jnp dot_general + rank-1 correction; the portable
                  default (CPU/GPU, and perfectly respectable on TPU).
   * ``pallas`` — MXU tile kernel (this file) for gathered window
-                 blocks; the series-resident Hankel variants live in
+                 blocks; the series-chunk window-tile variants live in
                  ``kernels/mpblock`` and are dispatched by the engine
                  (``core/tiles.TileEngine``) for contiguous sweeps.
   * ``numpy``  — pure-NumPy host reference, routed through
@@ -85,8 +85,9 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .common import (default_interpret, exclusion_mask,
-                     pad_block_operands, pad_to, znorm_d2_formula)
+from .common import (F32_DOT, default_interpret, exclusion_mask,
+                     exclusion_mask_cols, pad_block_operands, pad_to,
+                     znorm_d2_cols, znorm_d2_formula)
 
 TileBackendFn = Callable[..., jnp.ndarray]
 
@@ -108,10 +109,7 @@ ENV_VAR = "REPRO_TILE_BACKEND"
 # ``REPRO_KEEP_ASYNC_DISPATCH=1`` to opt out of the guard.
 if ((os.cpu_count() or 1) <= 1
         and not os.environ.get("REPRO_KEEP_ASYNC_DISPATCH")):
-    try:
-        jax.config.update("jax_cpu_enable_async_dispatch", False)
-    except AttributeError:      # jax build without the flag
-        pass
+    jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 
 #: IR-level traits per backend, consumed by the jaxpr auditor
@@ -224,6 +222,7 @@ def resolve_backend(name: str | None = None) -> str:
 def tile_d2_xla(qwin, qmu, qsig, qid, cwin, cmu, csig, cid, *,
                 s: int, n_valid: int):
     dots = lax.dot_general(qwin, cwin, (((1,), (1,)), ((), ())),
+                           precision=F32_DOT,
                            preferred_element_type=jnp.float32)
     d2 = znorm_d2_formula(dots, s, qmu, qsig, cmu, csig)
     return jnp.where(exclusion_mask(qid, cid, s, n_valid), jnp.inf, d2)
@@ -264,11 +263,11 @@ def _tile_d2_kernel(q_ref, qmu_ref, qsig_ref, qid_ref,
                     c_ref, cmu_ref, csig_ref, cid_ref,
                     d2_ref, *, s: int, n_valid: int):
     dots = lax.dot_general(q_ref[...], c_ref[...],
-                           (((1,), (1,)), ((), ())),
+                           (((1,), (1,)), ((), ())), precision=F32_DOT,
                            preferred_element_type=jnp.float32)
-    d2 = znorm_d2_formula(dots, s, qmu_ref[...], qsig_ref[...],
-                          cmu_ref[...], csig_ref[...])
-    bad = exclusion_mask(qid_ref[...], cid_ref[...], s, n_valid)
+    d2 = znorm_d2_cols(dots, s, qmu_ref[...], qsig_ref[...],
+                       cmu_ref[...], csig_ref[...])
+    bad = exclusion_mask_cols(qid_ref[...], cid_ref[...], s, n_valid)
     d2_ref[...] = jnp.where(bad, float("inf"), d2)
 
 
@@ -282,7 +281,9 @@ def tile_d2_pallas(qwin, qmu, qsig, qid, cwin, cmu, csig, cid, *,
     """Gridded MXU tile kernel: arbitrary (Bq, Bc) inputs stream
     through VMEM in (BLOCK_Q x BLOCK_C) steps, so per-step residency
     is bounded no matter how large the caller's blocks are (the
-    distributed ring hands over whole per-shard slabs)."""
+    distributed ring hands over whole per-shard slabs).  Query stats
+    and ids enter as (Bq, 1) columns and candidate ones as (1, Bc)
+    rows: Mosaic rejects 1-D per-window blocks."""
     if interpret is None:
         interpret = default_interpret()
     bq, bc = qwin.shape[0], cwin.shape[0]
@@ -296,23 +297,22 @@ def tile_d2_pallas(qwin, qmu, qsig, qid, cwin, cmu, csig, cid, *,
     blk_q = min(bq_p, BLOCK_Q)
     grid = (bq_p // blk_q, bc_p // BLOCK_C)
     kernel = functools.partial(_tile_d2_kernel, s=s, n_valid=n_valid)
+    q_col = pl.BlockSpec((blk_q, 1), lambda i, j: (i, 0))
+    c_row = pl.BlockSpec((1, BLOCK_C), lambda i, j: (0, j))
     d2 = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((blk_q, s_p), lambda i, j: (i, 0)),
-            pl.BlockSpec((blk_q,), lambda i, j: (i,)),
-            pl.BlockSpec((blk_q,), lambda i, j: (i,)),
-            pl.BlockSpec((blk_q,), lambda i, j: (i,)),
+            q_col, q_col, q_col,
             pl.BlockSpec((BLOCK_C, s_p), lambda i, j: (j, 0)),
-            pl.BlockSpec((BLOCK_C,), lambda i, j: (j,)),
-            pl.BlockSpec((BLOCK_C,), lambda i, j: (j,)),
-            pl.BlockSpec((BLOCK_C,), lambda i, j: (j,)),
+            c_row, c_row, c_row,
         ],
         out_specs=pl.BlockSpec((blk_q, BLOCK_C), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bq_p, bc_p), jnp.float32),
         interpret=interpret,
-    )(qwin, qmu, qsig, qid, cwin, cmu, csig, cid)
+    )(qwin, qmu[:, None], qsig[:, None], qid[:, None],
+      cwin, cmu[None, :], csig[None, :], cid[None, :])
     return d2[:bq, :bc]
 
 
@@ -322,6 +322,7 @@ def tile_d2_pallas(qwin, qmu, qsig, qid, cwin, cmu, csig, cid, *,
 @register_dot_backend("xla")
 def dot_tile_xla(q, c):
     return lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                           precision=F32_DOT,
                            preferred_element_type=jnp.float32)
 
 
@@ -339,6 +340,7 @@ def dot_tile_numpy(q, c):
 def _dot_tile_kernel(q_ref, c_ref, o_ref):
     o_ref[...] = lax.dot_general(q_ref[...], c_ref[...],
                                  (((1,), (1,)), ((), ())),
+                                 precision=F32_DOT,
                                  preferred_element_type=jnp.float32)
 
 

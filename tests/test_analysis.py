@@ -566,7 +566,7 @@ def test_irlint_f64_literal_tp_and_near_miss():
     def fn(v):
         return v * 2.0
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         findings, _ = _fake_cell(fn, avals=(((4,), "float64"),))
     assert any(f.rule == "ir-f64" for f in findings)
     findings, _ = _fake_cell(fn, avals=(((4,), "float32"),))
@@ -791,7 +791,7 @@ def test_cli_budget_finding(tmp_path):
 
 
 def test_cli_irlint_pass(tmp_path):
-    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=SRC)
     rp = tmp_path / "rep.json"
     out = subprocess.run(
         [sys.executable, "-m", "repro.analysis", "irlint",

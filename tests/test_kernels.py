@@ -45,6 +45,31 @@ def test_mpblock_matches_brute_profile(n, s):
     assert np.all(np.abs(arg - idx) >= s)
 
 
+def test_mpblock_earlier_block_neighbours_match_xla():
+    """Windows whose nearest neighbours sit in *earlier* blocks: the
+    second half of the series repeats the first, so every late window's
+    neighbour lies blocks before its own.  An accumulator that relied
+    on revisiting an output block would lose exactly these minima on
+    the chip (interpret mode keeps the whole output and hides it); the
+    kernel must match the xla profile, neighbours included."""
+    import jax.numpy as jnp
+    from repro.core.tiles import TileEngine
+    rng = np.random.default_rng(5)
+    half = rng.normal(size=700)
+    x = np.concatenate([half, half]) + 0.01 * rng.normal(size=1400)
+    s, block = 40, 64
+    out = {be: TileEngine(jnp.asarray(x, jnp.float32), s, block=block,
+                          backend=be).profile()
+           for be in ("pallas", "xla")}
+    d_pl, a_pl = (np.asarray(v) for v in out["pallas"])
+    d_xl, a_xl = (np.asarray(v) for v in out["xla"])
+    late = np.arange(d_pl.shape[0]) >= 700
+    assert np.mean(a_xl[late] // block < np.arange(
+        d_pl.shape[0])[late] // block) > 0.9
+    assert np.allclose(d_pl, d_xl, rtol=1e-3, atol=1e-3)
+    assert np.array_equal(a_pl, a_xl)
+
+
 @pytest.mark.parametrize("s,P,alpha", [(96, 4, 4), (120, 4, 3),
                                        (64, 8, 6), (150, 5, 4)])
 def test_paa_sax_words_match(s, P, alpha):

@@ -83,7 +83,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, json
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.optim.compress import compressed_psum
 
 mesh = Mesh(np.array(jax.devices()), ("d",))
@@ -93,8 +92,8 @@ def body(g):
     red, err = compressed_psum({"g": g}, "d")
     return red["g"], err["g"]
 
-f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("d"),
-                      out_specs=(P("d"), P("d"))))
+f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("d"),
+                          out_specs=(P("d"), P("d"))))
 red, err = f(G.reshape(-1))
 red = np.asarray(red).reshape(8, 256)
 true_mean = G.mean(axis=0)
