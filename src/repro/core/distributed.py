@@ -48,11 +48,10 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..kernels.common import default_interpret
 from ..parallel.sharding import SERIES_AXIS as AXIS, series_mesh
 from .result import DiscordResult
-from .tiles import (TileBlock, resolve_backend, tile_d2, tile_mins,
-                    topk_nonoverlapping)
+from .tiles import (TileBlock, resolve_backend, set_row_mins, tile_d2,
+                    tile_mins, topk_nonoverlapping)
 
 #: legacy name of :func:`repro.parallel.sharding.series_mesh`
 data_mesh = series_mesh
@@ -90,31 +89,17 @@ def _pack_blocks(series: np.ndarray, s: int, ndev: int):
 # ----------------------------------------------------------------------
 # 1) ring matrix profile
 # ----------------------------------------------------------------------
-def _ring_row_mins(q, c, s: int, n: int, backend: str, block: int):
-    """Row (min d2, argmin id) of the local query set ``q`` against
-    the visiting candidate set ``c``.  On ``pallas`` each set is
-    ``(chunks, mu, sig, ids)`` for the mpblock kernel, which builds
-    the window tiles in VMEM; elsewhere ``(windows, mu, sig, ids)``
-    through the tile registry."""
-    if backend == "pallas":
-        from ..kernels.mpblock.kernel import mp_block_pallas
-        return mp_block_pallas(*q, *c, s=s, n_valid=n, block=block,
-                               interpret=default_interpret())
-    m = tile_mins(_tile_d2(*q, *c, s, n, backend), q[3], c[3])
-    return m.row_min, m.row_arg
-
-
-def _ring_mp_shard(qset, qmu, qsig, qid, s: int, n: int, ndev: int,
+def _ring_mp_shard(qbody, qmu, qsig, qid, s: int, n: int, ndev: int,
                    backend: str, block: int):
     """Per-shard body: local queries fixed; candidates orbit the ring.
-    ``qset`` is the shard's chunks on ``pallas`` and its windows
-    elsewhere (see :func:`_ring_row_mins`)."""
-    q = (qset, qmu, qsig, qid)
+    The arguments are the shard of a ``TileEngine.window_set``."""
+    q = (qbody, qmu, qsig, qid)
     perm = [(i, (i + 1) % ndev) for i in range(ndev)]
 
     def hop(carry, _):
         c, best, barg = carry
-        tmin, targ = _ring_row_mins(q, c, s, n, backend, block)
+        tmin, targ = set_row_mins(q, c, s=s, n_valid=n, block=block,
+                                  backend=backend)
         take = tmin < best
         best = jnp.where(take, tmin, best)
         barg = jnp.where(take, targ, barg)

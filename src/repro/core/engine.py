@@ -848,24 +848,16 @@ class DiscordEngine:
         and ``n_sh = per * ndev`` the mesh-wide padded count."""
         return plan_shard_geom(s, Lb, self.spec.block, ndev)
 
-    def _sharded_blocks(self, eng: TileEngine, n_pad: int, n_sh: int,
-                        chunked: bool = False):
+    def _sharded_blocks(self, eng: TileEngine, n_pad: int, n_sh: int):
         """All (bucket-padded) windows of ``eng``, further padded to
         the mesh-wide count ``n_sh`` with masked lanes (ids -1) so the
-        per-device shards split evenly and stay block-aligned.
-        ``chunked`` carries the windows as the mpblock kernel's
-        per-block series chunks instead of materialized rows."""
+        per-device shards split evenly and stay block-aligned."""
+        blk = eng.all_windows()          # padding ids already masked
         pad = n_sh - n_pad
-        if chunked:
-            first = jnp.pad(eng.block_chunks(),
-                            ((0, pad // eng.block), (0, 0)))
-        else:
-            first = jnp.pad(eng.all_windows().win, ((0, pad), (0, 0)))
-        ids = eng._mask_ids(jnp.arange(n_pad, dtype=jnp.int32))
-        return (first,
-                jnp.pad(eng.mu_pad, (0, pad)),
-                jnp.pad(eng.sig_pad, (0, pad), constant_values=1.0),
-                jnp.pad(ids, (0, pad), constant_values=-1))
+        return (jnp.pad(blk.win, ((0, pad), (0, 0))),
+                jnp.pad(blk.mu, (0, pad)),
+                jnp.pad(blk.sig, (0, pad), constant_values=1.0),
+                jnp.pad(blk.ids, (0, pad), constant_values=-1))
 
     def _ring_plan(self, s: int, Lb: int):
         """(series_pad (Lb,), n_valid) -> (d2 (n_sh,), neighbor).
@@ -901,8 +893,7 @@ class DiscordEngine:
                 eng = TileEngine(series_pad, s, block=spec.block,
                                  backend=be, znorm=spec.znorm,
                                  n_valid=n_valid)
-                return sweep(*self._sharded_blocks(
-                    eng, n_pad, n_sh, chunked=be == "pallas"))
+                return sweep(*eng.window_set(n_sh))
             return fn
         return self._get_plan(("ring", s, Lb, (ndev,)), build)
 

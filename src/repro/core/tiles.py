@@ -36,7 +36,8 @@ from ..kernels.registry import (available_backends, get_backend,
 
 __all__ = [
     "TileBlock", "TileMins", "TileEngine", "tile_d2", "tile_mins",
-    "pair_d2", "exact_pair_d2", "topk_nonoverlapping", "batched_profile",
+    "set_row_mins", "pair_d2", "exact_pair_d2", "topk_nonoverlapping",
+    "batched_profile",
     "resolve_backend", "available_backends", "register_backend",
 ]
 
@@ -72,6 +73,20 @@ def tile_mins(d2: jnp.ndarray, qids, cids) -> TileMins:
         col_min=jnp.min(d2, axis=0),
         col_arg=qids[jnp.argmin(d2, axis=0)],
     )
+
+
+def set_row_mins(q: tuple, c: tuple, *, s: int, n_valid, block: int,
+                 backend: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Row (min d2, neighbor id) of window set ``q`` against window set
+    ``c``, both slices of :meth:`TileEngine.window_set` built on the
+    (resolved) ``backend``.  Eq. (3) distances only."""
+    if backend == "pallas":
+        from ..kernels.mpblock.kernel import mp_block_pallas
+        return mp_block_pallas(*q, *c, s=s, n_valid=n_valid, block=block,
+                               interpret=default_interpret())
+    m = tile_mins(tile_d2(TileBlock(*q), TileBlock(*c), s=s,
+                          n_valid=n_valid, backend=backend), q[3], c[3])
+    return m.row_min, m.row_arg
 
 
 def pair_d2(wa, wb, mu_a, sig_a, mu_b, sig_b, s: int, valid=None):
@@ -248,6 +263,26 @@ class TileEngine:
         return TileBlock(win, self.mu_pad, self.sig_pad,
                          self._mask_ids(jnp.arange(n_pad,
                                                    dtype=jnp.int32)))
+
+    def window_set(self, pad_to: int) -> tuple:
+        """Every window as a ``(body, mu, sig, ids)`` set for
+        :func:`set_row_mins`, padded with masked lanes (ids -1) to
+        ``pad_to`` windows, a multiple of ``block`` (ring shards split
+        it evenly).  ``body`` is the per-block series chunks of
+        :meth:`block_chunks` on ``pallas`` and the materialized window
+        rows elsewhere; callers only pass the set on."""
+        n_pad = self.mu_pad.shape[0]
+        pad = pad_to - n_pad
+        if self.backend == "pallas":
+            body = jnp.pad(self.block_chunks(),
+                           ((0, pad // self.block), (0, 0)))
+        else:
+            body = jnp.pad(self.all_windows().win, ((0, pad), (0, 0)))
+        ids = self._mask_ids(jnp.arange(n_pad, dtype=jnp.int32))
+        return (body,
+                jnp.pad(self.mu_pad, (0, pad)),
+                jnp.pad(self.sig_pad, (0, pad), constant_values=1.0),
+                jnp.pad(ids, (0, pad), constant_values=-1))
 
     # -- tile ops ------------------------------------------------------
     def d2(self, q: TileBlock, c: TileBlock,

@@ -21,8 +21,8 @@ import jax.numpy as jnp
 from repro.core import find_discords, find_discords_batched
 from repro.core.hst_jax import NND_INIT, _scatter_min
 from repro.core.tiles import (TileEngine, available_backends, pair_d2,
-                              resolve_backend, tile_d2, tile_mins,
-                              topk_nonoverlapping)
+                              resolve_backend, set_row_mins, tile_d2,
+                              tile_mins, topk_nonoverlapping)
 
 BACKENDS = ("numpy", "xla", "pallas")
 
@@ -171,6 +171,41 @@ def test_profile_backend_matches_brute(backend):
     assert np.allclose(np.sqrt(np.asarray(d2)), prof, atol=2e-3)
     arg = np.asarray(arg)
     assert np.all(np.abs(arg - np.arange(eng.n)) >= s)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_window_set_shards_fold_to_profile(backend):
+    """The ring's shape: halves of a padded ``window_set`` (chunks on
+    pallas, window rows elsewhere) swept against each other with
+    ``set_row_mins`` and min-folded give the exact profile, and each
+    neighbor realizes its distance."""
+    from repro.core.serial.brute import exact_nnd_profile
+    x = _series(5, 450)
+    s, block = 24, 128
+    n = len(x) - s + 1
+    eng = TileEngine(x, s, block=block, backend=backend, n_valid=n)
+    n_sh = 6 * block                   # 4 tile blocks padded to 2 x 3
+    ws = eng.window_set(n_sh)
+    shards = [tuple(a[:a.shape[0] // 2] for a in ws),
+              tuple(a[a.shape[0] // 2:] for a in ws)]
+    d2, arg = [], []
+    for q in shards:
+        (da, aa), (db, ab) = (
+            set_row_mins(q, c, s=s, n_valid=n_sh, block=block,
+                         backend=eng.backend) for c in shards)
+        take = db < da
+        d2.append(np.where(take, db, da))
+        arg.append(np.where(take, ab, aa))
+    d = np.sqrt(np.concatenate(d2)[:n])
+    arg = np.concatenate(arg)[:n]
+    xf = np.asarray(x, np.float64)
+    prof = exact_nnd_profile(xf, s)
+    assert np.allclose(d, prof, atol=2e-3)
+    assert np.all(np.abs(arg - np.arange(n)) >= s)
+    w = np.lib.stride_tricks.sliding_window_view(xf, s)
+    z = (w - w.mean(1, keepdims=True)) / w.std(1, keepdims=True)
+    assert np.allclose(np.linalg.norm(z - z[arg], axis=1), prof,
+                       atol=2e-3)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
