@@ -85,6 +85,32 @@ lanes run the exact single-tenant bodies (bit-identical results).
 Work accounting is unified across planes (docs/cps.md): every result
 reports ``calls`` (= swept ``tile_lanes`` on this plane) and the
 derived ``cps``.
+
+Spans (``jax.profiler.TraceAnnotation``): each entry that answers from
+a plan opens an ``engine.search`` span on the calling thread, which a
+profile puts on the device planes' clock.  Names are fixed strings and
+identifiers are stats: ``kind`` (``profile``, ``ring``, ``qsweep``,
+``batched``, ``pan``) on every one, and on the profile path ``search``
+(the ``stats.searches`` index the search takes), ``bucket`` and ``n``.
+An entry that calls another nests a second ``engine.search`` inside
+its own; readers take the outermost.
+
+On the profile path the span holds, in order and without overlap:
+``engine.prepare``
+    f64 conversion, length check, bucket, padding, plan lookup;
+``engine.dispatch``
+    host-to-device copy and the plan's call, which returns before the
+    device finishes (and traces and compiles on a cache miss);
+``engine.wait``
+    the host blocked until the device's profile is ready;
+``engine.fetch``
+    the device-to-host copy of the profile;
+``engine.select``
+    square root, the non-overlapping top-k, the result.
+
+Each plan's traced function runs under ``jax.named_scope(<kind>)``,
+and every ``pallas_call`` carries a ``name=``, so device operations
+are named by plan kind and kernel.
 """
 from __future__ import annotations
 
@@ -100,6 +126,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from ..kernels.common import ceil_div, exclusion_mask, znorm_d2_formula
 from ..kernels.registry import (bound_dot_radius, get_bound_backend,
@@ -402,9 +429,10 @@ class DiscordEngine:
         return self.plan_cache._plans
 
     def _get_plan(self, key, build):
+        kind = key[0]
         key = self._plan_key(key)
-        fn, fresh = self.plan_cache.get(key,
-                                        lambda: jax.jit(build()))
+        fn, fresh = self.plan_cache.get(
+            key, lambda: jax.jit(jax.named_scope(kind)(build())))
         if fresh:
             self.stats.plans += 1
         return fn
@@ -1281,32 +1309,45 @@ class DiscordEngine:
         return self._dispatch(series, **kw)
 
     def _search_profile(self, series, s: int) -> DiscordResult:
-        """Bucketed, plan-cached exact-profile search."""
+        """Bucketed, plan-cached exact-profile search, in the spans of
+        the module docstring."""
         t0 = time.perf_counter()
-        x = np.asarray(series, np.float64).ravel()
-        L = x.shape[0]
-        if L < s + 1:
-            raise ValueError(f"series of {L} points is too short for "
-                             f"window spec.s={s} (need at least "
-                             f"s + 1 points)")
-        n_true = L - s + 1
-        Lb = length_bucket(L)
-        xp = _bucket_pad(x, Lb)
-        d2, _arg = self._profile_plan(s, Lb)(jnp.asarray(xp),
-                                             np.int32(n_true))
-        prof = np.sqrt(np.asarray(d2, np.float64)[:n_true])
-        pos, vals = topk_nonoverlapping(
-            np.where(np.isfinite(prof), prof, -np.inf), self.spec.k, s)
-        lanes = self._n_pad(s, Lb) ** 2
-        self.stats.searches += 1
-        self.stats.tile_lanes += lanes
-        return DiscordResult(
-            positions=pos, nnds=vals,
-            calls=lanes,                  # swept tile lanes (docs/cps.md)
-            n=n_true, s=s, method=f"scamp[{self.backend}]",
-            runtime_s=time.perf_counter() - t0, tile_lanes=lanes,
-            extra={"backend": self.backend, "bucket": Lb,
-                   "tile_lanes": lanes, "znorm": self.spec.znorm})
+        with TraceAnnotation("engine.search", kind="profile",
+                             search=self.stats.searches) as span:
+            with TraceAnnotation("engine.prepare"):
+                x = np.asarray(series, np.float64).ravel()
+                L = x.shape[0]
+                if L < s + 1:
+                    raise ValueError(
+                        f"series of {L} points is too short for window "
+                        f"spec.s={s} (need at least s + 1 points)")
+                n_true = L - s + 1
+                Lb = length_bucket(L)
+                span.set_metadata(bucket=Lb, n=n_true)
+                xp = _bucket_pad(x, Lb)
+                plan = self._profile_plan(s, Lb)
+            with TraceAnnotation("engine.dispatch"):
+                d2, _arg = plan(jnp.asarray(xp), np.int32(n_true))
+            with TraceAnnotation("engine.wait"):
+                d2.block_until_ready()
+            with TraceAnnotation("engine.fetch"):
+                d2 = np.asarray(d2, np.float64)[:n_true]
+            with TraceAnnotation("engine.select"):
+                prof = np.sqrt(d2)
+                pos, vals = topk_nonoverlapping(
+                    np.where(np.isfinite(prof), prof, -np.inf),
+                    self.spec.k, s)
+                lanes = self._n_pad(s, Lb) ** 2
+                self.stats.searches += 1
+                self.stats.tile_lanes += lanes
+                return DiscordResult(
+                    positions=pos, nnds=vals,
+                    calls=lanes,          # swept tile lanes (docs/cps.md)
+                    n=n_true, s=s, method=f"scamp[{self.backend}]",
+                    runtime_s=time.perf_counter() - t0, tile_lanes=lanes,
+                    extra={"backend": self.backend, "bucket": Lb,
+                           "tile_lanes": lanes,
+                           "znorm": self.spec.znorm})
 
     def _qsweep_select(self, lo_d2, hi_d2, n_true: int, s: int,
                        refine):
@@ -1443,16 +1484,17 @@ class DiscordEngine:
             return (self._qsweep_plan(s_, Lb),
                     self._n_pad(s_, Lb) ** 2)
 
-        out = self._qsweep_exec(series, s, bound_plan_lanes)
-        if out is None:      # single-block bucket: exact outright
-            return self._search_profile(series, s)
-        pos, vals, bl, rl, n_true, extra = out
-        self.stats.searches += 1
-        return DiscordResult(
-            positions=pos, nnds=vals, calls=bl + rl, n=n_true, s=s,
-            method=f"qsweep[{self.spec.precision}|{self.backend}]",
-            runtime_s=time.perf_counter() - t0, tile_lanes=bl,
-            extra=extra)
+        with TraceAnnotation("engine.search", kind="qsweep"):
+            out = self._qsweep_exec(series, s, bound_plan_lanes)
+            if out is None:      # single-block bucket: exact outright
+                return self._search_profile(series, s)
+            pos, vals, bl, rl, n_true, extra = out
+            self.stats.searches += 1
+            return DiscordResult(
+                positions=pos, nnds=vals, calls=bl + rl, n=n_true, s=s,
+                method=f"qsweep[{self.spec.precision}|{self.backend}]",
+                runtime_s=time.perf_counter() - t0, tile_lanes=bl,
+                extra=extra)
 
     def _ring_exec(self, s: int, Lb: int, series_pad, n_valid):
         """One ring-plan invocation — the single source of the mesh
@@ -1490,16 +1532,19 @@ class DiscordEngine:
         ring-per-series layout still counts as one search)."""
         t0 = time.perf_counter()
         s = self.spec.s
-        prof, _ngh, lanes, Lb, ndev, n_true = self._ring_profile(series,
-                                                                 s)
-        pos, vals = topk_nonoverlapping(
-            np.where(np.isfinite(prof), prof, -np.inf), self.spec.k, s)
-        return DiscordResult(
-            positions=pos, nnds=vals, calls=lanes, n=n_true, s=s,
-            method=f"ring_mp[{ndev}dev|{self.backend}]",
-            runtime_s=time.perf_counter() - t0, tile_lanes=lanes,
-            extra={"backend": self.backend, "bucket": Lb, "ndev": ndev,
-                   "tile_lanes": lanes, "znorm": self.spec.znorm})
+        with TraceAnnotation("engine.search", kind="ring"):
+            prof, _ngh, lanes, Lb, ndev, n_true = self._ring_profile(
+                series, s)
+            pos, vals = topk_nonoverlapping(
+                np.where(np.isfinite(prof), prof, -np.inf), self.spec.k,
+                s)
+            return DiscordResult(
+                positions=pos, nnds=vals, calls=lanes, n=n_true, s=s,
+                method=f"ring_mp[{ndev}dev|{self.backend}]",
+                runtime_s=time.perf_counter() - t0, tile_lanes=lanes,
+                extra={"backend": self.backend, "bucket": Lb,
+                       "ndev": ndev, "tile_lanes": lanes,
+                       "znorm": self.spec.znorm})
 
     def _search_qsweep_ring(self, series) -> DiscordResult:
         """Quantized ring search: mesh-sharded bound pass
@@ -1643,43 +1688,44 @@ class DiscordEngine:
                 "per-rung results) or 'lb_abandon'/'lb' (sequential "
                 "rungs, LB-skipped when only the global top-k "
                 f"matters); got {schedule!r}")
-        lad = canonical_ladder(spec.windows if ladder is None
-                               else ladder)
-        x = np.asarray(series, np.float64).ravel()
-        L = x.shape[0]
-        if L < lad[-1] + 1:
-            raise ValueError(f"series of {L} points is too short for "
-                             f"the ladder's longest window {lad[-1]} "
-                             f"(spec.s={spec.s} / ladder={lad})")
-        if schedule != "ladder":
-            return self._search_pan_lb(x, lad, t0)
-        s0 = lad[0]
-        n0 = L - s0 + 1
-        Lb = length_bucket(L)
-        xp = _bucket_pad(x, Lb)
-        ndev = self.ndev if self.sharded else 1
-        if self.sharded:
-            plan = self._pan_sharded_plan(lad, Lb)
-            n_pad, nb_p = self._pan_row_geom(lad, Lb, ndev)
-            n_rows = nb_p * spec.block
-        else:
-            plan = self._pan_plan(lad, Lb)
-            n_rows = n_pad = self._n_pad(s0, Lb)
-        # neighbor ids stay on device: PanResult carries no neighbor
-        # info, so only the d2 profiles cross to the host
-        d2s, _args = plan(jnp.asarray(xp), np.int32(n0))
-        d2s = np.asarray(d2s, np.float64)
-        lanes = pan_lanes(lad, n_rows, n_pad)
-        pan = self._pan_finish(
-            x, lad, d2s, lanes=lanes, cells=n_rows * n_pad, Lb=Lb,
-            ndev=ndev,
-            method=(f"pan[{self.backend}]" if ndev == 1 else
-                    f"pan[{ndev}dev|{self.backend}]"),
-            extra={"independent_lanes": self._independent_lanes(lad, Lb),
-                   "schedule": "ladder"})
-        self.stats.searches += 1
-        self.stats.tile_lanes += lanes
-        return self._stamp_pan_runtime(pan, time.perf_counter() - t0)
+        with TraceAnnotation("engine.search", kind="pan"):
+            lad = canonical_ladder(spec.windows if ladder is None
+                                   else ladder)
+            x = np.asarray(series, np.float64).ravel()
+            L = x.shape[0]
+            if L < lad[-1] + 1:
+                raise ValueError(f"series of {L} points is too short for "
+                                 f"the ladder's longest window {lad[-1]} "
+                                 f"(spec.s={spec.s} / ladder={lad})")
+            if schedule != "ladder":
+                return self._search_pan_lb(x, lad, t0)
+            s0 = lad[0]
+            n0 = L - s0 + 1
+            Lb = length_bucket(L)
+            xp = _bucket_pad(x, Lb)
+            ndev = self.ndev if self.sharded else 1
+            if self.sharded:
+                plan = self._pan_sharded_plan(lad, Lb)
+                n_pad, nb_p = self._pan_row_geom(lad, Lb, ndev)
+                n_rows = nb_p * spec.block
+            else:
+                plan = self._pan_plan(lad, Lb)
+                n_rows = n_pad = self._n_pad(s0, Lb)
+            # neighbor ids stay on device: PanResult carries no neighbor
+            # info, so only the d2 profiles cross to the host
+            d2s, _args = plan(jnp.asarray(xp), np.int32(n0))
+            d2s = np.asarray(d2s, np.float64)
+            lanes = pan_lanes(lad, n_rows, n_pad)
+            pan = self._pan_finish(
+                x, lad, d2s, lanes=lanes, cells=n_rows * n_pad, Lb=Lb,
+                ndev=ndev,
+                method=(f"pan[{self.backend}]" if ndev == 1 else
+                        f"pan[{ndev}dev|{self.backend}]"),
+                extra={"independent_lanes": self._independent_lanes(lad, Lb),
+                       "schedule": "ladder"})
+            self.stats.searches += 1
+            self.stats.tile_lanes += lanes
+            return self._stamp_pan_runtime(pan, time.perf_counter() - t0)
 
     # -- the sequential LB-abandoning rung schedule --------------------
     def _rung_stats(self, x, cache: dict, s_r: int):
@@ -1911,42 +1957,43 @@ class DiscordEngine:
         spec = self.spec
         self._require_profile_plan("search_batched")
         t0 = time.perf_counter()
-        xb = np.atleast_2d(np.asarray(series_batch, np.float64))
-        B, L = xb.shape
-        if spec.multi_window:
-            return self._search_pan_batched(xb, t0)
-        s = spec.s
-        if L < s + 1:
-            raise ValueError(f"series of {L} points is too short for "
-                             f"window spec.s={s}")
-        if spec.precision != "f32":
-            return self._search_batched_qsweep(xb, t0)
-        if self.sharded:
-            return self._search_batched_sharded(xb, t0)
-        n_true = L - s + 1
-        Lb = length_bucket(L)
-        xbp = _bucket_pad(xb, Lb)
-        d2b, _argb = self._batched_plan(s, B, Lb)(jnp.asarray(xbp),
-                                                  np.int32(n_true))
-        profs = np.sqrt(np.asarray(d2b, np.float64)[:, :n_true])
-        elapsed = time.perf_counter() - t0
-        per_lanes = self._n_pad(s, Lb) ** 2
-        lanes = B * per_lanes
-        self.stats.searches += 1
-        self.stats.tile_lanes += lanes
-        out: List[DiscordResult] = []
-        for b in range(B):
-            prof = np.where(np.isfinite(profs[b]), profs[b], -np.inf)
-            pos, vals = topk_nonoverlapping(prof, spec.k, s)
-            out.append(DiscordResult(
-                positions=pos, nnds=vals, calls=per_lanes,
-                n=n_true, s=s, method=f"batched_mp[{self.backend}]",
-                runtime_s=elapsed, tile_lanes=per_lanes,
-                extra={"batch_size": B, "batch_index": b,
-                       "backend": self.backend, "bucket": Lb,
-                       "per_series_s": elapsed / B,
-                       "tile_lanes": lanes}))
-        return out
+        with TraceAnnotation("engine.search", kind="batched"):
+            xb = np.atleast_2d(np.asarray(series_batch, np.float64))
+            B, L = xb.shape
+            if spec.multi_window:
+                return self._search_pan_batched(xb, t0)
+            s = spec.s
+            if L < s + 1:
+                raise ValueError(f"series of {L} points is too short for "
+                                 f"window spec.s={s}")
+            if spec.precision != "f32":
+                return self._search_batched_qsweep(xb, t0)
+            if self.sharded:
+                return self._search_batched_sharded(xb, t0)
+            n_true = L - s + 1
+            Lb = length_bucket(L)
+            xbp = _bucket_pad(xb, Lb)
+            d2b, _argb = self._batched_plan(s, B, Lb)(jnp.asarray(xbp),
+                                                      np.int32(n_true))
+            profs = np.sqrt(np.asarray(d2b, np.float64)[:, :n_true])
+            elapsed = time.perf_counter() - t0
+            per_lanes = self._n_pad(s, Lb) ** 2
+            lanes = B * per_lanes
+            self.stats.searches += 1
+            self.stats.tile_lanes += lanes
+            out: List[DiscordResult] = []
+            for b in range(B):
+                prof = np.where(np.isfinite(profs[b]), profs[b], -np.inf)
+                pos, vals = topk_nonoverlapping(prof, spec.k, s)
+                out.append(DiscordResult(
+                    positions=pos, nnds=vals, calls=per_lanes,
+                    n=n_true, s=s, method=f"batched_mp[{self.backend}]",
+                    runtime_s=elapsed, tile_lanes=per_lanes,
+                    extra={"batch_size": B, "batch_index": b,
+                           "backend": self.backend, "bucket": Lb,
+                           "per_series_s": elapsed / B,
+                           "tile_lanes": lanes}))
+            return out
 
     def _search_batched_qsweep(self, xb: np.ndarray, t0: float
                                ) -> List[DiscordResult]:

@@ -149,6 +149,7 @@ def mp_block_pallas(q_chunks, qmu, qsig, qid, c_chunks, cmu, csig, cid,
                                block=block, n_valid=n_valid)
     dmin, darg = pl.pallas_call(
         kernel,
+        name="mp_block",
         grid=(nbq, nbc),
         in_specs=[
             pl.BlockSpec((None, 1, width), lambda i, j: (i, 0, 0)),
@@ -226,6 +227,7 @@ def qvc_block_pallas(qwin, qmu, qsig, qid, chunk, cmu, csig, cid, *,
     c_row = pl.BlockSpec((1, blk_p), lambda i: (0, 0))
     d2 = pl.pallas_call(
         kernel,
+        name="qvc_block",
         grid=(bq_p // blk_q,),
         in_specs=[
             pl.BlockSpec((blk_q, s_pad), lambda i: (i, 0)),

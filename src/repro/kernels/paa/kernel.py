@@ -50,6 +50,7 @@ def paa_sax_pallas(boxsum_pad, mu_pad, sig_pad, *, P: int, w: int,
     L = boxsum_pad.shape[0]
     return pl.pallas_call(
         kernel,
+        name="paa_sax",
         grid=grid,
         in_specs=[
             pl.BlockSpec((L,), lambda i: (0,)),          # boxsum resident

@@ -301,6 +301,7 @@ def tile_d2_pallas(qwin, qmu, qsig, qid, cwin, cmu, csig, cid, *,
     c_row = pl.BlockSpec((1, BLOCK_C), lambda i, j: (0, j))
     d2 = pl.pallas_call(
         kernel,
+        name="tile_d2",
         grid=grid,
         in_specs=[
             pl.BlockSpec((blk_q, s_p), lambda i, j: (i, 0)),
@@ -360,6 +361,7 @@ def dot_tile_pallas(q, c, *, interpret: bool | None = None):
     blk_q = min(bq_p, BLOCK_Q)
     dots = pl.pallas_call(
         _dot_tile_kernel,
+        name="dot_tile",
         grid=(bq_p // blk_q, bc_p // BLOCK_C),
         in_specs=[
             pl.BlockSpec((blk_q, w_p), lambda i, j: (i, 0)),
@@ -510,6 +512,7 @@ def bound_dot_pallas(q, c, *, precision: str, sq=None, sc=None,
     blk_q = min(bq_p, BLOCK_Q)
     dots = pl.pallas_call(
         _bound_dot_kernel_bf16,
+        name="bound_dot",
         grid=(bq_p // blk_q, bc_p // BLOCK_C),
         in_specs=[
             pl.BlockSpec((blk_q, w_p), lambda i, j: (i, 0)),

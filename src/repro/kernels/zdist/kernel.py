@@ -85,6 +85,7 @@ def zdist_min_pallas(qids, qwin, qmu, qsig, cwin, cmu, csig, *,
     )
     return pl.pallas_call(
         kernel,
+        name="zdist_min",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_q,), lambda i, j: (i,)),         # qid
