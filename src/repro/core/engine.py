@@ -307,7 +307,9 @@ class EngineStats:
     ``traces`` counts jit traces of the engine's compiled plans — the
     compile-once contract is ``traces == plans`` for the session.
     ``tile_lanes`` counts distance lanes swept through the tile
-    engine, the blocked analogue of the paper's distance calls.
+    engine, the blocked analogue of the paper's distance calls: on
+    ``pallas`` the profile kinds sweep only the live window blocks
+    (``_swept_cols``), elsewhere the bucket's padded grid.
     """
     traces: int = 0
     plans: int = 0
@@ -410,6 +412,16 @@ class DiscordEngine:
     def _n_pad(self, s: int, Lb: int) -> int:
         """Padded window count of bucket ``Lb`` (tile geometry)."""
         return plan_pad_geom(s, Lb, self.spec.block)
+
+    def _swept_cols(self, s: int, Lb: int, n_true: int) -> int:
+        """Candidate windows a profile-family plan sweeps per query row
+        for a record of ``n_true`` windows: the mpblock kernel's grid
+        stops at the live blocks (``pallas``, Eq. (3)); every other
+        path sweeps the bucket's padded width.  A full profile sweeps
+        the square of it."""
+        if self.backend == "pallas" and self.spec.znorm:
+            return ceil_div(n_true, self.spec.block) * self.spec.block
+        return self._n_pad(s, Lb)
 
     def _plan_key(self, key):
         """Full cache key of a plan: the session-invariant spec prefix
@@ -1337,7 +1349,7 @@ class DiscordEngine:
                 pos, vals = topk_nonoverlapping(
                     np.where(np.isfinite(prof), prof, -np.inf),
                     self.spec.k, s)
-                lanes = self._n_pad(s, Lb) ** 2
+                lanes = self._swept_cols(s, Lb, n_true) ** 2
                 self.stats.searches += 1
                 self.stats.tile_lanes += lanes
                 return DiscordResult(
@@ -1459,7 +1471,8 @@ class DiscordEngine:
             lo, hi, n_true, s, refine_many)
         # honest lanes: every executed refinement call sweeps a pair
         # of (block x n_pad) tiles, duplicate padding included
-        refine_lanes = ncalls * 2 * spec.block * n_pad
+        refine_lanes = (ncalls * 2 * spec.block
+                        * self._swept_cols(s, Lb, n_true))
         self.stats.tile_lanes += bound_lanes + refine_lanes
         prune = 1.0 - (n_ref / nb_live if nb_live else 0.0)
         extra = {"backend": self.backend, "bucket": Lb,
@@ -1900,7 +1913,7 @@ class DiscordEngine:
                 xp, np.int32(L - s_b + 1))
             evaluated[bad] = (np.asarray(d2_b, np.float64),
                               np.asarray(ngh_b, np.int64))
-            rung_lanes[bad] = self._n_pad(s_b, Lb) ** 2
+            rung_lanes[bad] = self._swept_cols(s_b, Lb, L - s_b + 1) ** 2
             lanes += rung_lanes[bad]
             resweeps += 1
 
@@ -1977,7 +1990,7 @@ class DiscordEngine:
                                                       np.int32(n_true))
             profs = np.sqrt(np.asarray(d2b, np.float64)[:, :n_true])
             elapsed = time.perf_counter() - t0
-            per_lanes = self._n_pad(s, Lb) ** 2
+            per_lanes = self._swept_cols(s, Lb, n_true) ** 2
             lanes = B * per_lanes
             self.stats.searches += 1
             self.stats.tile_lanes += lanes
@@ -2059,7 +2072,7 @@ class DiscordEngine:
             jnp.asarray(xbp), jnp.full((1,), n_true, jnp.int32))
         profs = np.sqrt(np.asarray(d2b, np.float64)[:B, :n_true])
         elapsed = time.perf_counter() - t0
-        per_lanes = self._n_pad(s, Lb) ** 2
+        per_lanes = self._swept_cols(s, Lb, n_true) ** 2
         lanes = Bp * per_lanes
         self.stats.searches += 1
         self.stats.tile_lanes += lanes
@@ -2337,7 +2350,7 @@ class DiscordStream:
                 _, per, n_sh = eng._shard_geom(s, Lb, ndev)
                 lanes = n_sh * per * ndev
             else:
-                lanes = eng._n_pad(s, Lb) ** 2
+                lanes = eng._swept_cols(s, Lb, n_new) ** 2
             return {"kind": "fill", "s": s, "Lb": Lb, "xp": xp,
                     "n_new": n_new, "lanes": lanes}
         n_tail = n_new - n_old
@@ -2765,9 +2778,10 @@ class PlanKindAudit:
     (the batch width of ``batched``/``*_mb`` kinds — their runtime
     accounting applies the ceil per series, then multiplies).
     ``lanes`` is the ``tile_lanes`` the runtime call site books for
-    the same geometry; ``repro.analysis.irlint`` asserts the traced
-    IR reproduces ``pattern`` exactly and that ``model_lanes()`` of
-    the traced dots equals ``lanes``.
+    the same geometry on ``xla`` (``pallas`` books the live blocks of
+    the profile kinds, ``_swept_cols``); ``repro.analysis.irlint``
+    asserts the traced IR reproduces ``pattern`` exactly and that
+    ``model_lanes()`` of the traced dots equals ``lanes``.
     """
     kind: str
     family: str          # "local" | "mb" | "ring"

@@ -201,6 +201,16 @@ class TileEngine:
             self.mu_pad = jnp.zeros(n_pad, jnp.float32)
             self.sig_pad = jnp.ones(n_pad, jnp.float32)
 
+    def live_blocks(self):
+        """Window blocks holding a window of the record,
+        ``ceil(n_valid / block)`` as an int32 scalar (traced with
+        ``n_valid``); None when the engine was built without a dynamic
+        n_valid, whose every block is live."""
+        if not self._dyn:
+            return None
+        nv = jnp.asarray(self.n_valid, jnp.int32)
+        return jnp.clip(ceil_div(nv, self.block), 1, self.nb)
+
     def _mask_ids(self, ids):
         """Remap plan-cache padding windows (id >= n_valid) to -1 so
         the backends' id mask retires them; identity when the engine
@@ -325,43 +335,48 @@ class TileEngine:
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Exact matrix profile (d2, neighbor) of the whole series: the
         :meth:`block_rows` of every query block."""
-        starts = jnp.arange(self.nb, dtype=jnp.int32) * self.block
-        d2b, argb = self.block_rows(starts, backend=backend,
-                                    interpret=interpret)
+        d2b, argb = self.block_rows(backend=backend, interpret=interpret)
         return d2b.reshape(-1)[:self.n], argb.reshape(-1)[:self.n]
 
-    def block_rows(self, starts, *, backend: Optional[str] = None,
+    def block_rows(self, starts=None, *, backend: Optional[str] = None,
                    interpret: Optional[bool] = None
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Row (min d2, neighbor) of the query blocks at the (traced,
-        block-aligned) ``starts`` against every window, each
-        ``(len(starts), block)``.  A row is computed the same way
-        whichever blocks are listed with it, so re-sweeping a few
-        blocks reproduces the full profile's rows bit for bit.
+        block-aligned) ``starts`` (None: every block, in order)
+        against every window, each ``(len(starts), block)``.  A row is
+        computed the same way whichever blocks are listed with it, so
+        re-sweeping a few blocks reproduces the full profile's rows bit
+        for bit.
 
         ``pallas`` dispatches to the mpblock kernel (window tiles built
-        in VMEM from per-block series chunks); other backends run a
-        blocked row sweep through the registry.  ``interpret``
-        overrides the pallas interpret-mode auto-detect (debug hook;
-        ignored by the other backends).  The kernel only speaks
-        Eq. (3), so ``znorm=False`` engines take the blocked sweep on
-        every backend.
+        in VMEM from per-block series chunks), whose grid stops at the
+        :meth:`live_blocks` on the candidate axis and, when every block
+        is listed, on the query axis; other backends run a blocked row
+        sweep through the registry.  ``interpret`` overrides the pallas
+        interpret-mode auto-detect (debug hook; ignored by the other
+        backends).  The kernel only speaks Eq. (3), so ``znorm=False``
+        engines take the blocked sweep on every backend.
         """
         backend = resolve_backend(backend or self.backend)
+        nb, blk = self.nb, self.block
+        every = starts is None
+        if every:
+            starts = jnp.arange(nb, dtype=jnp.int32) * blk
         if backend == "pallas" and self.znorm:
             from ..kernels.mpblock.kernel import mp_block_pallas
             if interpret is None:
                 interpret = default_interpret()
-            nb, blk = self.nb, self.block
             chunks = self.block_chunks()
             ids = self._mask_ids(jnp.arange(nb * blk, dtype=jnp.int32))
+            live = self.live_blocks()
             rows = starts // blk
             d2, arg = mp_block_pallas(
                 chunks[rows], self.mu_pad.reshape(nb, blk)[rows].ravel(),
                 self.sig_pad.reshape(nb, blk)[rows].ravel(),
                 ids.reshape(nb, blk)[rows].ravel(),
                 chunks, self.mu_pad, self.sig_pad, ids, s=self.s,
-                n_valid=self.n, block=blk, interpret=interpret)
+                n_valid=self.n, block=blk, nq=live if every else None,
+                nc=live, interpret=interpret)
             return d2.reshape(-1, blk), arg.reshape(-1, blk)
 
         cand = self.all_windows()

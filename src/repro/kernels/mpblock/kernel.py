@@ -11,17 +11,23 @@ past ``s``, and contracts two such tiles on the MXU.  Window lanes come
 out in reversed sample order; both sides of every contraction use the
 same order, so the dot products are unchanged.
 
-``mp_block_pallas`` runs the full (query block i, candidate block j)
-grid with ``j`` innermost.  Each grid step folds its tile's row
-(min, argmin) into output block ``i``, which only consecutive steps
-visit, so the accumulator never depends on an output block being read
-back from HBM.  Every tile of the square is computed: the d(a, b) =
-d(b, a) triangle would need a column accumulator revisited across the
-whole grid.
+``mp_block_pallas`` runs the (query block i, candidate block j) grid
+with ``j`` innermost.  Each grid step folds its tile's row (min,
+argmin) into output block ``i``, which only consecutive steps visit, so
+the accumulator never depends on an output block being read back from
+HBM.  The grid's bounds may be traced live block counts ``(nq, nc)``:
+a length-bucketed series passes the blocks that hold a window of the
+record, so the bucket's padding square is never swept.  Every tile
+past them is wholly padding (ids -1 or >= n_valid) and could only
+fold +inf into a row, never take, so the live rows are the same bit
+for bit; the query rows past ``nq`` come back (+inf, 0), as a padding
+row of the full grid does.  Inside the live grid every tile is
+computed: the d(a, b) = d(b, a) triangle would need a column
+accumulator revisited across the whole grid.
 
-Residency: per grid step two chunks (1, W) and the per-window stats of
-both blocks are double-buffered in VMEM, the query tile is cached in a
-(block, s_pad) scratch for the whole row of the grid, and the (block, W)
+Residency: per grid step, two chunks (1, W) and the per-window stats
+of both blocks are double-buffered in VMEM, the query tile is cached in
+a (block, s_pad) scratch for the whole row of the grid, and the (block, W)
 rotation staging plus the (block, block) distance tile are temporaries
 — about 3 MB at block=256, s_pad=384, independent of the series length.
 HBM holds the chunks (W / block times the series, 2.5x at those sizes)
@@ -119,8 +125,8 @@ def _mp_rows_kernel(qc_ref, qmu_ref, qsig_ref, qid_ref,
 
 
 def mp_block_pallas(q_chunks, qmu, qsig, qid, c_chunks, cmu, csig, cid,
-                    *, s: int, n_valid: int, block: int,
-                    interpret: bool = True):
+                    *, s: int, n_valid: int, block: int, nq=None,
+                    nc=None, interpret: bool = True):
     """Row (min d2, argmin id) of every query window over every
     candidate window, with the windows built in-kernel from chunks.
 
@@ -128,9 +134,12 @@ def mp_block_pallas(q_chunks, qmu, qsig, qid, c_chunks, cmu, csig, cid,
     :func:`block_chunks`.  qmu/qsig/qid (nbq*block,) and
     cmu/csig/cid (nbc*block,): per-window stats and *global* ids (ids
     outside [0, n_valid) are padding and never win).  The self-join
-    passes the same operands on both sides.  Returns (d2 (nbq*block,)
-    f32, neighbour id (nbq*block,) i32); a row with no unmasked
-    candidate keeps (+inf, 0).
+    passes the same operands on both sides.  ``nq`` / ``nc`` (int32
+    scalars, may be traced; None = all ``nbq`` / ``nbc``) bound the
+    grid to the leading query / candidate blocks: every block past
+    them must hold padding ids only.  Returns (d2 (nbq*block,) f32,
+    neighbour id (nbq*block,) i32); a row with no unmasked candidate,
+    and every row past ``nq``, keeps (+inf, 0).
     """
     nbq, width = q_chunks.shape
     nbc = c_chunks.shape[0]
@@ -150,7 +159,7 @@ def mp_block_pallas(q_chunks, qmu, qsig, qid, c_chunks, cmu, csig, cid,
     dmin, darg = pl.pallas_call(
         kernel,
         name="mp_block",
-        grid=(nbq, nbc),
+        grid=(nbq if nq is None else nq, nbc if nc is None else nc),
         in_specs=[
             pl.BlockSpec((None, 1, width), lambda i, j: (i, 0, 0)),
             row_spec, row_spec, row_spec,
@@ -166,6 +175,10 @@ def mp_block_pallas(q_chunks, qmu, qsig, qid, c_chunks, cmu, csig, cid,
         interpret=interpret,
     )(q_chunks.reshape(nbq, 1, width), rows(qmu), rows(qsig), rows(qid),
       c_chunks.reshape(nbc, 1, width), cols(cmu), cols(csig), cols(cid))
+    if nq is not None:                  # rows past nq were never written
+        live = (jnp.arange(nbq) < nq)[:, None, None]
+        dmin = jnp.where(live, dmin, BIG)
+        darg = jnp.where(live, darg, 0)
     return dmin.reshape(-1), darg.reshape(-1)
 
 

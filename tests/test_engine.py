@@ -154,6 +154,30 @@ def test_stream_append_parity_every_backend(backend):
     assert np.all(np.abs(ngh - np.arange(st.n_windows)) >= s)
 
 
+@pytest.mark.parametrize("backend", ("xla", "pallas"))
+def test_profile_tile_lanes_count_swept_blocks(backend):
+    """``tile_lanes`` books what the profile paths sweep: the mpblock
+    kernel's live blocks squared on ``pallas``, the bucket's padded
+    square on ``xla`` -- per search, per batched series and for a
+    stream's first fill."""
+    s, block, L = 32, 128, 700
+    eng = DiscordEngine(SearchSpec(s=s, k=1, method="matrix_profile",
+                                   backend=backend, block=block))
+    n_pad = eng._n_pad(s, length_bucket(L))
+    live = -(-(L - s + 1) // block) * block
+    swept = live ** 2 if backend == "pallas" else n_pad ** 2
+    assert live < n_pad
+    x = _series(3, L)
+    r = eng.search(x)
+    assert r.calls == r.tile_lanes == eng.stats.tile_lanes == swept
+    rs = eng.search_batched(np.stack([x, x[::-1]]))
+    assert [b.calls for b in rs] == [swept, swept]
+    assert eng.stats.tile_lanes == 3 * swept
+    st = eng.open_stream(history=x)
+    assert st.tile_lanes == swept
+    assert eng.stats.tile_lanes == 4 * swept
+
+
 def test_stream_sweeps_only_tail_rows():
     eng = DiscordEngine(SearchSpec(s=24, k=1, method="matrix_profile",
                                    backend="xla"))

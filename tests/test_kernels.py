@@ -70,6 +70,65 @@ def test_mpblock_earlier_block_neighbours_match_xla():
     assert np.array_equal(a_pl, a_xl)
 
 
+MP_S, MP_BLOCK, MP_NB = 40, 64, 8      # one bucket of 8 window blocks
+
+
+@pytest.mark.parametrize("n_valid", [
+    3 * MP_BLOCK, 3 * MP_BLOCK + 1, 3 * MP_BLOCK - 1,
+    MP_NB * MP_BLOCK, "profile_mb"])
+def test_mpblock_live_grid_matches_full_grid(n_valid, monkeypatch):
+    """Bounding the grid by the live blocks changes no row, bit for
+    bit, and leaves every dead row (+inf, 0): at the block edges, at
+    the bucket's full count (counts == nb), and per lane of the
+    micro-batched profile plan, whose lanes hold different counts."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import DiscordEngine, SearchSpec
+    from repro.core.tiles import TileEngine
+    from repro.kernels.common import ceil_div
+    from repro.kernels.mpblock.kernel import mp_block_pallas
+    rng = np.random.default_rng(7)
+    L = MP_NB * MP_BLOCK + MP_S - 1
+    x = np.sin(0.05 * np.arange(L)) + 0.3 * rng.normal(size=L)
+    if n_valid == "profile_mb":
+        nvs = [3 * MP_BLOCK + 1, MP_BLOCK - 1, MP_NB * MP_BLOCK,
+               5 * MP_BLOCK]
+
+        def sweep():
+            eng = DiscordEngine(SearchSpec(
+                s=MP_S, k=1, method="matrix_profile", backend="pallas",
+                block=MP_BLOCK))
+            return eng._profile_mb_plan(MP_S, L, len(nvs))(
+                jnp.asarray(np.stack([x] * len(nvs)), jnp.float32),
+                jnp.asarray(nvs, jnp.int32))
+
+        got = sweep()
+        # the same plan over the bucket's full grid
+        monkeypatch.setattr(TileEngine, "live_blocks", lambda self: None)
+        ref = sweep()
+    else:
+        nvs = [n_valid]
+        eng = TileEngine(jnp.asarray(x, jnp.float32), MP_S,
+                         block=MP_BLOCK, backend="pallas",
+                         n_valid=n_valid)
+        ops = (eng.block_chunks(), eng.mu_pad, eng.sig_pad,
+               eng._mask_ids(jnp.arange(MP_NB * MP_BLOCK,
+                                        dtype=jnp.int32)))
+        rows = jax.jit(lambda n, *o: mp_block_pallas(
+            *o, *o, s=MP_S, n_valid=eng.n, block=MP_BLOCK, nq=n, nc=n))
+        got = [v[None] for v in rows(
+            jnp.int32(ceil_div(n_valid, MP_BLOCK)), *ops)]
+        ref = [v[None] for v in mp_block_pallas(
+            *ops, *ops, s=MP_S, n_valid=eng.n, block=MP_BLOCK)]
+    for b, nv in enumerate(nvs):
+        d2, arg = np.asarray(got[0][b]), np.asarray(got[1][b])
+        assert np.array_equal(d2, np.asarray(ref[0][b])), nv
+        assert np.array_equal(arg, np.asarray(ref[1][b])), nv
+        dead = ceil_div(nv, MP_BLOCK) * MP_BLOCK
+        assert np.all(np.isposinf(d2[dead:])) and np.all(arg[dead:] == 0)
+        assert np.isfinite(d2[:nv]).any(), nv
+
+
 @pytest.mark.parametrize("s,P,alpha", [(96, 4, 4), (120, 4, 3),
                                        (64, 8, 6), (150, 5, 4)])
 def test_paa_sax_words_match(s, P, alpha):

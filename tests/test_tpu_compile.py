@@ -66,6 +66,13 @@ def _kernel_case(name):
         return (lambda *a: mp_block_pallas(*a, s=S, n_valid=N_PAD,
                                            block=BLOCK, interpret=False),
                 window_set(N_PAD, (nb, width)) * 2)
+    if name == "mp_block_pallas[live]":
+        # traced live block counts bound the grid (bucket padding)
+        return (lambda *a: mp_block_pallas(*a[:8], s=S, n_valid=N_PAD,
+                                           block=BLOCK, nq=a[8],
+                                           nc=a[9], interpret=False),
+                window_set(N_PAD, (nb, width)) * 2
+                + [((), i32), ((), i32)])
     if name == "qvc_block_pallas":
         return (lambda *a: qvc_block_pallas(*a, s=S, n_valid=N_PAD,
                                             interpret=False),
@@ -86,8 +93,8 @@ def _kernel_case(name):
 
 
 @pytest.mark.parametrize("name", [
-    "mp_block_pallas", "qvc_block_pallas", "tile_d2_pallas",
-    "dot_tile_pallas", "bound_dot_pallas[bf16]"])
+    "mp_block_pallas", "mp_block_pallas[live]", "qvc_block_pallas",
+    "tile_d2_pallas", "dot_tile_pallas", "bound_dot_pallas[bf16]"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     import jax
     fn, shapes = _kernel_case(name)
