@@ -90,21 +90,25 @@ Spans (``jax.profiler.TraceAnnotation``): each entry that answers from
 a plan opens an ``engine.search`` span on the calling thread, which a
 profile puts on the device planes' clock.  Names are fixed strings and
 identifiers are stats: ``kind`` (``profile``, ``ring``, ``qsweep``,
-``batched``, ``pan``) on every one, and on the profile path ``search``
-(the ``stats.searches`` index the search takes), ``bucket`` and ``n``.
-An entry that calls another nests a second ``engine.search`` inside
-its own; readers take the outermost.
+``batched``, ``pan``) on every one; on the profile and ring paths
+``search`` (the ``stats.searches`` index the search takes), ``bucket``
+and ``n``; on the ring path ``ndev`` too.  An entry that calls another
+nests a second ``engine.search`` inside its own; readers take the
+outermost.
 
-On the profile path the span holds, in order and without overlap:
+On the profile and ring paths the span holds, in order and without
+overlap:
 ``engine.prepare``
-    f64 conversion, length check, bucket, padding, plan lookup;
+    f64 conversion, length check, bucket, padding (and on the profile
+    path the plan lookup);
 ``engine.dispatch``
     host-to-device copy and the plan's call, which returns before the
     device finishes (and traces and compiles on a cache miss);
 ``engine.wait``
-    the host blocked until the device's profile is ready;
+    the host blocked until the device's profile is ready (on the ring,
+    both sharded outputs: squared nnds and neighbours);
 ``engine.fetch``
-    the device-to-host copy of the profile;
+    the device-to-host copy of the profile (on the ring, of both);
 ``engine.select``
     square root, the non-overlapping top-k, the result.
 
@@ -1316,8 +1320,9 @@ class DiscordEngine:
                                 f"no extra kwargs, got {sorted(kw)}")
             if spec.precision != "f32":
                 return self._search_qsweep_ring(series)
+            res = self._search_ring(series)
             self.stats.searches += 1
-            return self._search_ring(series)
+            return res
         return self._dispatch(series, **kw)
 
     def _search_profile(self, series, s: int) -> DiscordResult:
@@ -1519,45 +1524,65 @@ class DiscordEngine:
         _, per, n_sh = self._shard_geom(s, Lb, ndev)
         return d2, arg, n_sh * per * ndev, ndev
 
-    def _ring_profile(self, series, s: int):
-        """Mesh-sharded exact (nnd, ngh) of every true window, through
-        the plan cache.  Returns ``(prof, ngh, lanes, Lb, ndev,
-        n_true)``."""
-        x = np.asarray(series, np.float64).ravel()
-        L = x.shape[0]
-        if L < s + 1:
-            raise ValueError(f"series of {L} points is too short for "
-                             f"window spec.s={s} (need at least "
-                             f"s + 1 points)")
-        n_true = L - s + 1
-        Lb = length_bucket(L)
-        xp = _bucket_pad(x, Lb)
-        d2, arg, lanes, ndev = self._ring_exec(s, Lb, jnp.asarray(xp),
-                                               np.int32(n_true))
-        prof = np.sqrt(np.asarray(d2, np.float64)[:n_true])
-        ngh = np.asarray(arg, np.int64)[:n_true]
+    def _ring_d2(self, series, s: int, span=None):
+        """Mesh-sharded exact squared nnd and neighbour of every true
+        window, through the plan cache, in the ``engine.prepare`` to
+        ``engine.fetch`` spans of the module docstring; ``span``, the
+        caller's ``engine.search``, is given ``bucket``, ``n`` and
+        ``ndev``.  Returns ``(d2, ngh, lanes, Lb, ndev, n_true)``, the
+        arrays on the host (f64 and i64)."""
+        with TraceAnnotation("engine.prepare"):
+            x = np.asarray(series, np.float64).ravel()
+            L = x.shape[0]
+            if L < s + 1:
+                raise ValueError(f"series of {L} points is too short for "
+                                 f"window spec.s={s} (need at least "
+                                 f"s + 1 points)")
+            n_true = L - s + 1
+            Lb = length_bucket(L)
+            xp = _bucket_pad(x, Lb)
+        with TraceAnnotation("engine.dispatch"):
+            d2, arg, lanes, ndev = self._ring_exec(s, Lb, jnp.asarray(xp),
+                                                   np.int32(n_true))
+        if span is not None:
+            span.set_metadata(bucket=Lb, n=n_true, ndev=ndev)
+        with TraceAnnotation("engine.wait"):
+            jax.block_until_ready((d2, arg))
+        with TraceAnnotation("engine.fetch"):
+            d2 = np.asarray(d2, np.float64)[:n_true]
+            ngh = np.asarray(arg, np.int64)[:n_true]
         self.stats.tile_lanes += lanes
-        return prof, ngh, lanes, Lb, ndev, n_true
+        return d2, ngh, lanes, Lb, ndev, n_true
+
+    def _ring_profile(self, series, s: int):
+        """:meth:`_ring_d2` with the nnd itself: ``(prof, ngh, lanes,
+        Lb, ndev, n_true)``."""
+        d2, *rest = self._ring_d2(series, s)
+        return (np.sqrt(d2), *rest)
 
     def _search_ring(self, series) -> DiscordResult:
-        """Top-k discords via the mesh-sharded ring plan.  Callers own
-        the ``stats.searches`` bump (one per API call, so a batched
-        ring-per-series layout still counts as one search)."""
+        """Top-k discords via the mesh-sharded ring plan, in the spans
+        of the module docstring.  Callers own the ``stats.searches``
+        bump (one per API call, so a batched ring-per-series layout
+        still counts as one search)."""
         t0 = time.perf_counter()
         s = self.spec.s
-        with TraceAnnotation("engine.search", kind="ring"):
-            prof, _ngh, lanes, Lb, ndev, n_true = self._ring_profile(
-                series, s)
-            pos, vals = topk_nonoverlapping(
-                np.where(np.isfinite(prof), prof, -np.inf), self.spec.k,
-                s)
-            return DiscordResult(
-                positions=pos, nnds=vals, calls=lanes, n=n_true, s=s,
-                method=f"ring_mp[{ndev}dev|{self.backend}]",
-                runtime_s=time.perf_counter() - t0, tile_lanes=lanes,
-                extra={"backend": self.backend, "bucket": Lb,
-                       "ndev": ndev, "tile_lanes": lanes,
-                       "znorm": self.spec.znorm})
+        with TraceAnnotation("engine.search", kind="ring",
+                             search=self.stats.searches) as span:
+            d2, _ngh, lanes, Lb, ndev, n_true = self._ring_d2(
+                series, s, span)
+            with TraceAnnotation("engine.select"):
+                prof = np.sqrt(d2)
+                pos, vals = topk_nonoverlapping(
+                    np.where(np.isfinite(prof), prof, -np.inf),
+                    self.spec.k, s)
+                return DiscordResult(
+                    positions=pos, nnds=vals, calls=lanes, n=n_true, s=s,
+                    method=f"ring_mp[{ndev}dev|{self.backend}]",
+                    runtime_s=time.perf_counter() - t0, tile_lanes=lanes,
+                    extra={"backend": self.backend, "bucket": Lb,
+                           "ndev": ndev, "tile_lanes": lanes,
+                           "znorm": self.spec.znorm})
 
     def _search_qsweep_ring(self, series) -> DiscordResult:
         """Quantized ring search: mesh-sharded bound pass
@@ -1578,8 +1603,9 @@ class DiscordEngine:
 
         out = self._qsweep_exec(series, s, bound_plan_lanes)
         if out is None:      # single-block bucket: exact outright
+            res = self._search_ring(series)
             self.stats.searches += 1
-            return self._search_ring(series)
+            return res
         pos, vals, bl, rl, n_true, extra = out
         extra["ndev"] = ndev
         self.stats.searches += 1

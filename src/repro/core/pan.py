@@ -12,11 +12,10 @@ module is that observation as a plan family:
 ``PanEngine``
     jit-safe sweep over a *ladder* ``(s_0 < s_1 < ... < s_{R-1})``:
 
-      * **one cumulative-sum pass** over the series yields the per-rung
-        ``mu``/``sigma`` (and raw window norms) for every ladder rung —
-        the same ``csum[s+i] - csum[i]`` arithmetic as
-        ``kernels.common.sliding_stats_jnp``, so in-range stats are
-        bit-identical to the single-length engine's;
+      * the per-rung ``mu``/``sigma`` (and raw window norms) come from
+        the block-centred window sums of
+        ``kernels.common.sliding_stats_jnp``, rung by rung, so in-range
+        stats are bit-identical to the single-length engine's;
       * per query block, the **base rung** pays one full-width dot tile
         (``dot_tile`` backend primitive, ``kernels.registry``) and each
         later rung only the ``(s_r - s_{r-1})``-wide *extension* tile,
@@ -171,16 +170,16 @@ class PanEngine:
         need = self.n_pad + smax - 1
         self.series_pad = jnp.pad(x, (0, max(0, need - x.shape[0])))
         self.n_valid = self.n if n_valid is None else n_valid
-        # one cumulative-sum pass -> every rung's stats, through the
-        # same stats_from_csums formula as sliding_stats_jnp — so
-        # in-range values are bit-identical to the single-length
-        # TileEngine's by construction.
-        csum, csum2 = series_csums(self.series_pad)
+        # every rung's stats through the same series_csums /
+        # stats_from_csums pass as sliding_stats_jnp — so in-range
+        # values are bit-identical to the single-length TileEngine's by
+        # construction.
         self.mu: List[jnp.ndarray] = []
         self.sig: List[jnp.ndarray] = []
         self.nrm: List[jnp.ndarray] = []        # raw ||window||^2
         for s in self.ladder:
-            mu, sig, nrm = stats_from_csums(csum, csum2, s, self.n_pad)
+            mu, sig, nrm = stats_from_csums(
+                series_csums(self.series_pad, s), s, self.n_pad)
             self.mu.append(mu)
             self.sig.append(sig)
             self.nrm.append(nrm)

@@ -57,33 +57,78 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def series_csums(series):
-    """Zero-prefixed cumulative sums of x and x² (f32) — the one pass
-    every sliding-stats consumer derives from."""
+def sum_block(s_max: int) -> int:
+    """Points per block of :func:`series_csums`: a power of two, at
+    least 1024 and at least the longest window, so that a window spans
+    at most two blocks."""
+    return max(1024, 1 << (int(s_max) - 1).bit_length())
+
+
+def series_csums(series, s: int):
+    """Block-centred prefix sums of a series for windows of ``s``
+    points, the one pass every sliding-stats consumer derives from:
+    ``(c, e1, e2, f1, f2)``.
+
+    The series (f32) is cut into blocks of ``B`` = :func:`sum_block`
+    points.  ``c`` (nb,) is the mean of each block's first window;
+    ``e1``/``e2`` (nb, B + 1) are the exclusive prefix sums of the
+    block's points less ``c`` and of their squares, and ``f1``/``f2``
+    (nb, s) those of the first ``s - 1`` points of the next block, less
+    the same ``c``.  So every sum has the size of one block's
+    deviations, whatever the series' length or level: prefix sums over
+    the whole series grow with both, and cost an f32 window statistic a
+    digit for every tenfold.  A window's sums read only its own points
+    and its block's first window, which every window of the block that
+    holds real data covers or follows, so padding after the data never
+    reaches them."""
     x = jnp.asarray(series, dtype=jnp.float32)
-    return (jnp.concatenate([jnp.zeros(1, x.dtype), jnp.cumsum(x)]),
-            jnp.concatenate([jnp.zeros(1, x.dtype),
-                             jnp.cumsum(x * x)]))
+    s = int(s)
+    B = sum_block(s)
+    nb = ceil_div(x.shape[0], B)
+    blocks = jnp.pad(x, (0, nb * B - x.shape[0])).reshape(nb, B)
+    nxt = jnp.pad(blocks[1:, :s - 1], ((0, 1), (0, 0)))
+    c = jnp.mean(blocks[:, :s], axis=1)[:, None]
+
+    def psum(v):
+        return jnp.pad(jnp.cumsum(v, axis=1), ((0, 0), (1, 0)))
+
+    y, z = blocks - c, nxt - c
+    return c[:, 0], psum(y), psum(y * y), psum(z), psum(z * z)
 
 
-def stats_from_csums(csum, csum2, s: int, n: int):
-    """(mu, clamped sigma, raw ||window||²) of the ``n`` windows of
-    length ``s`` from precomputed cumulative sums.  THE sliding-stats
-    formula — ``sliding_stats_jnp`` and the pan-length ladder both
-    delegate here, so per-rung stats are bit-identical to the
-    single-length engine's by construction."""
-    winsum = csum[s:s + n] - csum[:n]
-    winsum2 = csum2[s:s + n] - csum2[:n]
-    mu = winsum / s
-    var = jnp.maximum(winsum2 / s - mu * mu, 0.0)
-    return mu, jnp.maximum(jnp.sqrt(var), 1e-10), winsum2
+def stats_from_csums(sums, s: int, n: int):
+    """(mu, clamped sigma, raw ||window||²) of the first ``n`` windows
+    of length ``s`` from :func:`series_csums` made for ``s``.  THE
+    sliding-stats formula — ``sliding_stats_jnp`` and the pan-length
+    ladder both delegate here, so per-rung stats are bit-identical to
+    the single-length engine's by construction.
+
+    Window ``i = b B + j`` is summed about ``c[b]``: its points in
+    block ``b`` from ``e``, then the ``m = j + s - B`` points it reaches
+    into block ``b + 1`` from ``f``."""
+    c, e1, e2, f1, f2 = sums
+    nb, B = e1.shape[0], e1.shape[1] - 1
+    s = int(s)
+
+    def window(e, f):
+        last = jnp.broadcast_to(e[:, B:], (nb, s - 1))
+        head = jnp.concatenate([e[:, s:], last], axis=1) - e[:, :B]
+        return (head + jnp.pad(f[:, 1:], ((0, 0), (B - s + 1, 0)))
+                ).reshape(-1)[:n]
+
+    s1, s2 = window(e1, f1), window(e2, f2)
+    cb = jnp.repeat(c, B)[:n]
+    dev = s1 / s
+    var = jnp.maximum(s2 / s - dev * dev, 0.0)
+    return (cb + dev, jnp.maximum(jnp.sqrt(var), 1e-10),
+            s2 + cb * (2.0 * s1 + s * cb))
 
 
 def sliding_stats_jnp(series, s: int):
     """jnp twin of windows.sliding_stats (float32 path, clamped sigma)."""
     x = jnp.asarray(series, dtype=jnp.float32)
     n = x.shape[0] - s + 1
-    mu, sigma, _ = stats_from_csums(*series_csums(x), s, n)
+    mu, sigma, _ = stats_from_csums(series_csums(x, s), s, n)
     return mu, sigma
 
 
