@@ -92,9 +92,10 @@ profile puts on the device planes' clock.  Names are fixed strings and
 identifiers are stats: ``kind`` (``profile``, ``ring``, ``qsweep``,
 ``batched``, ``pan``) on every one; on the profile and ring paths
 ``search`` (the ``stats.searches`` index the search takes), ``bucket``
-and ``n``; on the ring path ``ndev`` too.  An entry that calls another
-nests a second ``engine.search`` inside its own; readers take the
-outermost.
+and ``n``; on the profile path ``grid`` too (``triangle`` or
+``square``, ``DiscordEngine._profile_grid``), on the ring path
+``ndev``.  An entry that calls another nests a second
+``engine.search`` inside its own; readers take the outermost.
 
 On the profile and ring paths the span holds, in order and without
 overlap:
@@ -140,7 +141,8 @@ from .pan import (PanEngine, canonical_ladder, cross_length_ub,
                   pan_rung_shares, pan_tail_sweep)
 from .result import DiscordResult, PanResult
 from .spec import SearchSpec, length_bucket
-from .tiles import TileEngine, exact_pair_d2, topk_nonoverlapping
+from .tiles import (TileEngine, exact_pair_d2, self_join_grid,
+                    self_join_lanes, topk_nonoverlapping)
 from .windows import sliding_stats
 
 __all__ = ["DiscordEngine", "DiscordStream", "PanStream", "EngineStats",
@@ -312,8 +314,9 @@ class EngineStats:
     compile-once contract is ``traces == plans`` for the session.
     ``tile_lanes`` counts distance lanes swept through the tile
     engine, the blocked analogue of the paper's distance calls: on
-    ``pallas`` the profile kinds sweep only the live window blocks
-    (``_swept_cols``), elsewhere the bucket's padded grid.
+    ``pallas`` the profile kinds sweep only the live window blocks,
+    as the upper triangle of their tiles where the bucket allows it
+    (``_profile_lanes``), elsewhere the bucket's padded square.
     """
     traces: int = 0
     plans: int = 0
@@ -426,6 +429,34 @@ class DiscordEngine:
         if self.backend == "pallas" and self.spec.znorm:
             return ceil_div(n_true, self.spec.block) * self.spec.block
         return self._n_pad(s, Lb)
+
+    def _profile_grid(self, s: int, Lb: int) -> str:
+        """Tile grid of a profile-family plan's self-join sweep in
+        bucket ``Lb`` (``tiles.self_join_grid``): ``"triangle"``, each
+        window pair once, or ``"square"``."""
+        block = self.spec.block
+        return self_join_grid(self._n_pad(s, Lb) // block, block,
+                              self.backend, self.spec.znorm)
+
+    def _profile_lanes(self, s: int, Lb: int, n_true: int) -> int:
+        """Tile lanes one full profile of ``n_true`` windows sweeps in
+        bucket ``Lb`` (``tiles.self_join_lanes``): the upper triangle
+        of the live blocks on the triangle grid; the square of
+        :meth:`_swept_cols` elsewhere."""
+        block = self.spec.block
+        return self_join_lanes(self._n_pad(s, Lb) // block, block,
+                               self.backend, self.spec.znorm,
+                               ceil_div(n_true, block))
+
+    def _pan_cells(self, s0: int, Lb: int) -> int:
+        """Tile cells one local ladder sweep (``PanEngine.profile``)
+        covers in bucket ``Lb`` at base window ``s0``: each window
+        pair once where the single-length self-join sweeps its
+        triangle, else the bucket's padded square
+        (``tiles.self_join_lanes`` over every block)."""
+        block = self.spec.block
+        return self_join_lanes(self._n_pad(s0, Lb) // block, block,
+                               self.backend, self.spec.znorm)
 
     def _plan_key(self, key):
         """Full cache key of a plan: the session-invariant spec prefix
@@ -732,11 +763,15 @@ class DiscordEngine:
             (d2 (2, block), ngh).
 
         Exact f32 re-sweep of a *pair* of query blocks against every
-        candidate — ``TileEngine.block_rows``, the computation the
-        ``("profile", ...)`` plan runs over all blocks, so refined rows
-        are bit-identical to a full profile sweep's.  The block starts
-        are traced operands, so one compiled plan refines any pair:
-        zero retraces across the escalation loop.  The fixed trip count
+        candidate — ``TileEngine.block_rows`` of listed blocks, which
+        computes every pair with the tile and operand roles that the
+        ``("profile", ...)`` plan's sweep of all blocks uses (on
+        ``pallas`` the triangle's: a pair with a lower block as a
+        column of that block's tile) and folds them in the same order,
+        so refined rows are bit-identical to a full profile sweep's.
+        The block starts are traced operands, so one compiled plan
+        refines any pair: zero retraces across the escalation loop.
+        The fixed trip count
         of 2 is load-bearing: XLA unrolls trip-count-1 loops into the
         enclosing computation and re-fuses the math into ulp-different
         results (observed in raw mode),
@@ -1340,7 +1375,8 @@ class DiscordEngine:
                         f"spec.s={s} (need at least s + 1 points)")
                 n_true = L - s + 1
                 Lb = length_bucket(L)
-                span.set_metadata(bucket=Lb, n=n_true)
+                grid = self._profile_grid(s, Lb)
+                span.set_metadata(bucket=Lb, n=n_true, grid=grid)
                 xp = _bucket_pad(x, Lb)
                 plan = self._profile_plan(s, Lb)
             with TraceAnnotation("engine.dispatch"):
@@ -1354,7 +1390,7 @@ class DiscordEngine:
                 pos, vals = topk_nonoverlapping(
                     np.where(np.isfinite(prof), prof, -np.inf),
                     self.spec.k, s)
-                lanes = self._swept_cols(s, Lb, n_true) ** 2
+                lanes = self._profile_lanes(s, Lb, n_true)
                 self.stats.searches += 1
                 self.stats.tile_lanes += lanes
                 return DiscordResult(
@@ -1363,7 +1399,7 @@ class DiscordEngine:
                     n=n_true, s=s, method=f"scamp[{self.backend}]",
                     runtime_s=time.perf_counter() - t0, tile_lanes=lanes,
                     extra={"backend": self.backend, "bucket": Lb,
-                           "tile_lanes": lanes,
+                           "tile_lanes": lanes, "grid": grid,
                            "znorm": self.spec.znorm})
 
     def _qsweep_select(self, lo_d2, hi_d2, n_true: int, s: int,
@@ -1746,21 +1782,22 @@ class DiscordEngine:
             if self.sharded:
                 plan = self._pan_sharded_plan(lad, Lb)
                 n_pad, nb_p = self._pan_row_geom(lad, Lb, ndev)
-                n_rows = nb_p * spec.block
+                cells = nb_p * spec.block * n_pad
             else:
                 plan = self._pan_plan(lad, Lb)
-                n_rows = n_pad = self._n_pad(s0, Lb)
+                cells = self._pan_cells(s0, Lb)
             # neighbor ids stay on device: PanResult carries no neighbor
             # info, so only the d2 profiles cross to the host
             d2s, _args = plan(jnp.asarray(xp), np.int32(n0))
             d2s = np.asarray(d2s, np.float64)
-            lanes = pan_lanes(lad, n_rows, n_pad)
+            lanes = pan_lanes(lad, 1, cells)
             pan = self._pan_finish(
-                x, lad, d2s, lanes=lanes, cells=n_rows * n_pad, Lb=Lb,
+                x, lad, d2s, lanes=lanes, cells=cells, Lb=Lb,
                 ndev=ndev,
                 method=(f"pan[{self.backend}]" if ndev == 1 else
                         f"pan[{ndev}dev|{self.backend}]"),
-                extra={"independent_lanes": self._independent_lanes(lad, Lb),
+                extra={"independent_lanes":
+                           self._independent_lanes(lad, Lb, L),
                        "schedule": "ladder"})
             self.stats.searches += 1
             self.stats.tile_lanes += lanes
@@ -1939,7 +1976,7 @@ class DiscordEngine:
                 xp, np.int32(L - s_b + 1))
             evaluated[bad] = (np.asarray(d2_b, np.float64),
                               np.asarray(ngh_b, np.int64))
-            rung_lanes[bad] = self._swept_cols(s_b, Lb, L - s_b + 1) ** 2
+            rung_lanes[bad] = self._profile_lanes(s_b, Lb, L - s_b + 1)
             lanes += rung_lanes[bad]
             resweeps += 1
 
@@ -1960,17 +1997,19 @@ class DiscordEngine:
                    "skipped_rungs": tuple(lad[r] for r in skipped),
                    "resweeps": resweeps,
                    "refine_calls": refine_calls,
-                   "ladder_lanes": pan_lanes(lad, n_pad, n_pad),
+                   "ladder_lanes": pan_lanes(lad, 1,
+                                             self._pan_cells(lad[0], Lb)),
                    "independent_lanes":
-                       self._independent_lanes(lad, Lb)})
+                       self._independent_lanes(lad, Lb, L)})
         self.stats.searches += 1
         self.stats.tile_lanes += lanes
         return self._stamp_pan_runtime(pan, time.perf_counter() - t0)
 
-    def _independent_lanes(self, ladder: tuple, Lb: int) -> int:
-        """What ``len(ladder)`` independent per-length profile sweeps
-        of the same bucket would cost — the pan sweep's baseline."""
-        return sum(self._n_pad(s, Lb) ** 2 for s in ladder)
+    def _independent_lanes(self, ladder: tuple, Lb: int, L: int) -> int:
+        """What ``len(ladder)`` independent local per-length profile
+        searches of a series of ``L`` points would sweep
+        (``_profile_lanes``) — the pan sweep's baseline."""
+        return sum(self._profile_lanes(s, Lb, L - s + 1) for s in ladder)
 
     def search_batched(self, series_batch
                        ) -> Union[List[DiscordResult], List[PanResult]]:
@@ -2016,7 +2055,7 @@ class DiscordEngine:
                                                       np.int32(n_true))
             profs = np.sqrt(np.asarray(d2b, np.float64)[:, :n_true])
             elapsed = time.perf_counter() - t0
-            per_lanes = self._swept_cols(s, Lb, n_true) ** 2
+            per_lanes = self._profile_lanes(s, Lb, n_true)
             lanes = B * per_lanes
             self.stats.searches += 1
             self.stats.tile_lanes += lanes
@@ -2098,7 +2137,7 @@ class DiscordEngine:
             jnp.asarray(xbp), jnp.full((1,), n_true, jnp.int32))
         profs = np.sqrt(np.asarray(d2b, np.float64)[:B, :n_true])
         elapsed = time.perf_counter() - t0
-        per_lanes = self._swept_cols(s, Lb, n_true) ** 2
+        per_lanes = self._profile_lanes(s, Lb, n_true)
         lanes = Bp * per_lanes
         self.stats.searches += 1
         self.stats.tile_lanes += lanes
@@ -2151,7 +2190,6 @@ class DiscordEngine:
                                batch_tile_lanes=total)
             return out
         ndev = self.ndev if self.sharded else 1
-        n_pad = self._n_pad(s0, Lb)
         if self.sharded:
             Bp = ceil_div(B, ndev) * ndev
             xbp = _bucket_pad(xb, Lb, rows=Bp)
@@ -2166,7 +2204,8 @@ class DiscordEngine:
             layout = "local"
             n_swept = B
         d2b = np.asarray(d2b, np.float64)
-        per_lanes = pan_lanes(lad, n_pad, n_pad)
+        cells = self._pan_cells(s0, Lb)
+        per_lanes = pan_lanes(lad, 1, cells)
         total = n_swept * per_lanes
         self.stats.searches += 1
         self.stats.tile_lanes += total
@@ -2177,12 +2216,12 @@ class DiscordEngine:
         for b in range(B):
             pan = self._pan_finish(
                 xb[b], lad, d2b[b], lanes=per_lanes,
-                cells=n_pad * n_pad, Lb=Lb, ndev=ndev, method=method,
+                cells=cells, Lb=Lb, ndev=ndev, method=method,
                 extra={"batch_size": B, "batch_index": b,
                        "layout": layout, "per_series_s": elapsed / B,
                        "batch_tile_lanes": total,
                        "independent_lanes":
-                           self._independent_lanes(lad, Lb),
+                           self._independent_lanes(lad, Lb, L),
                        "schedule": "ladder"})
             out.append(self._stamp_pan_runtime(pan, elapsed))
         return out
@@ -2376,7 +2415,7 @@ class DiscordStream:
                 _, per, n_sh = eng._shard_geom(s, Lb, ndev)
                 lanes = n_sh * per * ndev
             else:
-                lanes = eng._swept_cols(s, Lb, n_new) ** 2
+                lanes = eng._profile_lanes(s, Lb, n_new)
             return {"kind": "fill", "s": s, "Lb": Lb, "xp": xp,
                     "n_new": n_new, "lanes": lanes}
         n_tail = n_new - n_old
@@ -2664,13 +2703,13 @@ class PanStream:
         if not self._filled:          # first fill: one full ladder plan
             if self._sharded:
                 n_pad, nb_p = eng._pan_row_geom(lad, Lb, ndev)
-                n_rows = nb_p * eng.spec.block
+                cells = nb_p * eng.spec.block * n_pad
             else:
-                n_rows = n_pad = eng._n_pad(s0, Lb)
+                cells = eng._pan_cells(s0, Lb)
             return {"kind": "pan_fill", "ladder": lad, "Lb": Lb,
                     "xp": xp, "n_new": n_new,
-                    "shares": pan_rung_shares(lad, n_rows, n_pad),
-                    "cells": n_rows * n_pad}
+                    "shares": pan_rung_shares(lad, 1, cells),
+                    "cells": cells}
         # the tail's base-rung query ids span every rung's new
         # windows: rung r's start n_old - (s_r - s0) is smallest
         # at the longest rung
@@ -2805,7 +2844,7 @@ class PlanKindAudit:
     accounting applies the ceil per series, then multiplies).
     ``lanes`` is the ``tile_lanes`` the runtime call site books for
     the same geometry on ``xla`` (``pallas`` books the live blocks of
-    the profile kinds, ``_swept_cols``); ``repro.analysis.irlint``
+    the profile kinds, ``_profile_lanes``); ``repro.analysis.irlint``
     asserts the traced IR reproduces ``pattern`` exactly and that
     ``model_lanes()`` of the traced dots equals ``lanes``.
     """

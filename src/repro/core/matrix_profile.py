@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .result import DiscordResult
-from .tiles import TileEngine, resolve_backend, topk_nonoverlapping
+from .tiles import (TileEngine, resolve_backend, self_join_lanes,
+                    topk_nonoverlapping)
 
 
 # standalone one-shot baseline kept session-free on purpose (the
@@ -61,10 +62,9 @@ def discords_via_matrix_profile(series, s: int, k: int = 1, *,
     prof = np.asarray(d, np.float64)
     n = prof.shape[0]
     pos, vals = topk_nonoverlapping(prof, k, s)
-    # swept tile lanes (docs/cps.md): every backend, the pallas mpblock
-    # kernel included, sweeps the full block-aligned grid
-    n_pad = -(-n // block) * block
-    lanes = n_pad * n_pad
+    # swept tile lanes (docs/cps.md): the block-aligned grid's square,
+    # or its upper triangle where the pallas mpblock kernel sweeps that
+    lanes = self_join_lanes(-(-n // block), block, backend)
     return DiscordResult(positions=pos, nnds=vals,
                          calls=lanes,
                          n=n, s=s, method=f"scamp[{backend}]",

@@ -46,8 +46,11 @@ module is that observation as a plan family:
 Beyond the full-ladder sweep, ``PanEngine`` exposes the three sweep
 shapes the session layer's pan planes are built from:
 
-  * ``rows(starts)`` — the full-ladder profile sweep (``("pan", ...)``
-    plans, query blocks shardable across a mesh);
+  * ``profile()`` — the full-ladder profile sweep (``("pan", ...)``
+    plans), each window pair once where the single-length self-join
+    sweeps its upper triangle (``tiles.self_join_grid``); ``rows(starts)``
+    — the same sweep of listed query blocks against every candidate
+    (query blocks shardable across a mesh);
   * ``tail(qids, c0, n_cand)`` — a *streaming append* sweep: the new
     tail windows against a candidate id range, QT carried across rungs
     exactly like the full sweep, returning row **and** column minima
@@ -89,6 +92,7 @@ from ..kernels.common import (ceil_div, exclusion_mask,
                               raw_d2_from_dots, series_csums,
                               stats_from_csums, znorm_d2_formula)
 from ..kernels.registry import get_dot_backend, resolve_backend
+from .tiles import self_join_grid
 from .windows import sliding_stats
 
 __all__ = ["PanEngine", "canonical_ladder", "pan_lanes",
@@ -167,6 +171,10 @@ class PanEngine:
                                  f"block={self.block}")
             self.n_pad = int(n_pad)
             self.nb = self.n_pad // self.block
+        # the single-length self-join's tile grid: where it sweeps the
+        # upper triangle, so does :meth:`profile`
+        self.grid = self_join_grid(self.nb, self.block, self.backend,
+                                   self.znorm)
         need = self.n_pad + smax - 1
         self.series_pad = jnp.pad(x, (0, max(0, need - x.shape[0])))
         self.n_valid = self.n if n_valid is None else n_valid
@@ -330,12 +338,96 @@ class PanEngine:
     def profile(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """All rungs' full profiles: ``(d2, ngh)`` of shape
         ``(R, n_pad)`` (entries past rung r's own window count are
-        masked +inf)."""
+        masked +inf).  Each window pair once where ``self.grid`` is
+        the triangle (:meth:`_half_profile`), else every query block
+        against every candidate (:meth:`rows`)."""
+        if self.grid == "triangle":
+            return self._half_profile()
         starts = jnp.arange(self.nb, dtype=jnp.int32) * self.block
         d2, arg = self.rows(starts)             # (nb, R, block)
         R = len(self.ladder)
         return (d2.transpose(1, 0, 2).reshape(R, -1),
                 arg.transpose(1, 0, 2).reshape(R, -1))
+
+    def _half_profile(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """:meth:`profile` with each window pair computed once: the
+        ``nb (nb + 1) / 2`` tiles of the self-join's triangle, in
+        circulant order.  Query block ``i`` sweeps the candidate blocks
+        ``i, i + 1, ...`` (mod ``nb``), ``nb // 2 + 1`` of them, and one
+        fewer in the last ``nb / 2`` rows of an even ``nb``, so any two
+        blocks meet in exactly one tile.  A tile's row minima are its
+        query windows'; off the diagonal tile, its column minima fold
+        into an accumulator carried across the rows, and the two meet
+        at the end.  Every fold keeps the lowest minimizing id on a
+        tie, :meth:`rows`' ``argmin`` rule, and a window with no finite
+        distance reads neighbour 0, as there."""
+        dot = get_dot_backend(self.backend)
+        nb, blk, lad = self.nb, self.block, self.ladder
+        R, n_pad = len(lad), self.n_pad
+        base, exts, ids = self._cand_slab()
+        # doubled, so a row's wrapped run of candidate blocks is a slice
+        base, ids = (jnp.concatenate([v, v]) for v in (base, ids))
+        exts = [jnp.concatenate([e, e]) for e in exts]
+        none = jnp.iinfo(jnp.int32).max
+
+        def first_min(d2, who, axis):
+            m = jnp.min(d2, axis=axis)
+            return m, jnp.min(jnp.where(d2 == jnp.expand_dims(m, axis),
+                                        who, none), axis=axis)
+
+        def fold(a, b):
+            take = (b[0] < a[0]) | ((b[0] == a[0]) & (b[1] < a[1]))
+            return (jnp.where(take, b[0], a[0]),
+                    jnp.where(take, b[1], a[1]))
+
+        def sweep(width):
+            def row(col, i):
+                q0 = i * blk
+                qi = q0 + jnp.arange(blk, dtype=jnp.int32)
+                qs = jnp.clip(qi, 0, n_pad - 1)
+
+                def cand(v):
+                    return lax.dynamic_slice_in_dim(v, q0, width * blk)
+
+                cid = cand(ids)
+                cc = jnp.clip(cid, 0, n_pad - 1)
+                qt = dot(self._q_slab(qs, 0, lad[0]), cand(base))
+                rmin, cmin = [], []
+                for r in range(R):
+                    if r:
+                        qt = qt + dot(self._q_slab(qs, lad[r - 1], lad[r]),
+                                      cand(exts[r - 1]))
+                    d2 = self._rung_d2(qt, r, qs, cc, qi, cid)
+                    rmin.append(first_min(d2, cid[None, :], 1))
+                    if width > 1:   # the diagonal tile's columns are rows
+                        cmin.append(first_min(d2[:, blk:], qi[:, None], 0))
+                if width > 1:
+                    def at(v):
+                        return lax.dynamic_slice_in_dim(
+                            v, q0 + blk, (width - 1) * blk, axis=1)
+                    new = fold(tuple(at(v) for v in col),
+                               tuple(jnp.stack(v) for v in zip(*cmin)))
+                    col = tuple(lax.dynamic_update_slice_in_dim(
+                        v, n, q0 + blk, axis=1) for v, n in zip(col, new))
+                return col, tuple(jnp.stack(v) for v in zip(*rmin))
+            return row
+
+        h = nb // 2
+        groups = (((0, nb, h + 1),) if nb % 2 else
+                  ((0, h, h + 1), (h, nb, h)))
+        col = (jnp.full((R, 2 * n_pad), jnp.inf, jnp.float32),
+               jnp.full((R, 2 * n_pad), none, jnp.int32))
+        rows = []
+        for lo, hi, width in groups:
+            col, out = lax.scan(sweep(width), col,
+                                jnp.arange(lo, hi, dtype=jnp.int32))
+            rows.append(out)                    # (rows, R, block) each
+        row = tuple(jnp.concatenate(v).transpose(1, 0, 2).reshape(R, -1)
+                    for v in zip(*rows))
+        col = fold(tuple(v[:, :n_pad] for v in col),
+                   tuple(v[:, n_pad:] for v in col))
+        d2, ngh = fold(row, col)
+        return d2, jnp.where(d2 == jnp.inf, 0, ngh)
 
 
 def pan_tail_sweep(series_pad, ladder: Tuple[int, ...], q0, Qb: int, *,

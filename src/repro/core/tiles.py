@@ -37,7 +37,7 @@ from ..kernels.registry import (available_backends, get_backend,
 __all__ = [
     "TileBlock", "TileMins", "TileEngine", "tile_d2", "tile_mins",
     "set_row_mins", "pair_d2", "exact_pair_d2", "topk_nonoverlapping",
-    "batched_profile",
+    "batched_profile", "self_join_grid", "self_join_lanes",
     "resolve_backend", "available_backends", "register_backend",
 ]
 
@@ -87,6 +87,36 @@ def set_row_mins(q: tuple, c: tuple, *, s: int, n_valid, block: int,
     m = tile_mins(tile_d2(TileBlock(*q), TileBlock(*c), s=s,
                           n_valid=n_valid, backend=backend), q[3], c[3])
     return m.row_min, m.row_arg
+
+
+def self_join_grid(nb: int, block: int, backend: str,
+                   znorm: bool = True) -> str:
+    """Tile grid :meth:`TileEngine.block_rows` sweeps for the self-join
+    of ``nb`` window blocks on the (resolved) ``backend``:
+    ``"triangle"`` (each window pair once) on ``pallas`` with Eq. (3)
+    when its step table and column accumulator fit the core
+    (``kernels.mpblock.kernel.tri_fits``: up to 571 blocks), else
+    ``"square"``."""
+    from ..kernels.mpblock import kernel as mpk
+    if backend == "pallas" and znorm and mpk.tri_fits(nb, block):
+        return "triangle"
+    return "square"
+
+
+def self_join_lanes(nb: int, block: int, backend: str,
+                    znorm: bool = True, live=None) -> int:
+    """Tile lanes of one whole self-join of ``nb`` window blocks, of
+    which ``live`` (default all) hold a window, as
+    :meth:`TileEngine.block_rows` sweeps it: on ``pallas`` with Eq. (3)
+    the mpblock kernels stop at the live blocks and take the upper
+    triangle of their tiles, ``live (live + 1) / 2`` of them, where
+    :func:`self_join_grid` picks it, else their square; every other
+    path sweeps the square of all ``nb``."""
+    if live is None or not (backend == "pallas" and znorm):
+        live = nb
+    if self_join_grid(nb, block, backend, znorm) == "triangle":
+        return live * (live + 1) // 2 * block * block
+    return (live * block) ** 2
 
 
 def pair_d2(wa, wb, mu_a, sig_a, mu_b, sig_b, s: int, valid=None):
@@ -348,14 +378,20 @@ class TileEngine:
         re-sweeping a few blocks reproduces the full profile's rows bit
         for bit.
 
-        ``pallas`` dispatches to the mpblock kernel (window tiles built
-        in VMEM from per-block series chunks), whose grid stops at the
-        :meth:`live_blocks` on the candidate axis and, when every block
-        is listed, on the query axis; other backends run a blocked row
-        sweep through the registry.  ``interpret`` overrides the pallas
-        interpret-mode auto-detect (debug hook; ignored by the other
-        backends).  The kernel only speaks Eq. (3), so ``znorm=False``
-        engines take the blocked sweep on every backend.
+        ``pallas`` dispatches to the mpblock kernels (window tiles built
+        in VMEM from per-block series chunks), whose grids stop at the
+        :meth:`live_blocks`.  Every block listed, the self-join is swept
+        as its upper triangle (``mp_tri_pallas``), each pair once with
+        the lower block on the query side; listed blocks take the same
+        tiles in the same roles (``mp_rows_pallas``: pairs with a lower
+        block as the columns of its tile, the rest as rows), so their
+        rows are the triangle's bit for bit.  Buckets past
+        :func:`self_join_grid`'s bound sweep the square
+        (``mp_block_pallas``) either way.  Other backends run a blocked
+        row sweep through the registry.  ``interpret`` overrides the
+        pallas interpret-mode auto-detect (debug hook; ignored by the
+        other backends).  The kernels only speak Eq. (3), so
+        ``znorm=False`` engines take the blocked sweep on every backend.
         """
         backend = resolve_backend(backend or self.backend)
         nb, blk = self.nb, self.block
@@ -363,20 +399,27 @@ class TileEngine:
         if every:
             starts = jnp.arange(nb, dtype=jnp.int32) * blk
         if backend == "pallas" and self.znorm:
-            from ..kernels.mpblock.kernel import mp_block_pallas
+            from ..kernels.mpblock import kernel as mpk
             if interpret is None:
                 interpret = default_interpret()
             chunks = self.block_chunks()
             ids = self._mask_ids(jnp.arange(nb * blk, dtype=jnp.int32))
             live = self.live_blocks()
             rows = starts // blk
-            d2, arg = mp_block_pallas(
-                chunks[rows], self.mu_pad.reshape(nb, blk)[rows].ravel(),
-                self.sig_pad.reshape(nb, blk)[rows].ravel(),
-                ids.reshape(nb, blk)[rows].ravel(),
-                chunks, self.mu_pad, self.sig_pad, ids, s=self.s,
-                n_valid=self.n, block=blk, nq=live if every else None,
-                nc=live, interpret=interpret)
+            ops = (chunks, self.mu_pad, self.sig_pad, ids)
+            kw = dict(s=self.s, n_valid=self.n, block=blk,
+                      interpret=interpret)
+            if self_join_grid(nb, blk, backend) == "square":
+                def q(v):
+                    return v.reshape(nb, blk)[rows].ravel()
+                d2, arg = mpk.mp_block_pallas(
+                    chunks[rows], q(self.mu_pad), q(self.sig_pad), q(ids),
+                    *ops, nq=live if every else None, nc=live, **kw)
+            elif every:
+                d2, arg = mpk.mp_tri_pallas(*ops, nb_live=live, **kw)
+            else:
+                d2, arg = mpk.mp_rows_pallas(*ops, rows, nb_live=live,
+                                             **kw)
             return d2.reshape(-1, blk), arg.reshape(-1, blk)
 
         cand = self.all_windows()

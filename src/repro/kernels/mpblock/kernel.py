@@ -21,15 +21,25 @@ record, so the bucket's padding square is never swept.  Every tile
 past them is wholly padding (ids -1 or >= n_valid) and could only
 fold +inf into a row, never take, so the live rows are the same bit
 for bit; the query rows past ``nq`` come back (+inf, 0), as a padding
-row of the full grid does.  Inside the live grid every tile is
-computed: the d(a, b) = d(b, a) triangle would need a column
-accumulator revisited across the whole grid.
+row of the full grid does.  The ring's hops, which join two different
+window sets, run it.
+
+The self-join uses d(a, b) = d(b, a): ``mp_tri_pallas`` sweeps the
+upper triangle of tiles in ascending rows, each pair once, and keeps a
+column accumulator of every window in VMEM, which persists across the
+whole grid, so no output block is ever revisited: by the time row
+``i`` starts, its column minima are final and seed its row
+accumulator.  ``mp_rows_pallas`` gives listed rows of the same sweep
+bit for bit.  Buckets whose step table or accumulator would not fit
+(:func:`tri_fits`) keep the square.
 
 Residency: per grid step, two chunks (1, W) and the per-window stats
 of both blocks are double-buffered in VMEM, the query tile is cached in
 a (block, s_pad) scratch for the whole row of the grid, and the (block, W)
 rotation staging plus the (block, block) distance tile are temporaries
 — about 3 MB at block=256, s_pad=384, independent of the series length.
+The triangle adds its column accumulator, 8 bytes a window (1 MB for
+the 512 blocks of a 2^17 bucket).
 HBM holds the chunks (W / block times the series, 2.5x at those sizes)
 and the per-window stats.
 """
@@ -95,6 +105,70 @@ def _window_tile(chunk, block: int, s: int, s_pad: int):
     return jnp.where(lane >= s_pad - s, tile, 0.0)
 
 
+def _tile_d2(qtile_ref, cc_ref, qmu_ref, qsig_ref, qid_ref, cmu_ref,
+             csig_ref, cid_ref, *, s: int, s_pad: int, block: int,
+             n_valid: int):
+    """(masked (block, block) d2 tile, candidate ids (1, block)) of the
+    query window tile in ``qtile_ref`` (rows) against the candidate
+    block built from its chunk (lanes).  Every kernel here computes a
+    pair through this one function, with the lower block on the query
+    side wherever a pair is computed once, so a pair reads the same
+    bits whichever of those kernels computes it."""
+    ctile = _window_tile(cc_ref[...], block, s, s_pad)
+    dots = lax.dot_general(qtile_ref[...], ctile,
+                           (((1,), (1,)), ((), ())), precision=F32_DOT,
+                           preferred_element_type=jnp.float32)
+    d2 = znorm_d2_cols(dots, s, qmu_ref[...], qsig_ref[...],
+                       cmu_ref[...], csig_ref[...])
+    cid = cid_ref[...]
+    return (jnp.where(exclusion_mask_cols(qid_ref[...], cid, s, n_valid),
+                      BIG, d2), cid)
+
+
+def _fold_rows(d2, cid, dmin_ref, darg_ref):
+    """Fold a tile's row (min, first minimizing candidate) into the
+    (block, 1) row accumulator; strict ``<`` keeps the earlier fold on
+    a tie, so folding candidates in ascending id order keeps the
+    lowest minimizing id (argmin's tie rule, without argmin)."""
+    tmin = jnp.min(d2, axis=1, keepdims=True)
+    targ = jnp.min(jnp.where(d2 == tmin, cid, INT_MAX), axis=1,
+                   keepdims=True)
+    cur = dmin_ref[...]
+    take = tmin < cur
+    dmin_ref[...] = jnp.where(take, tmin, cur)
+    darg_ref[...] = jnp.where(take, targ, darg_ref[...])
+
+
+def _fold_cols(d2, qid, amin_ref, aarg_ref, row):
+    """Fold a tile's column (min, first minimizing query) into row
+    ``row`` of a lane-dense (rows, block) accumulator, with
+    :func:`_fold_rows`' tie rule."""
+    tmin = jnp.min(d2, axis=0, keepdims=True)
+    targ = jnp.min(jnp.where(d2 == tmin, qid, INT_MAX), axis=0,
+                   keepdims=True)
+    cur = amin_ref[pl.ds(row, 1), :]
+    take = tmin < cur
+    amin_ref[pl.ds(row, 1), :] = jnp.where(take, tmin, cur)
+    aarg_ref[pl.ds(row, 1), :] = jnp.where(take, targ,
+                                           aarg_ref[pl.ds(row, 1), :])
+
+
+def _lanes_to_rows(v, fill):
+    """(1, n) -> (n, 1), exactly: the diagonal of ``v`` broadcast over
+    rows (a select and a lane reduction, no transpose)."""
+    n = v.shape[1]
+    eye = (lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.min(jnp.where(eye, v, fill), axis=1, keepdims=True)
+
+
+def _start_row(amin_ref, aarg_ref, row, dmin_ref, darg_ref):
+    """Start a row accumulator from row ``row`` of a column
+    accumulator: the minima over every lower-indexed candidate block."""
+    dmin_ref[...] = _lanes_to_rows(amin_ref[pl.ds(row, 1), :], BIG)
+    darg_ref[...] = _lanes_to_rows(aarg_ref[pl.ds(row, 1), :], INT_MAX)
+
+
 def _mp_rows_kernel(qc_ref, qmu_ref, qsig_ref, qid_ref,
                     cc_ref, cmu_ref, csig_ref, cid_ref,
                     dmin_ref, darg_ref, qtile_ref, *,
@@ -105,23 +179,10 @@ def _mp_rows_kernel(qc_ref, qmu_ref, qsig_ref, qid_ref,
         dmin_ref[...] = jnp.full((block, 1), BIG, jnp.float32)
         darg_ref[...] = jnp.zeros((block, 1), jnp.int32)
 
-    ctile = _window_tile(cc_ref[...], block, s, s_pad)
-    dots = lax.dot_general(qtile_ref[...], ctile,
-                           (((1,), (1,)), ((), ())), precision=F32_DOT,
-                           preferred_element_type=jnp.float32)
-    d2 = znorm_d2_cols(dots, s, qmu_ref[...], qsig_ref[...],
-                       cmu_ref[...], csig_ref[...])
-    cid = cid_ref[...]
-    d2 = jnp.where(exclusion_mask_cols(qid_ref[...], cid, s, n_valid),
-                   BIG, d2)
-    tmin = jnp.min(d2, axis=1, keepdims=True)
-    # first minimizing candidate (argmin's tie rule, without argmin)
-    targ = jnp.min(jnp.where(d2 == tmin, cid, INT_MAX), axis=1,
-                   keepdims=True)
-    cur = dmin_ref[...]
-    take = tmin < cur
-    dmin_ref[...] = jnp.where(take, tmin, cur)
-    darg_ref[...] = jnp.where(take, targ, darg_ref[...])
+    d2, cid = _tile_d2(qtile_ref, cc_ref, qmu_ref, qsig_ref, qid_ref,
+                       cmu_ref, csig_ref, cid_ref, s=s, s_pad=s_pad,
+                       block=block, n_valid=n_valid)
+    _fold_rows(d2, cid, dmin_ref, darg_ref)
 
 
 def mp_block_pallas(q_chunks, qmu, qsig, qid, c_chunks, cmu, csig, cid,
@@ -176,10 +237,264 @@ def mp_block_pallas(q_chunks, qmu, qsig, qid, c_chunks, cmu, csig, cid,
     )(q_chunks.reshape(nbq, 1, width), rows(qmu), rows(qsig), rows(qid),
       c_chunks.reshape(nbc, 1, width), cols(cmu), cols(csig), cols(cid))
     if nq is not None:                  # rows past nq were never written
-        live = (jnp.arange(nbq) < nq)[:, None, None]
-        dmin = jnp.where(live, dmin, BIG)
-        darg = jnp.where(live, darg, 0)
+        dmin, darg = _dead_rows(jnp.arange(nbq) < nq, dmin, darg)
     return dmin.reshape(-1), darg.reshape(-1)
+
+
+#: VMEM the triangle's column accumulator may take: one f32 min and one
+#: i32 argmin per window of the bucket, lane-dense
+TRI_ACC_BYTES = 8 << 20
+
+#: SMEM the triangle's packed step table may take (a v5e core has 1 MiB
+#: of SMEM, which the kernel's other scalars share)
+STEP_TABLE_BYTES = 640 << 10
+
+
+def tri_fits(nb: int, block: int) -> bool:
+    """Whether a bucket of ``nb`` window blocks sweeps its self-join
+    as the upper triangle (:func:`mp_tri_pallas`, :func:`mp_rows_pallas`):
+    its step table, 4 bytes a step, fits :data:`STEP_TABLE_BYTES` and its
+    column accumulator, ``2 * nb * lane_pad(block) * 4`` bytes, fits
+    :data:`TRI_ACC_BYTES` -- up to 571 blocks, a 2^17 bucket at block
+    256.  Larger buckets keep :func:`mp_block_pallas` over the square."""
+    return (2 * nb * (nb + 1) <= STEP_TABLE_BYTES
+            and 2 * nb * lane_pad(block) * 4 <= TRI_ACC_BYTES)
+
+
+def _tri_step(t, live):
+    """(i, j) of step ``t`` of the upper-triangle grid over ``live``
+    blocks: rows ``i`` ascending, ``j = i .. live - 1`` ascending in
+    each.  Counted from the end, rows hold 1, 2, 3, ... steps, so the
+    ``u``-th step from the end lies in the row ``r`` from the end with
+    ``r (r + 1) / 2 <= u``: a square root, then one exact step each way
+    for its rounding."""
+    u = ((live * (live + 1)) >> 1) - 1 - t
+
+    def tri(v):
+        return (v * (v + 1)) >> 1
+
+    r = ((jnp.sqrt((8 * u + 1).astype(jnp.float32)) - 1.0)
+         * 0.5).astype(jnp.int32)
+    r = jnp.where(tri(r + 1) <= u, r + 1, r)
+    r = jnp.where(tri(r) > u, r - 1, r)
+    return live - 1 - r, live - 1 - (u - tri(r))
+
+
+def _tri_table(nb: int, live):
+    """Step table of the triangle grid over ``live`` (int32, may be
+    traced) of ``nb`` blocks: every step's ``i << 16 | j``, made by
+    :func:`_tri_step` outside the kernel and prefetched to SMEM, so a
+    step's index maps read one word (:func:`_tri_ij`)."""
+    t = jnp.arange(nb * (nb + 1) // 2, dtype=jnp.int32)
+    i, j = _tri_step(jnp.minimum(t, ((live * (live + 1)) >> 1) - 1), live)
+    return (i << 16) | j
+
+
+def _tri_ij(t, ref):
+    """(i, j) of triangle step ``t`` from the step table ``ref``."""
+    v = ref[t]
+    return v >> 16, v & 0xFFFF
+
+
+def _load_query(qc_ref, qmu_ref, qsig_ref, qid_ref, qtile_ref, qmu_s,
+                qsig_s, qid_s, *, s: int, s_pad: int, block: int):
+    """Query side of a self-join kernel: the window tile of the chunk,
+    and the block's lane-dense stats as the (block, 1) rows that
+    :func:`_tile_d2` reads."""
+    qtile_ref[...] = _window_tile(qc_ref[...], block, s, s_pad)
+    qmu_s[...] = _lanes_to_rows(qmu_ref[...], BIG)
+    qsig_s[...] = _lanes_to_rows(qsig_ref[...], BIG)
+    qid_s[...] = _lanes_to_rows(qid_ref[...], INT_MAX)
+
+
+def _mp_tri_kernel(step_ref, qc_ref, qmu_ref, qsig_ref, qid_ref,
+                   cc_ref, cmu_ref, csig_ref, cid_ref,
+                   dmin_ref, darg_ref, qtile_ref, qmu_s, qsig_s, qid_s,
+                   amin_ref, aarg_ref, *, s: int, s_pad: int,
+                   block: int, n_valid: int):
+    t = pl.program_id(0)
+    i, j = _tri_ij(t, step_ref)
+
+    @pl.when(t == 0)
+    def _clear():
+        amin_ref[...] = jnp.full(amin_ref.shape, BIG, jnp.float32)
+        aarg_ref[...] = jnp.zeros(aarg_ref.shape, jnp.int32)
+
+    @pl.when(j == i)        # first step of row i: its columns are final
+    def _init():
+        _load_query(qc_ref, qmu_ref, qsig_ref, qid_ref, qtile_ref, qmu_s,
+                    qsig_s, qid_s, s=s, s_pad=s_pad, block=block)
+        _start_row(amin_ref, aarg_ref, i, dmin_ref, darg_ref)
+
+    d2, cid = _tile_d2(qtile_ref, cc_ref, qmu_s, qsig_s, qid_s,
+                       cmu_ref, csig_ref, cid_ref, s=s, s_pad=s_pad,
+                       block=block, n_valid=n_valid)
+    _fold_rows(d2, cid, dmin_ref, darg_ref)
+
+    @pl.when(j > i)         # the diagonal tile's rows cover its columns
+    def _cols():
+        _fold_cols(d2, qid_s[...], amin_ref, aarg_ref, j)
+
+
+def _self_join_call(kernel, name, index, grid, out_spec, n_out,
+                    acc_rows, semantics, chunks, mu, sig, ids, prefetch, *,
+                    block: int, s_pad: int, interpret: bool):
+    """``pallas_call`` of a self-join kernel whose ``index(*grid_ids,
+    ref)`` gives the (query, candidate) blocks of a step: the chunk and
+    lane-dense stats of both blocks, from one copy of the operands;
+    ``n_out`` row output blocks; scratch for the query tile and its
+    (block, 1) stats, and a lane-dense column accumulator of
+    ``acc_rows`` blocks."""
+    nb, width = chunks.shape
+
+    def q(*a):
+        return (index(*a)[0], 0, 0)
+
+    def c(*a):
+        return (index(*a)[1], 0, 0)
+
+    blocks = [v.reshape(nb, 1, -1) for v in (chunks, mu, sig, ids)]
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[pl.BlockSpec((None, 1, width), q),
+                      *[pl.BlockSpec((None, 1, block), q)] * 3,
+                      pl.BlockSpec((None, 1, width), c),
+                      *[pl.BlockSpec((None, 1, block), c)] * 3],
+            out_specs=(out_spec, out_spec),
+            scratch_shapes=[pltpu.VMEM((block, s_pad), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.int32),
+                            pltpu.VMEM((acc_rows, block), jnp.float32),
+                            pltpu.VMEM((acc_rows, block), jnp.int32)]),
+        out_shape=(jax.ShapeDtypeStruct((n_out, block, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((n_out, block, 1), jnp.int32)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics),
+        interpret=interpret,
+    )(prefetch, *blocks, *blocks)
+
+
+def mp_tri_pallas(chunks, mu, sig, ids, *, s: int, n_valid: int,
+                  block: int, nb_live=None, interpret: bool = True):
+    """The self-join's row (min d2, argmin id), each window pair
+    computed once: the upper triangle of tiles ``(i, j)``, ``j >= i``.
+
+    Operands as :func:`mp_block_pallas`' self-join passes them, once:
+    ``chunks`` (nb, W), ``mu``/``sig``/``ids`` (nb*block,).  The grid
+    is 1-D and dense, ``L (L + 1) / 2`` steps for ``L = nb_live``
+    (int32, may be traced; None = ``nb``) live blocks, in ascending
+    rows ``i`` with ``j`` ascending in each (a step table in SMEM,
+    :func:`_tri_table`); block ``i`` is always the query side.  A step
+    folds its tile's row minima into output block ``i``, which only
+    consecutive steps visit, and, off the diagonal, its column (min,
+    first minimizing row id) into a VMEM accumulator of every window,
+    lane-dense, that persists across the grid.  Every row ``i' < i``
+    has passed column block ``i`` before row ``i`` starts, so row ``i``
+    starts from those column minima and not from +inf.  Both folds
+    keep the lowest minimizing id, the square's tie rule: the column
+    side holds the lower ids and is folded first.  A pair's distance
+    can differ from the square's in its last bits, since the square
+    also computes it the other way round.  Rows past ``nb_live`` come
+    back (+inf, 0).  Buckets past :func:`tri_fits` keep
+    :func:`mp_block_pallas`.
+    """
+    nb, width = chunks.shape
+    s_pad = lane_pad(s)
+    assert width == chunk_width(block, s_pad), (width, block, s_pad)
+    assert tri_fits(nb, block), (nb, block)
+    live = jnp.asarray(nb if nb_live is None else nb_live, jnp.int32)
+    kernel = functools.partial(_mp_tri_kernel, s=s, s_pad=s_pad,
+                               block=block, n_valid=n_valid)
+    dmin, darg = _self_join_call(
+        kernel, "mp_tri", _tri_ij,
+        (nb * (nb + 1) // 2 if nb_live is None
+         else (live * (live + 1)) // 2,),
+        pl.BlockSpec((None, block, 1),
+                     lambda t, ref: (_tri_ij(t, ref)[0], 0, 0)),
+        nb, nb, ("arbitrary",), chunks, mu, sig, ids,
+        _tri_table(nb, live),
+        block=block, s_pad=s_pad, interpret=interpret)
+    if nb_live is not None:             # rows past it were never written
+        dmin, darg = _dead_rows(jnp.arange(nb) < live, dmin, darg)
+    return dmin.reshape(-1), darg.reshape(-1)
+
+
+def _mp_listed_kernel(rows_ref, qc_ref, qmu_ref, qsig_ref, qid_ref,
+                      cc_ref, cmu_ref, csig_ref, cid_ref,
+                      dmin_ref, darg_ref, qtile_ref, qmu_s, qsig_s, qid_s,
+                      amin_ref, aarg_ref, *, s: int, s_pad: int,
+                      block: int, n_valid: int):
+    r = rows_ref[pl.program_id(0)]
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _clear():
+        amin_ref[...] = jnp.full(amin_ref.shape, BIG, jnp.float32)
+        aarg_ref[...] = jnp.zeros(aarg_ref.shape, jnp.int32)
+
+    @pl.when(j <= r)        # the query block changes: j, then r
+    def _query():
+        _load_query(qc_ref, qmu_ref, qsig_ref, qid_ref, qtile_ref, qmu_s,
+                    qsig_s, qid_s, s=s, s_pad=s_pad, block=block)
+
+    d2, cid = _tile_d2(qtile_ref, cc_ref, qmu_s, qsig_s, qid_s,
+                       cmu_ref, csig_ref, cid_ref, s=s, s_pad=s_pad,
+                       block=block, n_valid=n_valid)
+
+    @pl.when(j < r)         # tile (j, r): row r's pairs as its columns
+    def _cols():
+        _fold_cols(d2, qid_s[...], amin_ref, aarg_ref, 0)
+
+    @pl.when(j == r)
+    def _init():
+        _start_row(amin_ref, aarg_ref, 0, dmin_ref, darg_ref)
+
+    @pl.when(j >= r)        # tile (r, j): row r's pairs as its rows
+    def _rows():
+        _fold_rows(d2, cid, dmin_ref, darg_ref)
+
+
+def mp_rows_pallas(chunks, mu, sig, ids, rows, *, s: int, n_valid: int,
+                   block: int, nb_live=None, interpret: bool = True):
+    """The rows of :func:`mp_tri_pallas` at the listed query blocks
+    ``rows`` (P,) (int32, may be traced), bit for bit: row ``r`` takes
+    its pairs with blocks ``j < r`` as the columns of tile ``(j, r)``
+    and those with ``j >= r`` as the rows of tile ``(r, j)``, the
+    operand roles of the triangle, folded ``j`` ascending with its tie
+    rule.  Grid ``(P, nb_live)``; returns ((P*block,) d2, ids), and
+    (+inf, 0) for a listed block at or past ``nb_live``."""
+    nb, width = chunks.shape
+    s_pad = lane_pad(s)
+    assert width == chunk_width(block, s_pad), (width, block, s_pad)
+    rows = jnp.asarray(rows, jnp.int32)
+
+    def index(p, j, rows_ref):
+        r = rows_ref[p]
+        return jnp.minimum(j, r), jnp.maximum(j, r)
+
+    kernel = functools.partial(_mp_listed_kernel, s=s, s_pad=s_pad,
+                               block=block, n_valid=n_valid)
+    dmin, darg = _self_join_call(
+        kernel, "mp_rows", index,
+        (rows.shape[0], nb if nb_live is None else nb_live),
+        pl.BlockSpec((None, block, 1), lambda p, j, rr: (p, 0, 0)),
+        rows.shape[0], 1, ("parallel", "arbitrary"), chunks, mu, sig,
+        ids, rows, block=block, s_pad=s_pad, interpret=interpret)
+    if nb_live is not None:             # such rows were never written
+        dmin, darg = _dead_rows(rows < nb_live, dmin, darg)
+    return dmin.reshape(-1), darg.reshape(-1)
+
+
+def _dead_rows(live, dmin, darg):
+    """(+inf, 0) in the output blocks where ``live`` (per block) is
+    false."""
+    live = live[:, None, None]
+    return jnp.where(live, dmin, BIG), jnp.where(live, darg, 0)
 
 
 def _qvc_tile_kernel(q_ref, qmu_ref, qsig_ref, qid_ref,

@@ -156,17 +156,18 @@ def test_stream_append_parity_every_backend(backend):
 
 @pytest.mark.parametrize("backend", ("xla", "pallas"))
 def test_profile_tile_lanes_count_swept_blocks(backend):
-    """``tile_lanes`` books what the profile paths sweep: the mpblock
-    kernel's live blocks squared on ``pallas``, the bucket's padded
-    square on ``xla`` -- per search, per batched series and for a
-    stream's first fill."""
+    """``tile_lanes`` books what the profile paths sweep: the upper
+    triangle of the mpblock kernel's live blocks on ``pallas``, the
+    bucket's padded square on ``xla`` -- per search, per batched series
+    and for a stream's first fill."""
     s, block, L = 32, 128, 700
     eng = DiscordEngine(SearchSpec(s=s, k=1, method="matrix_profile",
                                    backend=backend, block=block))
     n_pad = eng._n_pad(s, length_bucket(L))
-    live = -(-(L - s + 1) // block) * block
-    swept = live ** 2 if backend == "pallas" else n_pad ** 2
-    assert live < n_pad
+    nb = -(-(L - s + 1) // block)
+    swept = (nb * (nb + 1) // 2 * block ** 2 if backend == "pallas"
+             else n_pad ** 2)
+    assert nb * block < n_pad
     x = _series(3, L)
     r = eng.search(x)
     assert r.calls == r.tile_lanes == eng.stats.tile_lanes == swept
@@ -176,6 +177,27 @@ def test_profile_tile_lanes_count_swept_blocks(backend):
     st = eng.open_stream(history=x)
     assert st.tile_lanes == swept
     assert eng.stats.tile_lanes == 4 * swept
+
+
+@pytest.mark.parametrize("backend", ("xla", "pallas"))
+def test_profile_calls_count_its_grid(backend):
+    """A profile search's ``calls`` is the tiles its grid sweeps: the
+    upper triangle of the live blocks on ``pallas`` (each window pair
+    once), the padded square on ``xla`` (each pair twice), and
+    ``extra["grid"]`` says which ran."""
+    s, block, L = 24, 64, 600
+    eng = DiscordEngine(SearchSpec(s=s, k=1, method="matrix_profile",
+                                   backend=backend, block=block))
+    r = eng.search(_series(4, L))
+    n = L - s + 1
+    nb = -(-n // block)
+    if backend == "pallas":
+        assert r.extra["grid"] == "triangle"
+        assert r.calls == nb * (nb + 1) // 2 * block ** 2
+        assert r.calls < n * n          # under the pairs counted twice
+    else:
+        assert r.extra["grid"] == "square"
+        assert r.calls == eng._n_pad(s, length_bucket(L)) ** 2
 
 
 def test_stream_sweeps_only_tail_rows():

@@ -185,6 +185,102 @@ def test_eight_rung_ladder_sweeps_under_0p6x_independent():
     assert pan.extra["independent_lanes"] == indep
 
 
+@pytest.mark.parametrize("backend", ("xla", "pallas"))
+def test_pan_lanes_count_its_grid(backend):
+    """The ladder books the cells its sweep covers: each window pair
+    once on ``pallas``, where the single-length self-join sweeps its
+    triangle (``nb (nb + 1) / 2`` tiles of the bucket), the padded
+    square on ``xla``; the independent baseline is what the
+    single-length searches book."""
+    block = 64
+    x = _series(9, 500)
+    eng = DiscordEngine(SearchSpec(s=LADDER, k=1, method="matrix_profile",
+                                   backend=backend, block=block))
+    pan = eng.search_pan(x)
+    nb = eng._n_pad(LADDER[0], 512) // block
+    cells = (nb * (nb + 1) // 2 * block ** 2 if backend == "pallas"
+             else (nb * block) ** 2)
+    assert pan.tile_lanes == pan_lanes(LADDER, 1, cells)
+    indep = 0
+    for s in LADDER:
+        one = DiscordEngine(SearchSpec(s=s, k=1, method="matrix_profile",
+                                       backend=backend, block=block))
+        one.search(x)
+        indep += one.stats.tile_lanes
+    assert pan.extra["independent_lanes"] == indep
+    assert pan.tile_lanes < indep
+
+
+def _pan_engine(x, ladder, block):
+    import jax.numpy as jnp
+    from repro.core.pan import PanEngine
+    peng = PanEngine(jnp.asarray(x, jnp.float32), ladder, block=block,
+                     backend="pallas", znorm=True)
+    assert peng.grid == "triangle"
+    return peng
+
+
+def _square_profile(peng):
+    """Every query block against every candidate (``PanEngine.rows``),
+    as (R, n_pad) d2 and neighbours."""
+    import jax.numpy as jnp
+    d2, arg = peng.rows(jnp.arange(peng.nb, dtype=jnp.int32) * peng.block)
+    R = len(peng.ladder)
+    return (np.asarray(d2).transpose(1, 0, 2).reshape(R, -1),
+            np.asarray(arg).transpose(1, 0, 2).reshape(R, -1))
+
+
+@pytest.mark.parametrize("nb", [1, 2, 3, 4, 5])
+def test_pan_half_profile_matches_square(nb):
+    """The half sweep (each window pair once, in circulant rows) at odd
+    and even block counts against the square: distances within f32
+    rounding and the same neighbours on a series without near-ties,
+    the padding windows (+inf, 0)."""
+    block, ladder = 32, (8, 12, 16)
+    x = np.random.default_rng(nb).normal(size=nb * block - 5)
+    peng = _pan_engine(x, ladder, block)
+    assert peng.nb == nb
+    d2, ngh = (np.asarray(v) for v in peng.profile())
+    sq_d2, sq_ngh = _square_profile(peng)
+    assert np.array_equal(np.isfinite(d2), np.isfinite(sq_d2))
+    live = np.isfinite(sq_d2)
+    assert live[0].any()
+    assert np.allclose(d2[live], sq_d2[live], rtol=1e-5, atol=1e-5)
+    assert np.array_equal(ngh, sq_ngh)
+    assert np.all(ngh[~live] == 0)
+
+
+def test_pan_half_profile_exact_ties_take_the_lowest_id():
+    """Planted exact ties: a periodic series of small multiples of 1/2
+    with neutral stats (mu 0, sigma 1) makes every dot product exact,
+    so a window's best candidate recurs once a period with the same
+    d2.  The half sweep keeps the lowest minimizing id on both of its
+    sides, as the square's argmin does, bit for bit at every rung."""
+    import jax.numpy as jnp
+    block, ladder, nb = 32, (8, 12, 16), 4
+    rng = np.random.default_rng(5)
+    period = block + 11
+    x = np.resize(0.5 * rng.integers(-2, 3, size=period),
+                  nb * block + ladder[0] - 1)
+    peng = _pan_engine(x, ladder, block)
+    peng.mu = [jnp.zeros_like(v) for v in peng.mu]
+    peng.sig = [jnp.ones_like(v) for v in peng.sig]
+    d2, ngh = (np.asarray(v) for v in peng.profile())
+    sq_d2, sq_ngh = _square_profile(peng)
+    assert np.array_equal(d2, sq_d2)
+    assert np.array_equal(ngh, sq_ngh)
+    # exact arithmetic at the base rung: the lowest id among the
+    # largest dots outside the exclusion band, tied on most windows
+    n0 = x.size - ladder[0] + 1
+    win = np.lib.stride_tricks.sliding_window_view(x, ladder[0])
+    dots = win @ win.T
+    ids = np.arange(n0)
+    dots[np.abs(ids[:, None] - ids[None, :]) < ladder[0]] = -np.inf
+    best = dots == dots.max(axis=1)[:, None]
+    assert np.mean(best.sum(axis=1) > 1) > 0.5
+    assert np.array_equal(ngh[0, :n0], np.argmax(best, axis=1))
+
+
 # ----------------------------------------------------------------------
 # cross-length lower bound
 # ----------------------------------------------------------------------

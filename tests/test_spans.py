@@ -99,6 +99,7 @@ def test_search_span_stats(traced, i):
     assert stats["kind"] == "profile"
     assert stats["n"] == LENGTHS[i] - S + 1
     assert stats["bucket"] == length_bucket(LENGTHS[i])
+    assert stats["grid"] == "square"            # xla sweeps the square
 
 
 @pytest.mark.parametrize("i", range(len(LENGTHS)))

@@ -50,6 +50,8 @@ def _kernel_case(name):
     import jax.numpy as jnp
     from repro.kernels.mpblock.kernel import (chunk_width, lane_pad,
                                               mp_block_pallas,
+                                              mp_rows_pallas,
+                                              mp_tri_pallas,
                                               qvc_block_pallas)
     from repro.kernels.registry import (bound_dot_pallas,
                                         dot_tile_pallas, tile_d2_pallas)
@@ -73,6 +75,18 @@ def _kernel_case(name):
                                            nc=a[9], interpret=False),
                 window_set(N_PAD, (nb, width)) * 2
                 + [((), i32), ((), i32)])
+    if name == "mp_tri_pallas[live]":
+        # the self-join's upper triangle, the profile plan's kernel
+        return (lambda *a: mp_tri_pallas(*a[:4], s=S, n_valid=N_PAD,
+                                         block=BLOCK, nb_live=a[4],
+                                         interpret=False),
+                window_set(N_PAD, (nb, width)) + [((), i32)])
+    if name == "mp_rows_pallas[live]":
+        # listed rows of the triangle, the refine plan's kernel
+        return (lambda *a: mp_rows_pallas(*a[:5], s=S, n_valid=N_PAD,
+                                          block=BLOCK, nb_live=a[5],
+                                          interpret=False),
+                window_set(N_PAD, (nb, width)) + [((2,), i32), ((), i32)])
     if name == "qvc_block_pallas":
         return (lambda *a: qvc_block_pallas(*a, s=S, n_valid=N_PAD,
                                             interpret=False),
@@ -92,13 +106,23 @@ def _kernel_case(name):
             [((BLOCK, S), f32), ((N_PAD, S), f32)])
 
 
+#: the self-join kernels, whose custom call ``mpblock_roofline`` finds
+#: by its (row min f32, argmin s32) pair of (blocks, block, 1) results
+ROW_PAIR_KERNELS = ("mp_block_pallas", "mp_block_pallas[live]",
+                    "mp_tri_pallas[live]", "mp_rows_pallas[live]")
+
+
 @pytest.mark.parametrize("name", [
-    "mp_block_pallas", "mp_block_pallas[live]", "qvc_block_pallas",
-    "tile_d2_pallas", "dot_tile_pallas", "bound_dot_pallas[bf16]"])
+    *ROW_PAIR_KERNELS, "qvc_block_pallas", "tile_d2_pallas",
+    "dot_tile_pallas", "bound_dot_pallas[bf16]"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     import jax
+    from bench.metrics.mpblock_roofline import KERNEL
     fn, shapes = _kernel_case(name)
     args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
             for shape, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    found = [ln for ln in text.splitlines()
+             if KERNEL.search(ln.strip().removeprefix("ROOT "))]
+    assert len(found) == (name in ROW_PAIR_KERNELS), found
