@@ -147,7 +147,7 @@ def phase_kernels(eng, x):
     Lb = length_bucket(len(x))
     xp = np.zeros(Lb, np.float32)
     xp[:len(x)] = x
-    plan = eng._profile_plan(S, Lb)
+    plan = eng.plan("profile", S, Lb)
     text = plan.lower(xp, np.int32(len(x) - S + 1)).compile().as_text()
     calls = text.count("tpu_custom_call")
     if calls == 0:

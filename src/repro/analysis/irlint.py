@@ -23,8 +23,8 @@ only the IR can prove:
     ``pure_callback`` / ``io_callback`` appear only in plans whose
     backend declares ``host_callback`` traits
     (``kernels.registry.backend_traits``) — i.e. the ``numpy``
-    reference backend — and never inside ``*_ring`` or ``*_mb`` plans
-    (the audit matrix simply has no numpy cells for those families:
+    reference backend — and never inside sharded or ``mb`` plans (the
+    audit matrix simply has no numpy cells for those layouts:
     coalesced and mesh plans are device-backend planes by contract).
 
 ``ir-const``
@@ -47,12 +47,15 @@ only the IR can prove:
     never reach the IR (both still get the dtype/callback/const
     rules).
 
-This module imports jax lazily — keep it off the lint-only path.
+Importing this module imports the engine (and jax) — keep it off
+the lint-only path.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+
+from repro.core.engine import PLAN_KINDS
 
 from .report import Finding
 
@@ -64,11 +67,10 @@ __all__ = ["DEFAULT_CONST_BYTES", "ZNORM_ONLY_KINDS", "audit_matrix",
 #: id/iota vectors the plans legitimately bake are ~1 KiB)
 DEFAULT_CONST_BYTES = 128 * 1024
 
-#: kinds whose spec cannot be built raw (znorm=False): the engine
-#: refuses raw ring/tail_ring outright, and qsweep_ring rides a
-#: method="ring" spec that spec validation rejects raw (the local
-#: qsweep kinds audit both modes — their bound body handles raw)
-ZNORM_ONLY_KINDS = frozenset({"ring", "tail_ring", "qsweep_ring"})
+#: kinds audited (and sanitized) in z-normalized mode only
+#: (``PLAN_KINDS[kind].znorm_only``)
+ZNORM_ONLY_KINDS = frozenset(k for k, pk in PLAN_KINDS.items()
+                             if pk.znorm_only)
 
 _CALLBACK_PRIMS = ("pure_callback", "io_callback")
 
@@ -200,17 +202,18 @@ def audit_matrix(registry, backends: Sequence[str]
                  ) -> List[Tuple[str, str, bool]]:
     """The (kind, backend, znorm) cells to audit.  Every kind on every
     device backend; the host-callback (numpy) backend only audits
-    local non-coalesced kinds — ``*_mb``/``*_ring`` plans are
+    local non-coalesced kinds — ``mb`` and sharded plans are
     device-backend planes by contract, which is exactly what lets the
     ``ir-callback`` rule be absolute for them.  Raw mode re-audits on
-    ``xla`` only (same dot decomposition; the engine refuses raw
-    ``ring``/``tail_ring``)."""
+    ``xla`` only (same dot decomposition; :data:`ZNORM_ONLY_KINDS`
+    are skipped)."""
     from ..kernels.registry import backend_traits
     cells: List[Tuple[str, str, bool]] = []
     for be in backends:
         host_cb = bool(backend_traits(be)["host_callback"])
         for e in registry.values():
-            if host_cb and e.family in ("mb", "ring"):
+            pk = PLAN_KINDS[e.kind]
+            if host_cb and (pk.sharded or pk.layout == "mb"):
                 continue
             cells.append((e.kind, be, True))
     if "xla" in backends:
@@ -218,6 +221,14 @@ def audit_matrix(registry, backends: Sequence[str]
             if e.kind not in ZNORM_ONLY_KINDS:
                 cells.append((e.kind, "xla", False))
     return cells
+
+
+def _template(kind: str) -> str:
+    """The :class:`_Engines` spec template a plan kind audits on."""
+    pk = PLAN_KINDS[kind]
+    t = ("qsweep" if pk.quant else
+         "pan" if pk.op.startswith("pan") else "mp")
+    return t + "_ndev" if pk.sharded else t
 
 
 class _Engines:
@@ -242,7 +253,6 @@ class _Engines:
             "mp": dict(s=self.s, method="matrix_profile"),
             "mp_ndev": dict(s=self.s, method="matrix_profile",
                             ndev=self.ndev),
-            "ring": dict(s=self.s, method="ring", ndev=self.ndev),
             "pan": dict(s=self.ladder, method="matrix_profile"),
             "pan_ndev": dict(s=self.ladder, method="matrix_profile",
                              ndev=self.ndev),
@@ -270,7 +280,7 @@ def _audit_cell(entry, eng, backend: str, znorm: bool, *,
     locus = f"{entry.kind}[{backend},znorm={znorm}]"
     findings: List[Finding] = []
     try:
-        fn = getattr(eng, entry.builder)(*entry.build_args)
+        fn = eng.plan(entry.kind, *entry.geom)
         avals = [jax.ShapeDtypeStruct(tuple(shape), np.dtype(dt))
                  for shape, dt in entry.avals]
         closed = jax.make_jaxpr(fn)(*avals)
@@ -373,7 +383,7 @@ def run_irlint(backends: Iterable[str] = ("numpy", "xla", "pallas"),
     for kind, backend, znorm in audit_matrix(registry,
                                              tuple(backends)):
         entry = registry[kind]
-        eng = engines.get(entry.spec_template, backend, znorm)
+        eng = engines.get(_template(kind), backend, znorm)
         f, meta = _audit_cell(entry, eng, backend, znorm,
                               const_bytes=const_bytes)
         findings.extend(f)
@@ -387,26 +397,28 @@ def run_irlint(backends: Iterable[str] = ("numpy", "xla", "pallas"),
 
 
 def coverage_audit() -> List[Finding]:
-    """Registry completeness: every ``DiscordEngine`` plan-builder
-    method must have a ``plan_kind_registry`` entry naming it (the
-    "discover, don't hard-code" contract) — a new ``*_plan`` builder
-    without an entry is a finding, as is a registry entry pointing at
-    a method that no longer exists."""
+    """Registry completeness against the table: every
+    :data:`PLAN_KINDS` kind has a ``plan_kind_registry`` entry (the
+    "discover, don't hard-code" contract), every entry is a table
+    kind, and every kind's op and layout are ``DiscordEngine``
+    methods ``plan`` can call."""
     from repro.core.engine import DiscordEngine, plan_kind_registry
-    builders = {name for name in dir(DiscordEngine)
-                if name.endswith("_plan") and name.startswith("_")
-                and not name.startswith(("_get", "_require"))
-                and callable(getattr(DiscordEngine, name))}
     registry = plan_kind_registry()
-    registered = {e.builder for e in registry.values()}
     findings: List[Finding] = []
-    for name in sorted(builders - registered):
-        findings.append(Finding(
-            "irlint", "ir-kind-coverage", f"core/engine.py::{name}", 0,
-            f"plan builder {name} has no plan_kind_registry entry — "
-            "the IR auditor cannot see it"))
-    for name in sorted(registered - builders):
-        findings.append(Finding(
-            "irlint", "ir-kind-coverage", f"core/engine.py::{name}", 0,
-            f"plan_kind_registry names missing builder {name}"))
+
+    def bad(kind: str, msg: str) -> None:
+        findings.append(Finding("irlint", "ir-kind-coverage",
+                                f"core/engine.py::{kind}", 0, msg))
+
+    for kind in sorted(set(PLAN_KINDS) - set(registry)):
+        bad(kind, f"plan kind {kind} has no plan_kind_registry entry "
+                  "— the IR auditor cannot see it")
+    for kind in sorted(set(registry) - set(PLAN_KINDS)):
+        bad(kind, f"plan_kind_registry names {kind}, which is not in "
+                  "PLAN_KINDS")
+    for kind, pk in PLAN_KINDS.items():
+        for name in ("_op_" + pk.op, "_layout_" + pk.layout):
+            if not callable(getattr(DiscordEngine, name, None)):
+                bad(kind, f"plan kind {kind} needs DiscordEngine.{name}, "
+                          "which does not exist")
     return findings

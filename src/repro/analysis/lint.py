@@ -21,8 +21,9 @@ Rules (docs/analysis.md has the full catalogue):
 ``host-sync``
     No host synchronization (``.item()``, ``np.*`` calls,
     ``block_until_ready``, ``jax.device_get``, ``float(...)``) inside
-    the plan-builder bodies of ``core/engine.py`` (``build()``
-    closures) or the jit-safe ``PanEngine`` methods of
+    the plan bodies of ``core/engine.py`` (the ``_op_*``, ``_rows_*``
+    and ``_layout_*`` methods and ``build()`` closures) or the
+    jit-safe ``PanEngine`` methods of
     ``core/pan.py`` — a sync there either breaks tracing or silently
     serializes every plan invocation.  The serve/telemetry dispatch
     paths (``DiscordServer._exec_group``,
@@ -185,14 +186,16 @@ class HostSyncRule(Rule):
         return relpath in self.SCOPE or relpath in self.DEFERRED
 
     def _traced_scopes(self, tree, relpath) -> Iterator[ast.AST]:
-        """The subtrees whose code runs under jit tracing: every
-        ``build()`` plan-builder closure in engine.py, every
-        ``PanEngine`` method in pan.py (PanEngine is constructed
+        """The subtrees whose code runs under jit tracing: in
+        engine.py every op body, row body and layout
+        (``_op_*``/``_rows_*``/``_layout_*``) and ``build()`` closure,
+        in pan.py every ``PanEngine`` method (PanEngine is constructed
         *inside* plan bodies)."""
         if relpath.endswith("engine.py"):
             for node in ast.walk(tree):
-                if isinstance(node, ast.FunctionDef) and \
-                        node.name == "build":
+                if isinstance(node, ast.FunctionDef) and (
+                        node.name == "build" or node.name.startswith(
+                            ("_op_", "_rows_", "_layout_"))):
                     yield node
         elif relpath.endswith("pan.py"):
             for node in ast.walk(tree):

@@ -13,18 +13,21 @@ reliance on "pad zeros are harmless" fails loudly instead of silently
 biasing a top-k.
 
 Plan-kind coverage (``ALL_KINDS``) spans the whole session surface:
-profile / batched / stream-tail / pan ladder / pan LB-abandon /
-pan-stream / pan-batched / quantized sweep (bound pass + exact
-refinement, docs/cps.md), each in its local and mesh-sharded form.
-Raw (``znorm=False``) skips the kinds the engine itself refuses
-to run sharded-raw (spec validation rejects raw ``ring``, hence also
-``qsweep_ring``; a raw sharded stream falls back to the local tail
-plan, already covered by ``tail``).  The quantized kinds are the
+every kind of the engine's ``PLAN_KINDS`` table is reached through one
+search entry point, local (``LOCAL_KINDS``) or mesh-sharded
+(``SHARDED_KINDS``) as the table says — profile / batched /
+stream-tail / pan ladder / pan LB-abandon / pan-stream / pan-batched /
+quantized sweep (bound pass + exact refinement, docs/cps.md).
+Raw (``znorm=False``) skips the table's ``znorm_only`` kinds (spec
+validation rejects raw ``ring``, hence also ``qsweep_ring``; a raw
+sharded stream falls back to the local tail plan, already covered by
+``tail``).  The quantized kinds are the
 sharpest cells here: a pad lane that leaks into the *bound* pass
 doesn't just bias a min — it can wrongly prune a block, so the
 bit-identical bar doubles as a prune-soundness probe.
 
-This module imports jax lazily — keep it off the lint-only path.
+Importing this module imports the engine (and jax) — keep it off
+the lint-only path.
 """
 from __future__ import annotations
 
@@ -33,18 +36,37 @@ import warnings
 from contextlib import contextmanager
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+from repro.core.engine import PLAN_KINDS
+
+from .irlint import ZNORM_ONLY_KINDS
 from .report import Finding
 
 __all__ = ["ALL_KINDS", "LOCAL_KINDS", "SHARDED_KINDS", "CANARIES",
            "pad_fill", "run_sanitizer", "selfcheck"]
 
-LOCAL_KINDS = ("profile", "batched", "tail", "pan", "pan_lb",
-               "pan_tail", "pan_batched", "qsweep", "qsweep_tail")
-SHARDED_KINDS = ("ring", "batched_ring", "tail_ring", "pan_ring",
-                 "pan_tail_ring", "pan_batched_ring", "qsweep_ring")
+#: plan kinds reached through another kind's entry point: the f32
+#: refinements run inside their bound pass's search, and the LB
+#: schedule ``pan_lb`` sweeps ``pan_base``/``pan_step``.  The serve
+#: plane's ``mb`` kinds have no entry here: their lanes are
+#: bit-identical to the local kinds (tests/test_serve_property.py).
+_ENTRY = {"qsweep_refine": "qsweep", "qsweep_tail_refine": "qsweep_tail",
+          "pan_base": "pan_lb", "pan_step": "pan_lb"}
+
+
+def _entries(sharded: bool) -> Tuple[str, ...]:
+    """Entry points of the table's local or sharded kinds."""
+    out: List[str] = []
+    for kind, pk in PLAN_KINDS.items():
+        entry = _ENTRY.get(kind, kind)
+        if (pk.sharded == sharded and pk.layout != "mb"
+                and entry not in out):
+            out.append(entry)
+    return tuple(out)
+
+
+LOCAL_KINDS = _entries(False)
+SHARDED_KINDS = _entries(True)
 ALL_KINDS = LOCAL_KINDS + SHARDED_KINDS
-#: kinds with no raw-mode sharded path (engine-level, not a gap here)
-_RAW_SKIP = {"ring", "tail_ring", "qsweep_ring"}
 
 CANARIES = (("nan", float("nan")), ("+inf", math.inf),
             ("-inf", -math.inf))
@@ -214,7 +236,7 @@ def _sanitize_ctx(ctx: _Context, kinds: Sequence[str],
     checked: List[str] = []
     where = f"[{ctx.backend},znorm={ctx.znorm}]"
     for kind in kinds:
-        if not ctx.znorm and kind in _RAW_SKIP:
+        if not ctx.znorm and kind in ZNORM_ONLY_KINDS:
             continue
         locus = f"{kind}{where}"
         try:

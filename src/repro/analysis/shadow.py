@@ -46,24 +46,30 @@ degenerates into a 2x-cost exact sweep.
 
 Micro-batch (``*_mb``) plans are not separately shadowed: they are
 property-tested bit-identical to their single-stream counterparts
-(tests/test_serve.py), so the single-stream cells cover them.
+(tests/test_serve_property.py), so the single-stream cells cover
+them.
 
-This module imports jax lazily — keep it off the lint-only path.
+Importing this module imports the engine (and jax) — keep it off the
+lint-only path.
 """
 from __future__ import annotations
 
 import math
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+from repro.core.engine import PLAN_KINDS
+
+from .irlint import ZNORM_ONLY_KINDS
 from .report import Finding
-from .sanitize import _RAW_SKIP, ALL_KINDS, _Context
+from .sanitize import ALL_KINDS, _Context
 
 __all__ = ["DEFAULT_TOL", "QUANT_KINDS", "hostile_series",
            "ref_profile", "ref_topk", "run_shadow"]
 
 #: plan kinds that run the quantized bound pass + exact refinement —
 #: replayed per precision, and prune-gated on the benign series
-QUANT_KINDS = ("qsweep", "qsweep_tail", "qsweep_ring")
+QUANT_KINDS = tuple(k for k in ALL_KINDS
+                    if k in PLAN_KINDS and PLAN_KINDS[k].quant)
 
 #: max relative nnd error vs the f64 reference before a finding; the
 #: hostile series is built to sit well inside this on a healthy tree
@@ -340,7 +346,7 @@ def run_shadow(backends: Iterable[str] = ("numpy", "xla", "pallas"),
             ctx = _ShadowContext(backend, bool(znorm))
             qctx: Dict[str, _ShadowContext] = {}
             for kind in kinds:
-                if not znorm and kind in _RAW_SKIP:
+                if not znorm and kind in ZNORM_ONLY_KINDS:
                     continue
                 if (kind in QUANT_KINDS
                         and backend not in quant_backends):

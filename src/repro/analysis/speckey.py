@@ -14,11 +14,11 @@ dataclass fields against the engine's declared partition —
 per-kind key element, or the mesh shape), ``KIND_DISPATCH_FIELDS``
 (select *which* plan kind runs, so the kind string keys them), and
 ``TRACE_INVARIANT_FIELDS`` (host-side only, never closed over by a
-plan body).  It also checks every ``self._get_plan(...)`` call site:
-the key is a tuple literal led by a unique string kind, ``_plan_key``
-really references ``backend``/``znorm``/``block``, and every
-mesh-sharded builder (one that calls ``_resolve_mesh``) carries
-``ndev`` in its key.
+plan body).  It also checks how keys are built: ``PLAN_KINDS`` is a
+dict literal of unique string kinds, ``DiscordEngine.plan`` is the
+only ``self._get_plan(...)`` call site and gives sharded kinds the
+mesh shape (``ndev``, through ``_resolve_mesh``), and ``_plan_key``
+really references ``backend``/``znorm``/``block``/``precision``.
 
 **Runtime** (:func:`runtime_audit`, property-based): builds tiny
 engines, perturbs each field in turn, and asserts the populated plan
@@ -81,19 +81,23 @@ def _engine_methods(engine_tree: ast.AST) -> List[ast.FunctionDef]:
     return []
 
 
-def _get_plan_sites(method: ast.FunctionDef
-                    ) -> List[Tuple[int, Optional[ast.Tuple]]]:
-    """(line, key-tuple-literal-or-None) for each self._get_plan call
-    in ``method``."""
-    sites = []
-    for node in ast.walk(method):
-        if isinstance(node, ast.Call) \
-                and isinstance(node.func, ast.Attribute) \
-                and node.func.attr == "_get_plan":
-            key = node.args[0] if node.args else None
-            sites.append((node.lineno,
-                          key if isinstance(key, ast.Tuple) else None))
-    return sites
+def _get_plan_sites(method: ast.FunctionDef) -> List[int]:
+    """Line of each self._get_plan call in ``method``."""
+    return [node.lineno for node in ast.walk(method)
+            if isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr == "_get_plan"]
+
+
+def _plan_kinds(engine_tree: ast.AST) -> Optional[ast.Dict]:
+    """The engine's module-level ``PLAN_KINDS = {...}`` literal."""
+    for node in engine_tree.body:  # type: ignore[attr-defined]
+        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
+                and isinstance(node.targets[0], ast.Name) \
+                and node.targets[0].id == "PLAN_KINDS" \
+                and isinstance(node.value, ast.Dict):
+            return node.value
+    return None
 
 
 def _calls(method: ast.FunctionDef, attr: str) -> bool:
@@ -195,44 +199,57 @@ def static_audit(engine_source: Optional[str] = None,
                     f"_plan_key does not reference {needed!r}; the "
                     "field cannot reach the plan keys")
 
-    # every plan-key construction site: tuple literal, string kind,
-    # unique kinds, ndev present on mesh-sharded builders
-    kinds: Dict[str, int] = {}
+    # plan keys are built in one place, DiscordEngine.plan, from the
+    # PLAN_KINDS table: string kinds, each named once, and the mesh
+    # shape on the sharded ones
+    table = _plan_kinds(engine_tree)
+    if table is None:
+        bad("plan-key-sites", 0,
+            "no module-level PLAN_KINDS dict literal — the audit (and "
+            "readers) can no longer see which plan kinds exist")
+    else:
+        kinds: Dict[str, int] = {}
+        for k in table.keys:
+            line = getattr(k, "lineno", table.lineno)
+            if not (isinstance(k, ast.Constant)
+                    and isinstance(k.value, str)):
+                bad("plan-key-sites", line,
+                    "PLAN_KINDS keys must be string kinds")
+                continue
+            if k.value in kinds:
+                bad("plan-key-sites", line,
+                    f"duplicate plan kind {k.value!r} (also at line "
+                    f"{kinds[k.value]}) — the later entry silently "
+                    "replaces the earlier")
+            kinds[k.value] = line
     for m in methods:
-        sharded = _calls(m, "_resolve_mesh")
-        for line, key in _get_plan_sites(m):
-            if m.name == "_get_plan":
-                continue
-            if key is None:
-                bad("plan-key-sites", line,
-                    f"{m.name}: _get_plan key is not a tuple "
-                    "literal — the audit (and readers) can no "
-                    "longer see what the plan is keyed on")
-                continue
-            first = key.elts[0] if key.elts else None
-            if not (isinstance(first, ast.Constant)
-                    and isinstance(first.value, str)):
-                bad("plan-key-sites", line,
-                    f"{m.name}: plan key must lead with a string "
-                    "kind")
-                continue
-            kind = first.value
-            if kind in kinds:
-                bad("plan-key-sites", line,
-                    f"duplicate plan kind {kind!r} (also at line "
-                    f"{kinds[kind]})")
-            kinds[kind] = line
-            if len(key.elts) < 2:
-                bad("plan-key-sites", line,
-                    f"{m.name}: plan kind {kind!r} keys on nothing "
-                    "but its name — window geometry is missing")
-            names = {n.id for n in ast.walk(key)
-                     if isinstance(n, ast.Name)}
-            if sharded and "ndev" not in names:
-                bad("plan-key-sites", line,
-                    f"{m.name}: mesh-sharded builder (calls "
-                    "_resolve_mesh) whose key omits ndev — plans "
-                    "for different mesh shapes would collide")
+        if m.name in ("plan", "_get_plan"):
+            continue
+        for line in _get_plan_sites(m):
+            bad("plan-key-sites", line,
+                f"{m.name}: builds a plan key outside "
+                "DiscordEngine.plan — every key must come from the "
+                "PLAN_KINDS table")
+    plan = next((m for m in methods if m.name == "plan"), None)
+    if plan is None or not _get_plan_sites(plan):
+        bad("plan-key-sites", 0,
+            "DiscordEngine.plan, the one place plan keys are built, is "
+            "missing or never calls _get_plan")
+    else:
+        # names that flow into the key: every assignment to ``key``
+        names = set()
+        for node in ast.walk(plan):
+            targets = (node.targets if isinstance(node, ast.Assign)
+                       else [node.target]
+                       if isinstance(node, ast.AugAssign) else [])
+            if any(isinstance(x, ast.Name) and x.id == "key"
+                   for x in targets):
+                names |= {n.id for n in ast.walk(node.value)
+                          if isinstance(n, ast.Name)}
+        if not ("ndev" in names and _calls(plan, "_resolve_mesh")):
+            bad("plan-key-sites", plan.lineno,
+                "plan: sharded kinds' keys omit the mesh shape (ndev) "
+                "— plans for different mesh shapes would collide")
     return findings
 
 

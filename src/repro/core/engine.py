@@ -7,7 +7,7 @@ and forgets between calls.  This module is the session layer that
 carries that state:
 
 ``DiscordEngine``
-    Owns a plan cache keyed on ``(kind, s, length_bucket)``.  Series
+    Owns a plan cache keyed on ``(kind, s, length_bucket, ...)``.  Series
     lengths are rounded up to power-of-two buckets (the ServeEngine
     prompt-bucket rule) and the padding windows are *masked* inside the
     tile backends (their ids remap to -1), so a second search over any
@@ -70,8 +70,21 @@ docs/pan.md for the user guide):
         re-verified against the final top-k, so the result equals the
         all-rung sweep's.
 
-Every compiled plan body bumps ``stats.traces`` when (and only when)
+Plan kinds (``PLAN_KINDS``): every compiled plan is one kind of that
+table, built by ``DiscordEngine.plan(kind, *geom)`` from a per-series
+op body (``_op_<op>``: profile, tail, pan, pan_tail, pan_base,
+pan_step, qsweep, qsweep_refine, qsweep_tail, qsweep_tail_refine)
+under a layout (``_layout_<layout>``): ``local``; ``mb``, the serve
+plane's ``lax.map`` micro-batch with per-lane scalars; ``batched``,
+vmapped on ``xla`` and scanned elsewhere; ``series_sharded``, the
+batched layout under ``shard_map``; ``row_sharded``, query blocks
+across the mesh; and the shard bodies of ``ring``, ``tail_ring`` and
+``pan_tail_ring``.  The key is ``(kind, *geom)``, plus the mesh shape
+``(ndev,)`` for sharded kinds, behind the spec prefix of
+``_plan_key``.  Every plan bumps ``stats.traces`` when (and only when)
 it is traced, so tests can assert the compile-once contract directly.
+The IR audit's registry (``plan_kind_registry``) and the analysers'
+kind lists are derived from the same table.
 
 Fleet plane (``repro.serve.DiscordServer``, docs/serving.md): the
 plan cache is a first-class :class:`PlanCache` object — private and
@@ -120,6 +133,7 @@ are named by plan kind and kernel.
 from __future__ import annotations
 
 import functools
+import inspect
 import math
 import os
 import time
@@ -146,7 +160,7 @@ from .tiles import (TileEngine, exact_pair_d2, self_join_grid,
 from .windows import sliding_stats
 
 __all__ = ["DiscordEngine", "DiscordStream", "PanStream", "EngineStats",
-           "PlanCache", "PlanKindAudit", "plan_kind_registry",
+           "PlanCache", "PLAN_KINDS", "PlanKindAudit", "plan_kind_registry",
            "plan_pad_geom", "plan_shard_geom", "plan_pan_row_geom",
            "ring_series_threshold", "PLAN_KEY_FIELDS",
            "KIND_DISPATCH_FIELDS", "TRACE_INVARIANT_FIELDS"]
@@ -172,12 +186,65 @@ TRACE_INVARIANT_FIELDS = ("k", "P", "alpha", "seed", "r")
 PAD_FILL = 0.0
 
 
+@dataclass(frozen=True)
+class _PlanKind:
+    """How :meth:`DiscordEngine.plan` builds one kind: the per-series
+    ``op`` body (``DiscordEngine._op_<op>``) under a ``layout``
+    (``DiscordEngine._layout_<layout>``), and what the analysers read
+    of it.  ``znorm_only``: audited in z-normalized mode only (the
+    engine refuses raw ``ring``/``tail_ring``; ``qsweep_ring`` rides a
+    ring spec).  ``quant``: a quantized bound pass or its f32
+    refinement (docs/cps.md)."""
+    op: str
+    layout: str
+    znorm_only: bool = False
+    quant: bool = False
+
+    @property
+    def sharded(self) -> bool:
+        """Runs under ``shard_map`` on the session mesh, and keys on
+        its shape."""
+        return self.layout not in ("local", "mb", "batched")
+
+
+#: every plan-cache kind, by the string that leads its key, names its
+#: device operations (``jax.named_scope``) and keys the serve plane's
+#: coalesced groups
+PLAN_KINDS = {
+    "profile": _PlanKind("profile", "local"),
+    "batched": _PlanKind("profile", "batched"),
+    "tail": _PlanKind("tail", "local"),
+    "qsweep": _PlanKind("qsweep", "local", quant=True),
+    "qsweep_refine": _PlanKind("qsweep_refine", "local", quant=True),
+    "qsweep_tail": _PlanKind("qsweep_tail", "local", quant=True),
+    "qsweep_tail_refine": _PlanKind("qsweep_tail_refine", "local",
+                                    quant=True),
+    "pan": _PlanKind("pan", "local"),
+    "pan_tail": _PlanKind("pan_tail", "local"),
+    "pan_base": _PlanKind("pan_base", "local"),
+    "pan_step": _PlanKind("pan_step", "local"),
+    "pan_batched": _PlanKind("pan", "batched"),
+    "profile_mb": _PlanKind("profile", "mb"),
+    "tail_mb": _PlanKind("tail", "mb"),
+    "pan_mb": _PlanKind("pan", "mb"),
+    "pan_tail_mb": _PlanKind("pan_tail", "mb"),
+    "ring": _PlanKind("profile", "ring", znorm_only=True),
+    "batched_ring": _PlanKind("profile", "series_sharded"),
+    "tail_ring": _PlanKind("tail", "tail_ring", znorm_only=True),
+    "qsweep_ring": _PlanKind("qsweep", "row_sharded", znorm_only=True,
+                             quant=True),
+    "pan_ring": _PlanKind("pan", "row_sharded"),
+    "pan_tail_ring": _PlanKind("pan_tail", "pan_tail_ring"),
+    "pan_batched_ring": _PlanKind("pan", "series_sharded"),
+}
+
+
 def plan_pad_geom(s: int, Lb: int, block: int) -> int:
     """Padded window count of a bucket-``Lb`` sweep at window ``s`` —
-    the tile-grid geometry every local plan builder keys on.  Module
-    level (not a method) so the IR auditor's static lane model
+    the tile-grid geometry the local plans key on.  Module level
+    (not a method) so the IR auditor's static lane model
     (``repro.analysis.irlint``) derives its expectations from the
-    same arithmetic the builders use."""
+    same arithmetic the plans use."""
     return ceil_div(Lb - s + 1, block) * block
 
 
@@ -224,6 +291,23 @@ def _win_norms(win):
     pads (their cancellation error would poison the certified error
     radius; docs/ARCHITECTURE.md)."""
     return jnp.sqrt(jnp.sum(win * win, axis=1))
+
+
+def _tail_block(eng: TileEngine, q, c0):
+    """One candidate block of a tail sweep: the query rows' minima over
+    the block at ``c0`` with their candidate ids, then the block's
+    column minima over the rows with their query ids."""
+    d2, cid = eng.sweep(q, c0)
+    return (jnp.min(d2, axis=1), cid[jnp.argmin(d2, axis=1)],
+            jnp.min(d2, axis=0), q.ids[jnp.argmin(d2, axis=0)])
+
+
+def _min_fold(rm, ra):
+    """Fold stacked row minima ``rm`` (one row per candidate block or
+    device) and their neighbours ``ra`` into the global minimum."""
+    sel = jnp.argmin(rm, axis=0)[None]
+    return (jnp.take_along_axis(rm, sel, axis=0)[0],
+            jnp.take_along_axis(ra, sel, axis=0)[0])
 
 
 def ring_series_threshold() -> int:
@@ -484,77 +568,58 @@ class DiscordEngine:
             self.stats.plans += 1
         return fn
 
-    def _profile_body(self, s: int):
-        """Per-series bucketed profile body — the computation shared
-        verbatim by the single-tenant ``("profile", ...)`` plan and
-        the serve plane's ``("profile_mb", ...)`` lanes, so a
-        micro-batched fill is bit-identical to the tenant's own."""
-        spec, be = self.spec, self.backend
+    def plan(self, kind: str, *geom):
+        """The compiled plan of ``kind`` (a :data:`PLAN_KINDS` key) at
+        geometry ``geom``, from the session's plan cache.
 
-        def body(series_pad, n_valid):
-            eng = TileEngine(series_pad, s, block=spec.block,
-                             backend=be, znorm=spec.znorm,
-                             n_valid=n_valid)
-            return eng.profile()
-        return body
-
-    def _profile_plan(self, s: int, Lb: int):
-        """(series_pad (Lb,), n_valid) -> (d2 (n_pad,), neighbor)."""
-        body = self._profile_body(s)
+        The key is ``(kind, *geom)``, with the mesh shape ``(ndev,)``
+        appended for sharded kinds.  On a miss the kind's layout
+        (``_layout_<layout>``) composes its op body (``_op_<op>``) and
+        the traced function bumps ``stats.traces`` once per trace."""
+        pk = PLAN_KINDS[kind]
+        key = (kind,) + geom
+        ndev = 1
+        if pk.sharded:
+            ndev = int(self._resolve_mesh().devices.size)
+            key += ((ndev,),)
 
         def build():
-            def fn(series_pad, n_valid):
+            body = getattr(self, "_layout_" + pk.layout)(pk.op, geom,
+                                                         ndev)
+
+            def fn(*args):
                 self.stats.traces += 1        # trace-time side effect
-                return body(series_pad, n_valid)
+                return body(*args)
+            # the compiled program names its parameters as the body does
+            fn.__signature__ = inspect.signature(body)
             return fn
-        return self._get_plan(("profile", s, Lb), build)
+        return self._get_plan(key, build)
 
-    def _profile_mb_plan(self, s: int, Lb: int, B: int):
-        """(stack (B, Lb), n_valid (B,)) -> (d2 (B, n_pad), ngh).
+    # -- per-series op bodies (one per PLAN_KINDS op) ------------------
+    def _tiles(self, series_pad, s: int, n_valid) -> TileEngine:
+        spec = self.spec
+        return TileEngine(series_pad, s, block=spec.block,
+                          backend=self.backend, znorm=spec.znorm,
+                          n_valid=n_valid)
 
-        Cross-tenant micro-batched fill (the serve plane's coalesced
-        dispatch): ``B`` tenant series of the same bucket, each lane
-        running the exact single-tenant profile body with its *own*
-        valid window count.  Always ``lax.map`` — never vmap — so
-        every lane's result is bit-identical to that tenant's own
-        ``("profile", ...)`` plan invocation.
-        """
-        body = self._profile_body(s)
+    def _ladder(self, series_pad, ladder: tuple, n_valid,
+                n_pad: Optional[int] = None) -> PanEngine:
+        spec = self.spec
+        return PanEngine(series_pad, ladder, block=spec.block,
+                         backend=self.backend, znorm=spec.znorm,
+                         n_valid=n_valid, n_pad=n_pad)
 
-        def build():
-            def fn(stack, n_valid):
-                self.stats.traces += 1
-                return lax.map(lambda t: body(t[0], t[1]),
-                               (stack, n_valid))
-            return fn
-        return self._get_plan(("profile_mb", s, Lb, B), build)
+    def _op(self, op: str, geom: tuple):
+        """Op ``op``'s per-series body at geometry ``geom``: the
+        method ``_op_<op>(*geom, *args)`` with ``geom`` bound."""
+        return functools.partial(getattr(self, "_op_" + op), *geom)
 
-    def _profile_each(self, s: int, sub, n_valid):
-        """Per-series bucketed profile of a (b, Lb) stack — the one
-        batching rule shared by the local and sharded batched plans:
-        vmapped into one MXU sweep on ``xla``; scanned elsewhere
-        (pallas_call / pure_callback don't batch)."""
-        spec, be = self.spec, self.backend
+    def _op_profile(self, s: int, Lb: int, series_pad, n_valid):
+        """(series_pad (Lb,), n_valid) -> (d2 (n_pad,), neighbor)."""
+        return self._tiles(series_pad, s, n_valid).profile()
 
-        def one(x):
-            eng = TileEngine(x, s, block=spec.block, backend=be,
-                             znorm=spec.znorm, n_valid=n_valid)
-            return eng.profile()
-
-        if be == "xla":
-            return jax.vmap(one)(sub)
-        return lax.map(one, sub)
-
-    def _batched_plan(self, s: int, B: int, Lb: int):
-        """(stack (B, Lb), n_valid) -> (d2 (B, n_pad), neighbor)."""
-        def build():
-            def fn(stack, n_valid):
-                self.stats.traces += 1
-                return self._profile_each(s, stack, n_valid)
-            return fn
-        return self._get_plan(("batched", s, B, Lb), build)
-
-    def _tail_plan(self, s: int, Lb: int, Qb: int):
+    def _op_tail(self, s: int, Lb: int, Qb: int, series_pad, q0,
+                 n_valid):
         """Streaming-append sweep: only the new tail tile rows.
 
         (series_pad (Lb,), q0, n_valid) ->
@@ -567,63 +632,14 @@ class DiscordEngine:
         windows*, which the host folds into the old profile (append-
         only: old nnds can only be superseded, never worsen).
         """
-        body = self._tail_body(s, Qb)
+        eng = self._tiles(series_pad, s, n_valid)
+        q = eng.query_block(q0 + jnp.arange(Qb, dtype=jnp.int32))
+        starts = jnp.arange(eng.nb, dtype=jnp.int32) * eng.block
+        rm, ra, cm, ca = lax.map(functools.partial(_tail_block, eng, q),
+                                 starts)
+        return (*_min_fold(rm, ra), cm.reshape(-1), ca.reshape(-1))
 
-        def build():
-            def fn(series_pad, q0, n_valid):
-                self.stats.traces += 1
-                return body(series_pad, q0, n_valid)
-            return fn
-        return self._get_plan(("tail", s, Lb, Qb), build)
-
-    def _tail_body(self, s: int, Qb: int):
-        """Per-series tail-sweep body — shared verbatim by the
-        single-tenant ``("tail", ...)`` plan and the serve plane's
-        ``("tail_mb", ...)`` lanes (bit-identical coalescing)."""
-        spec, be = self.spec, self.backend
-
-        def body(series_pad, q0, n_valid):
-            eng = TileEngine(series_pad, s, block=spec.block,
-                             backend=be, znorm=spec.znorm,
-                             n_valid=n_valid)
-            qids = q0 + jnp.arange(Qb, dtype=jnp.int32)
-            q = eng.query_block(qids)
-            starts = jnp.arange(eng.nb, dtype=jnp.int32) * eng.block
-
-            def one(c0):
-                d2, cid = eng.sweep(q, c0)
-                return (jnp.min(d2, axis=1),
-                        cid[jnp.argmin(d2, axis=1)],
-                        jnp.min(d2, axis=0),
-                        q.ids[jnp.argmin(d2, axis=0)])
-
-            rm, ra, cm, ca = lax.map(one, starts)
-            sel = jnp.argmin(rm, axis=0)[None]        # best block/row
-            row_d2 = jnp.take_along_axis(rm, sel, axis=0)[0]
-            row_ngh = jnp.take_along_axis(ra, sel, axis=0)[0]
-            return row_d2, row_ngh, cm.reshape(-1), ca.reshape(-1)
-        return body
-
-    def _tail_mb_plan(self, s: int, Lb: int, Qb: int, B: int):
-        """(stack (B, Lb), q0 (B,), n_valid (B,)) ->
-            (row_d2 (B, Qb), row_ngh, col_d2 (B, n_pad), col_ngh).
-
-        Cross-tenant micro-batched streaming append: ``B`` same-bucket
-        tail sweeps coalesced into one dispatch, each lane running the
-        exact single-tenant tail body with its own ``q0`` / valid
-        count (``lax.map`` lanes — bit-identical to ``("tail", ...)``).
-        """
-        body = self._tail_body(s, Qb)
-
-        def build():
-            def fn(stack, q0, n_valid):
-                self.stats.traces += 1
-                return lax.map(lambda t: body(t[0], t[1], t[2]),
-                               (stack, q0, n_valid))
-            return fn
-        return self._get_plan(("tail_mb", s, Lb, Qb, B), build)
-
-    def _pan_plan(self, ladder: tuple, Lb: int):
+    def _op_pan(self, ladder: tuple, Lb: int, series_pad, n_valid0):
         """(series_pad (Lb,), n_valid0) -> (d2 (R, n_pad), ngh).
 
         The pan-length ladder sweep (``core/pan.py``): every rung's
@@ -634,50 +650,80 @@ class DiscordEngine:
         one compiled sweep serves the whole bucket (keyed on the
         canonical ladder — the *ladder bucket* — and ``Lb``).
         """
-        body = self._pan_body(ladder)
+        return self._ladder(series_pad, ladder, n_valid0).profile()
 
-        def build():
-            def fn(series_pad, n_valid0):
-                self.stats.traces += 1
-                return body(series_pad, n_valid0)
-            return fn
-        return self._get_plan(("pan", ladder, Lb), build)
+    def _op_pan_tail(self, ladder: tuple, Lb: int, Qb: int, series_pad,
+                     q0, n_valid0):
+        """Streaming pan append: only the tail rows, at every rung.
 
-    def _pan_body(self, ladder: tuple):
-        """Per-series ladder-sweep body — shared verbatim by the
-        single-tenant ``("pan", ...)`` plan and the serve plane's
-        ``("pan_mb", ...)`` lanes (bit-identical coalescing)."""
-        spec, be = self.spec, self.backend
+        (series_pad (Lb,), q0, n_valid0) ->
+            (row_d2 (R, Qb), row_ngh, col_d2 (R, n_pad), col_ngh)
 
-        def body(series_pad, n_valid0):
-            peng = PanEngine(series_pad, ladder, block=spec.block,
-                             backend=be, znorm=spec.znorm,
-                             n_valid=n_valid0)
-            return peng.profile()
-        return body
-
-    def _pan_mb_plan(self, ladder: tuple, Lb: int, B: int):
-        """(stack (B, Lb), n_valid0 (B,)) -> (d2 (B, R, n_pad), ngh).
-
-        Cross-tenant micro-batched ladder fill: unlike the
-        ``("pan_batched", ...)`` serving plan (one shared valid count,
-        vmapped on ``xla``), every lane here carries its own tenant's
-        base-rung count and runs the exact single-tenant pan body
-        under ``lax.map`` — bit-identical to ``("pan", ...)``.
+        The tail's base-rung window ids from ``q0`` swept against
+        every candidate with the QT carried across rungs exactly like
+        the full sweep (``pan.pan_tail_sweep``): an append pays
+        base-rung tail tiles plus Δ-wide extensions only.
         """
-        body = self._pan_body(ladder)
+        spec = self.spec
+        return pan_tail_sweep(series_pad, ladder, q0, Qb,
+                              block=spec.block, backend=self.backend,
+                              znorm=spec.znorm, n_valid=n_valid0)
 
-        def build():
-            def fn(stack, n_valid0):
-                self.stats.traces += 1
-                return lax.map(lambda t: body(t[0], t[1]),
-                               (stack, n_valid0))
-            return fn
-        return self._get_plan(("pan_mb", ladder, Lb, B), build)
+    def _op_pan_base(self, s0: int, Lb: int, series_pad, n_valid0):
+        """(series_pad (Lb,), n_valid0) -> (qt (n_pad, n_pad), d2, ngh).
 
-    # -- quantized-sweep plan family (bf16/int8 bound + f32 refine) ----
-    def _qsweep_bracket(self, s: int, eng: TileEngine, bound_dot,
-                        q, c, nq, nc, sq=None, sc=None):
+        Rung 0 of the sequential LB-abandoning schedule: pays the
+        full-width base dot tiles once and *returns* the carried QT so
+        the ``("pan_step", ...)`` plans can extend it across plan
+        invocations (the host decides between steps whether the next
+        rung is worth evaluating at all).
+        """
+        return self._ladder(series_pad, (s0,), n_valid0,
+                            self._n_pad(s0, Lb)).carry_rows()
+
+    def _op_pan_step(self, sub_ladder: tuple, Lb: int, n_pad: int,
+                     series_pad, qt, n_valid_from):
+        """(series_pad, qt (n_pad, n_pad), n_valid_from) ->
+        (qt', d2, ngh).
+
+        One evaluated step of the sequential schedule: extends the
+        carried QT from ``sub_ladder[0]`` (the last evaluated rung)
+        through every intermediate — possibly skipped — width to
+        ``sub_ladder[-1]``, accumulating the extension dots in exactly
+        the full ladder sweep's order (so evaluated profiles match it
+        whether or not the rungs in between were evaluated), and
+        applies Eq. (3) only at the final rung.  ``n_valid_from`` is
+        the window count at ``sub_ladder[0]``; ``n_pad`` is the *base*
+        rung's grid (the carried QT's geometry), not this sub-ladder's.
+        """
+        return self._ladder(series_pad, sub_ladder, n_valid_from,
+                            n_pad).carry_rows(qt)
+
+    def _rows_pan(self, ladder: tuple, Lb: int):
+        """The ladder sweep over listed query blocks
+        (``PanEngine.rows``), assembled into ``(R, n_pad)`` profiles.
+        No raw-mode guard: the pan body computes raw distances
+        natively from the carried QT."""
+        n_pad = self._n_pad(ladder[0], Lb)
+        R = len(ladder)
+
+        def rows(series_pad, n_valid0, starts):
+            return self._ladder(series_pad, ladder, n_valid0).rows(starts)
+
+        def assemble(d2, arg):
+            return (d2.transpose(1, 0, 2).reshape(R, -1)[:, :n_pad],
+                    arg.transpose(1, 0, 2).reshape(R, -1)[:, :n_pad])
+        return rows, assemble, n_pad
+
+    # -- quantized-sweep ops (bf16/int8 bound + f32 refine) ------------
+    def _qstats(self, win):
+        """Fresh f32 norms (and int8 scales) of a block of windows,
+        as :meth:`_qsweep_bracket` takes them."""
+        return (_win_norms(win), quant_scales(win)
+                if self.spec.precision == "int8" else None)
+
+    def _qsweep_bracket(self, s: int, eng: TileEngine, q, c, qstats,
+                        cstats):
         """Certified f32 bracket ``(d2_lo, d2_hi)`` of the exact-f32
         tile d² for one query block vs one candidate block.
 
@@ -691,13 +737,11 @@ class DiscordEngine:
         brackets the f32 tile value, not just the real-valued
         distance (full derivation: docs/ARCHITECTURE.md)."""
         spec, prec = self.spec, self.spec.precision
-        if prec == "int8":
-            dots = bound_dot(q.win, c.win, precision=prec,
-                             sq=sq, sc=sc)
-            rad = bound_dot_radius(prec, nq, nc, s, sq, sc)
-        else:
-            dots = bound_dot(q.win, c.win, precision=prec)
-            rad = bound_dot_radius(prec, nq, nc, s)
+        (nq, sq), (nc, sc) = qstats, cstats      # scales None off int8
+        dots = get_bound_backend(self.backend)(q.win, c.win,
+                                               precision=prec,
+                                               sq=sq, sc=sc)
+        rad = bound_dot_radius(prec, nq, nc, s, sq, sc)
         bad = exclusion_mask(q.ids, c.ids, s, eng.n)
 
         def d2_of(dd):
@@ -708,34 +752,29 @@ class DiscordEngine:
             return d2
         return d2_of(dots + rad), d2_of(dots - rad)
 
-    def _qsweep_bound_body(self, s: int):
-        """Reduced-precision bound pass shared by the local and
-        mesh-sharded qsweep plans: ``body(series_pad, n_valid,
-        starts) -> (lo, hi)``, per listed query block a
-        ``(len(starts), block)`` bracket of each row's profile value
-        with ``lo <= exact-f32-profile d² <= hi`` per window."""
-        spec, be, prec = self.spec, self.backend, self.spec.precision
-        bound_dot = get_bound_backend(be)
-
-        def body(series_pad, n_valid, starts):
-            eng = TileEngine(series_pad, s, block=spec.block,
-                             backend=be, znorm=spec.znorm,
-                             n_valid=n_valid)
+    def _rows_qsweep(self, s: int, Lb: int):
+        """Reduced-precision bound pass over listed query blocks:
+        ``(rows, assemble, n_pad)`` where ``rows(series_pad, n_valid,
+        starts) -> (lo, hi)`` brackets, per listed block, each row's
+        profile value (``lo <= exact-f32-profile d² <= hi``, shape
+        ``(len(starts), block)``) and ``assemble`` flattens them."""
+        def rows(series_pad, n_valid, starts):
+            eng = self._tiles(series_pad, s, n_valid)
             cand = eng.all_windows()
-            nc = _win_norms(cand.win)
-            sc = quant_scales(cand.win) if prec == "int8" else None
+            cstats = self._qstats(cand.win)
 
             def one_block(b0):
                 q = eng.contiguous_block(b0)
-                nq = _win_norms(q.win)
-                sq = quant_scales(q.win) if prec == "int8" else None
-                lo, hi = self._qsweep_bracket(s, eng, bound_dot, q,
-                                              cand, nq, nc, sq, sc)
+                lo, hi = self._qsweep_bracket(s, eng, q, cand,
+                                              self._qstats(q.win), cstats)
                 return jnp.min(lo, axis=1), jnp.min(hi, axis=1)
             return lax.map(one_block, starts)
-        return body
 
-    def _qsweep_plan(self, s: int, Lb: int):
+        def assemble(lo, hi):
+            return lo.reshape(-1), hi.reshape(-1)
+        return rows, assemble, self._n_pad(s, Lb)
+
+    def _op_qsweep(self, s: int, Lb: int, series_pad, n_valid):
         """(series_pad (Lb,), n_valid) -> (lo_d2 (n_pad,), hi_d2).
 
         The quantized bound pass of the two-phase search
@@ -744,21 +783,13 @@ class DiscordEngine:
         upper bounds cannot reach the top-k and refines the rest
         through ``("qsweep_refine", ...)``.
         """
-        spec = self.spec
-        nb = self._n_pad(s, Lb) // spec.block
-        body = self._qsweep_bound_body(s)
+        block = self.spec.block
+        rows, assemble, n_pad = self._rows_qsweep(s, Lb)
+        starts = jnp.arange(n_pad // block, dtype=jnp.int32) * block
+        return assemble(*rows(series_pad, n_valid, starts))
 
-        def build():
-            def fn(series_pad, n_valid):
-                self.stats.traces += 1
-                starts = (jnp.arange(nb, dtype=jnp.int32)
-                          * spec.block)
-                lo, hi = body(series_pad, n_valid, starts)
-                return lo.reshape(-1), hi.reshape(-1)
-            return fn
-        return self._get_plan(("qsweep", s, Lb), build)
-
-    def _qsweep_refine_plan(self, s: int, Lb: int):
+    def _op_qsweep_refine(self, s: int, Lb: int, series_pad, b2,
+                          n_valid):
         """(series_pad (Lb,), b2 (2,), n_valid) ->
             (d2 (2, block), ngh).
 
@@ -780,61 +811,10 @@ class DiscordEngine:
         sets, and buckets with fewer than two blocks take the exact
         plans outright.
         """
-        spec, be = self.spec, self.backend
+        return self._tiles(series_pad, s, n_valid).block_rows(b2)
 
-        def build():
-            def fn(series_pad, b2, n_valid):
-                self.stats.traces += 1
-                eng = TileEngine(series_pad, s, block=spec.block,
-                                 backend=be, znorm=spec.znorm,
-                                 n_valid=n_valid)
-                return eng.block_rows(b2)
-            return fn
-        return self._get_plan(("qsweep_refine", s, Lb), build)
-
-    def _qsweep_sharded_plan(self, s: int, Lb: int):
-        """(series_pad (Lb,), n_valid) -> (lo_d2 (nb_p*block,), hi_d2).
-
-        Mesh-sharded bound pass: the query row-blocks are sharded
-        across the device mesh (candidates replicated — the same row
-        decomposition as ``("pan_ring", ...)``), each device running
-        the shared reduced-precision bound body over its own starts.
-        Refinement stays local (``("qsweep_refine", ...)``):
-        survivors are a small block subset by construction, and the
-        local f32 re-sweep keeps refined values bit-identical to the
-        local profile plan's regardless of mesh shape.
-        """
-        spec = self.spec
-        mesh = self._resolve_mesh()
-        ndev = int(mesh.devices.size)
-        n_pad = self._n_pad(s, Lb)
-        nb_p = ceil_div(n_pad // spec.block, ndev) * ndev
-        body = self._qsweep_bound_body(s)
-
-        def build():
-            from jax.sharding import PartitionSpec as P
-            from .distributed import AXIS
-
-            def shard_body(starts, series_pad, n_valid):
-                return body(series_pad, n_valid[0], starts)
-
-            sweep = jax.shard_map(
-                shard_body, mesh=mesh,
-                in_specs=(P(AXIS), P(None), P(None)),
-                out_specs=(P(AXIS, None), P(AXIS, None)),
-                check_vma=False)
-
-            def fn(series_pad, n_valid):
-                self.stats.traces += 1
-                starts = (jnp.arange(nb_p, dtype=jnp.int32)
-                          * spec.block)
-                lo, hi = sweep(starts, series_pad,
-                               jnp.full((1,), n_valid, jnp.int32))
-                return lo.reshape(-1), hi.reshape(-1)
-            return fn
-        return self._get_plan(("qsweep_ring", s, Lb, (ndev,)), build)
-
-    def _qsweep_tail_plan(self, s: int, Lb: int, Qb: int):
+    def _op_qsweep_tail(self, s: int, Lb: int, Qb: int, series_pad, q0,
+                        n_valid):
         """Quantized streaming-append bound pass.
 
         (series_pad (Lb,), q0, n_valid) ->
@@ -848,83 +828,203 @@ class DiscordEngine:
         blocks that can matter, through
         ``("qsweep_tail_refine", ...)``.
         """
-        spec, be, prec = self.spec, self.backend, self.spec.precision
-        bound_dot = get_bound_backend(be)
-        nb = self._n_pad(s, Lb) // spec.block
+        eng = self._tiles(series_pad, s, n_valid)
+        q = eng.query_block(q0 + jnp.arange(Qb, dtype=jnp.int32))
+        qstats = self._qstats(q.win)
+        starts = jnp.arange(self._n_pad(s, Lb) // eng.block,
+                            dtype=jnp.int32) * eng.block
 
-        def build():
-            def fn(series_pad, q0, n_valid):
-                self.stats.traces += 1
-                eng = TileEngine(series_pad, s, block=spec.block,
-                                 backend=be, znorm=spec.znorm,
-                                 n_valid=n_valid)
-                qids = q0 + jnp.arange(Qb, dtype=jnp.int32)
-                q = eng.query_block(qids)
-                nq = _win_norms(q.win)
-                sq = quant_scales(q.win) if prec == "int8" else None
-                starts = (jnp.arange(nb, dtype=jnp.int32)
-                          * eng.block)
+        def one(c0):
+            c = eng.contiguous_block(c0)
+            lo, hi = self._qsweep_bracket(s, eng, q, c, qstats,
+                                          self._qstats(c.win))
+            return (jnp.min(lo, axis=1), jnp.min(hi, axis=1),
+                    jnp.min(lo, axis=0))
 
-                def one(c0):
-                    c = eng.contiguous_block(c0)
-                    nc = _win_norms(c.win)
-                    sc = (quant_scales(c.win) if prec == "int8"
-                          else None)
-                    lo, hi = self._qsweep_bracket(
-                        s, eng, bound_dot, q, c, nq, nc, sq, sc)
-                    return (jnp.min(lo, axis=1),
-                            jnp.min(hi, axis=1),
-                            jnp.min(lo, axis=0))
+        rlo, rhi, clo = lax.map(one, starts)
+        return rlo, rhi, clo.reshape(-1)
 
-                rlo, rhi, clo = lax.map(one, starts)
-                return rlo, rhi, clo.reshape(-1)
-            return fn
-        return self._get_plan(("qsweep_tail", s, Lb, Qb), build)
-
-    def _qsweep_tail_refine_plan(self, s: int, Lb: int, Qb: int):
+    def _op_qsweep_tail_refine(self, s: int, Lb: int, Qb: int,
+                               series_pad, q0, n_valid, c2):
         """(series_pad (Lb,), q0, n_valid, c2 (2,)) ->
             (rm (2, Qb), ra, cm (2, block), ca).
 
         Exact f32 tail sweep of the ``Qb`` tail queries against a
         *pair* of candidate blocks — the ``("tail", ...)`` plan's
-        per-block ``lax.map`` body re-run verbatim (same shapes, same
-        reduction order), so refined tail rows and columns are
-        bit-identical to the full exact tail sweep's.  The traced
-        pair of starts keeps one compiled plan serving every
+        per-block ``lax.map`` body (``_tail_block``) re-run verbatim
+        (same shapes, same reduction order), so refined tail rows and
+        columns are bit-identical to the full exact tail sweep's.  The
+        traced pair of starts keeps one compiled plan serving every
         refinement; the fixed trip count of 2 preserves the scan (see
-        ``_qsweep_refine_plan`` — XLA unrolls trip-count-1 loops and
+        ``_op_qsweep_refine`` — XLA unrolls trip-count-1 loops and
         drifts by ulps).
         """
-        spec, be = self.spec, self.backend
+        eng = self._tiles(series_pad, s, n_valid)
+        q = eng.query_block(q0 + jnp.arange(Qb, dtype=jnp.int32))
+        return lax.map(functools.partial(_tail_block, eng, q), c2)
 
-        def build():
-            def fn(series_pad, q0, n_valid, c2):
-                self.stats.traces += 1
-                eng = TileEngine(series_pad, s, block=spec.block,
-                                 backend=be, znorm=spec.znorm,
-                                 n_valid=n_valid)
-                qids = q0 + jnp.arange(Qb, dtype=jnp.int32)
-                q = eng.query_block(qids)
+    # -- layouts (one per PLAN_KINDS layout) ---------------------------
+    def _shard(self, body, in_dims, out_dims):
+        """``jax.shard_map`` of ``body`` over the session's series
+        mesh.  Each operand and output is named by the dimension the
+        mesh axis shards (``0`` leading, ``1`` second) or ``None``
+        (replicated)."""
+        from jax.sharding import PartitionSpec as P
 
-                def one(c):
-                    d2, cid = eng.sweep(q, c)
-                    return (jnp.min(d2, axis=1),
-                            cid[jnp.argmin(d2, axis=1)],
-                            jnp.min(d2, axis=0),
-                            q.ids[jnp.argmin(d2, axis=0)])
+        from .distributed import AXIS
 
-                return lax.map(one, c2)
-            return fn
-        return self._get_plan(("qsweep_tail_refine", s, Lb, Qb),
-                              build)
+        def spec(d):
+            return P() if d is None else P(*([None] * d), AXIS)
+        return jax.shard_map(
+            body, mesh=self._resolve_mesh(),
+            in_specs=tuple(spec(d) for d in in_dims),
+            out_specs=tuple(spec(d) for d in out_dims),
+            check_vma=False)
 
-    # -- mesh-sharded plan family (the ring fold-in) -------------------
+    def _layout_local(self, op: str, geom: tuple, ndev: int):
+        return self._op(op, geom)
+
+    def _layout_mb(self, op: str, geom: tuple, ndev: int):
+        """Cross-tenant micro-batch, geometry ``(*op_geom, B)``:
+        ``(stack (B, Lb), *scalars (B,))``, each lane the local op
+        body with that tenant's own scalars under ``lax.map`` — never
+        vmap — so every lane is bit-identical to the tenant's own
+        local plan (the serve plane's coalesced dispatch)."""
+        body = self._op(op, geom[:-1])
+
+        def run(stack, *lanes):
+            return lax.map(lambda t: body(*t), (stack, *lanes))
+        return run
+
+    def _layout_batched(self, op: str, geom: tuple, ndev: int):
+        """Batched sweep, geometry ``(s_or_ladder, B, Lb)``:
+        ``(stack (B, Lb), n_valid)`` with one valid count for the
+        whole batch, vmapped into one MXU sweep on ``xla`` and
+        scanned elsewhere (pallas_call / pure_callback don't batch)."""
+        body = self._op(op, (geom[0], geom[2]))
+        be = self.backend
+
+        def run(stack, n_valid):
+            def one(x):
+                return body(x, n_valid)
+            if be == "xla":
+                return jax.vmap(one)(stack)
+            return lax.map(one, stack)
+        return run
+
+    def _layout_series_sharded(self, op: str, geom: tuple, ndev: int):
+        """Series-parallel level of the two-level batched layout,
+        geometry ``(s_or_ladder, Bp, Lb)``: ``(stack (Bp, Lb),
+        n_valid (1,))``, the batch sharded across the mesh and each
+        device running :meth:`_layout_batched` over its sub-batch."""
+        batched = self._layout_batched(op, geom, ndev)
+        return self._shard(lambda sub, n_valid: batched(sub, n_valid[0]),
+                           (0, None), (0, 0))
+
+    def _layout_row_sharded(self, op: str, geom: tuple, ndev: int):
+        """Query blocks sharded across the mesh, candidates replicated
+        (``_rows_<op>``): ``(series_pad (Lb,), n_valid)``.  The block
+        count is padded to a device multiple; ``assemble`` turns the
+        per-block rows back into the op's outputs."""
+        rows, assemble, n_pad = getattr(self, "_rows_" + op)(*geom)
+        block = self.spec.block
+        nb_p = ceil_div(n_pad // block, ndev) * ndev
+        sweep = self._shard(lambda st, sp, nv: rows(sp, nv[0], st),
+                            (0, None, None), (0, 0))
+
+        def run(series_pad, n_valid):
+            starts = jnp.arange(nb_p, dtype=jnp.int32) * block
+            return assemble(*sweep(starts, series_pad,
+                                   jnp.full((1,), n_valid, jnp.int32)))
+        return run
+
+    def _layout_ring(self, op: str, geom: tuple, ndev: int):
+        """(series_pad (Lb,), n_valid) -> (d2 (n_sh,), neighbor).
+
+        The ring matrix profile: every device owns one block-aligned
+        shard of query windows; candidate shards orbit the ring via
+        ``ppermute`` (the hop body shared with ``core/distributed``)
+        while each device min-folds the visiting shard into its
+        queries.  Masking is carried entirely by the window ids, so
+        one compiled plan serves every series in the bucket.
+        """
+        from .distributed import _ring_mp_shard
+        self._require_znorm("the ring plan")
+        s, Lb = geom
+        _, per, n_sh = self._shard_geom(s, Lb, ndev)
+        sweep = self._shard(
+            functools.partial(_ring_mp_shard, s=s, n=n_sh, ndev=ndev,
+                              backend=self.backend,
+                              block=self.spec.block),
+            (0, 0, 0, 0), (0, 0))
+
+        def run(series_pad, n_valid):
+            return sweep(*self._tiles(series_pad, s,
+                                      n_valid).window_set(n_sh))
+        return run
+
+    def _layout_tail_ring(self, op: str, geom: tuple, ndev: int):
+        """The tail op with the *candidates* sharded: each device
+        sweeps the tail queries against its own candidate shard and
+        the per-shard row minima are min-folded globally (every
+        candidate has one owning shard, so columns need no fold)."""
+        from .distributed import _tile_d2
+        self._require_znorm("the sharded tail plan")
+        s, Lb, Qb = geom
+        n_pad, per, n_sh = self._shard_geom(s, Lb, ndev)
+        be = self.backend
+
+        def shard_body(qwin, qmu, qsig, qid, cwin, cmu, csig, cid):
+            d2 = _tile_d2(qwin, qmu, qsig, qid,
+                          cwin, cmu, csig, cid, s, n_sh, be)
+            return (jnp.min(d2, axis=1)[None],
+                    cid[jnp.argmin(d2, axis=1)][None],
+                    jnp.min(d2, axis=0),
+                    qid[jnp.argmin(d2, axis=0)])
+
+        sweep = self._shard(shard_body, (None,) * 4 + (0,) * 4,
+                            (0, 0, 0, 0))
+
+        def run(series_pad, q0, n_valid):
+            eng = self._tiles(series_pad, s, n_valid)
+            q = eng.query_block(q0 + jnp.arange(Qb, dtype=jnp.int32))
+            rm, ra, cm, ca = sweep(
+                q.win, q.mu, q.sig, q.ids,
+                *self._sharded_blocks(eng, n_pad, n_sh))
+            return (*_min_fold(rm, ra), cm, ca)
+        return run
+
+    def _layout_pan_tail_ring(self, op: str, geom: tuple, ndev: int):
+        """The pan tail op with the *candidates* sharded: each device
+        carries the QT for the tail queries against only the candidate
+        id range it owns; row minima are min-folded globally and the
+        column slices concatenate back to the full grid.  No znorm
+        guard: the pan body computes raw distances natively."""
+        from .distributed import AXIS
+        ladder, Lb, Qb = geom
+        n_pad, per, n_sh = self._shard_geom(ladder[0], Lb, ndev)
+
+        def shard_body(series_pad, q0, n_valid0):
+            dev = lax.axis_index(AXIS)
+            peng = self._ladder(series_pad, ladder, n_valid0[0], n_sh)
+            qids = q0[0] + jnp.arange(Qb, dtype=jnp.int32)
+            rd2, rng, cd2, cng = peng.tail(qids, dev * per, per)
+            return rd2[None], rng[None], cd2, cng
+
+        sweep = self._shard(shard_body, (None, None, None),
+                            (0, 0, 1, 1))
+
+        def run(series_pad, q0, n_valid0):
+            rm, ra, cm, ca = sweep(
+                series_pad, jnp.full((1,), q0, jnp.int32),
+                jnp.full((1,), n_valid0, jnp.int32))
+            return (*_min_fold(rm, ra), cm[:, :n_pad], ca[:, :n_pad])
+        return run
+
+    # -- mesh geometry -------------------------------------------------
     def _shard_geom(self, s: int, Lb: int, ndev: int):
-        """Window-count geometry of a sharded bucket-``Lb`` sweep:
-        ``(n_pad, per, n_sh)`` where ``n_pad`` is the tile grid's own
-        padded window count, ``per`` the per-device shard (rounded up
-        to a multiple of ``spec.block`` so shards stay MXU-aligned),
-        and ``n_sh = per * ndev`` the mesh-wide padded count."""
+        """Window-count geometry of a sharded bucket-``Lb`` sweep
+        (:func:`plan_shard_geom`): ``(n_pad, per, n_sh)``."""
         return plan_shard_geom(s, Lb, self.spec.block, ndev)
 
     def _sharded_blocks(self, eng: TileEngine, n_pad: int, n_sh: int):
@@ -938,387 +1038,10 @@ class DiscordEngine:
                 jnp.pad(blk.sig, (0, pad), constant_values=1.0),
                 jnp.pad(blk.ids, (0, pad), constant_values=-1))
 
-    def _ring_plan(self, s: int, Lb: int):
-        """(series_pad (Lb,), n_valid) -> (d2 (n_sh,), neighbor).
-
-        The ring matrix profile as a cached plan: every device owns one
-        block-aligned shard of query windows; candidate shards orbit
-        the ring via ``ppermute`` (the hop body shared with
-        ``core/distributed``) while each device min-folds the visiting
-        shard into its queries.  Masking is carried entirely by the
-        window ids, so one compiled plan serves every series in the
-        bucket — the compile-once contract, mesh-wide.
-        """
-        spec, be = self.spec, self.backend
-        self._require_znorm("the ring plan")
-        mesh = self._resolve_mesh()
-        ndev = int(mesh.devices.size)
-        n_pad, per, n_sh = self._shard_geom(s, Lb, ndev)
-
-        def build():
-            from jax.sharding import PartitionSpec as P
-            from .distributed import AXIS, _ring_mp_shard
-
-            body = functools.partial(_ring_mp_shard, s=s, n=n_sh,
-                                     ndev=ndev, backend=be,
-                                     block=spec.block)
-            sweep = jax.shard_map(
-                body, mesh=mesh,
-                in_specs=(P(AXIS, None), P(AXIS), P(AXIS), P(AXIS)),
-                out_specs=(P(AXIS), P(AXIS)), check_vma=False)
-
-            def fn(series_pad, n_valid):
-                self.stats.traces += 1
-                eng = TileEngine(series_pad, s, block=spec.block,
-                                 backend=be, znorm=spec.znorm,
-                                 n_valid=n_valid)
-                return sweep(*eng.window_set(n_sh))
-            return fn
-        return self._get_plan(("ring", s, Lb, (ndev,)), build)
-
-    def _batched_sharded_plan(self, s: int, Bp: int, Lb: int):
-        """(stack (Bp, Lb), n_valid (1,)) -> (d2 (Bp, n_pad), ngh).
-
-        Series-parallel level of the two-level batched layout: the
-        batch is sharded across devices and each device runs the local
-        bucketed profile sweep over its own sub-batch (vmapped on
-        ``xla``, scanned elsewhere — same rule as the local plan).
-        """
-        mesh = self._resolve_mesh()
-        ndev = int(mesh.devices.size)
-
-        def build():
-            from jax.sharding import PartitionSpec as P
-            from .distributed import AXIS
-
-            def shard_body(sub, n_valid):
-                return self._profile_each(s, sub, n_valid[0])
-
-            sweep = jax.shard_map(
-                shard_body, mesh=mesh,
-                in_specs=(P(AXIS, None), P(None)),
-                out_specs=(P(AXIS, None), P(AXIS, None)),
-                check_vma=False)
-
-            def fn(stack, n_valid):
-                self.stats.traces += 1
-                return sweep(stack, n_valid)
-            return fn
-        return self._get_plan(("batched_ring", s, Bp, Lb, (ndev,)),
-                              build)
-
-    def _tail_sharded_plan(self, s: int, Lb: int, Qb: int):
-        """Sharded streaming-append sweep: same contract as
-        ``_tail_plan`` but each device sweeps only the tail queries
-        against *its own* candidate shard; the per-shard row minima are
-        min-folded globally afterwards (the column side needs no fold —
-        every candidate has exactly one owning shard).
-        """
-        spec, be = self.spec, self.backend
-        self._require_znorm("the sharded tail plan")
-        mesh = self._resolve_mesh()
-        ndev = int(mesh.devices.size)
-        n_pad, per, n_sh = self._shard_geom(s, Lb, ndev)
-
-        def build():
-            from jax.sharding import PartitionSpec as P
-            from .distributed import AXIS, _tile_d2
-
-            def shard_body(qwin, qmu, qsig, qid, cwin, cmu, csig, cid):
-                d2 = _tile_d2(qwin, qmu, qsig, qid,
-                              cwin, cmu, csig, cid, s, n_sh, be)
-                return (jnp.min(d2, axis=1)[None],
-                        cid[jnp.argmin(d2, axis=1)][None],
-                        jnp.min(d2, axis=0),
-                        qid[jnp.argmin(d2, axis=0)])
-
-            sweep = jax.shard_map(
-                shard_body, mesh=mesh,
-                in_specs=(P(None, None), P(None), P(None), P(None),
-                          P(AXIS, None), P(AXIS), P(AXIS), P(AXIS)),
-                out_specs=(P(AXIS, None), P(AXIS, None),
-                           P(AXIS), P(AXIS)),
-                check_vma=False)
-
-            def fn(series_pad, q0, n_valid):
-                self.stats.traces += 1
-                eng = TileEngine(series_pad, s, block=spec.block,
-                                 backend=be, znorm=spec.znorm,
-                                 n_valid=n_valid)
-                qids = q0 + jnp.arange(Qb, dtype=jnp.int32)
-                q = eng.query_block(qids)
-                rm, ra, cm, ca = sweep(
-                    q.win, q.mu, q.sig, q.ids,
-                    *self._sharded_blocks(eng, n_pad, n_sh))
-                sel = jnp.argmin(rm, axis=0)[None]     # global min-fold
-                row_d2 = jnp.take_along_axis(rm, sel, axis=0)[0]
-                row_ngh = jnp.take_along_axis(ra, sel, axis=0)[0]
-                return row_d2, row_ngh, cm, ca
-            return fn
-        return self._get_plan(("tail_ring", s, Lb, Qb, (ndev,)), build)
-
     def _pan_row_geom(self, ladder: tuple, Lb: int, ndev: int):
-        """Query-row geometry of a pan sweep: ``(n_pad, nb_p)`` where
-        ``n_pad`` is the base-rung padded window count and ``nb_p``
-        the query block count padded to a device multiple (1 device =
-        no padding)."""
+        """Query-row geometry of a pan sweep
+        (:func:`plan_pan_row_geom`): ``(n_pad, nb_p)``."""
         return plan_pan_row_geom(ladder, Lb, self.spec.block, ndev)
-
-    def _pan_sharded_plan(self, ladder: tuple, Lb: int):
-        """Mesh-sharded pan sweep: the query *blocks* are sharded
-        across the device mesh (candidates replicated — the pan
-        sweep's row decomposition is embarrassingly parallel), each
-        device runs the same QT-carrying ladder body over its own
-        starts, and the host reassembles the (R, n_pad) profiles.
-        Unlike the ring plans this path needs no raw-mode guard: the
-        pan body computes raw distances natively from the carried QT.
-        """
-        spec, be = self.spec, self.backend
-        mesh = self._resolve_mesh()
-        ndev = int(mesh.devices.size)
-        n_pad, nb_p = self._pan_row_geom(ladder, Lb, ndev)
-
-        def build():
-            from jax.sharding import PartitionSpec as P
-            from .distributed import AXIS
-
-            def shard_body(starts, series_pad, n_valid0):
-                peng = PanEngine(series_pad, ladder, block=spec.block,
-                                 backend=be, znorm=spec.znorm,
-                                 n_valid=n_valid0[0])
-                return peng.rows(starts)
-
-            sweep = jax.shard_map(
-                shard_body, mesh=mesh,
-                in_specs=(P(AXIS), P(None), P(None)),
-                out_specs=(P(AXIS, None, None), P(AXIS, None, None)),
-                check_vma=False)
-
-            def fn(series_pad, n_valid0):
-                self.stats.traces += 1
-                starts = (jnp.arange(nb_p, dtype=jnp.int32)
-                          * spec.block)
-                d2, arg = sweep(starts, series_pad,
-                                jnp.full((1,), n_valid0, jnp.int32))
-                R = len(ladder)
-                return (d2.transpose(1, 0, 2).reshape(R, -1)[:, :n_pad],
-                        arg.transpose(1, 0, 2).reshape(R, -1)[:, :n_pad])
-            return fn
-        return self._get_plan(("pan_ring", ladder, Lb, (ndev,)), build)
-
-    def _pan_tail_plan(self, ladder: tuple, Lb: int, Qb: int):
-        """Streaming pan append: only the tail rows, at every rung.
-
-        (series_pad (Lb,), q0, n_valid0) ->
-            (row_d2 (R, Qb), row_ngh, col_d2 (R, n_pad), col_ngh)
-
-        Rows are the ``Qb`` (bucketed, masked) base-rung window ids
-        from ``q0`` — the appended tail, spanning every rung's new
-        windows — swept against every candidate with the QT carried
-        across rungs exactly like the full sweep (``PanEngine.tail``):
-        an append pays base-rung tail tiles plus Δ-wide extensions
-        only.  Row minima are the new windows' exact per-rung nnds;
-        column minima fold new-neighbor improvements into each rung's
-        old profile.
-        """
-        body = self._pan_tail_body(ladder, Qb)
-
-        def build():
-            def fn(series_pad, q0, n_valid0):
-                self.stats.traces += 1
-                return body(series_pad, q0, n_valid0)
-            return fn
-        return self._get_plan(("pan_tail", ladder, Lb, Qb), build)
-
-    def _pan_tail_body(self, ladder: tuple, Qb: int):
-        """Per-series pan tail body (``pan.pan_tail_sweep``) — shared
-        verbatim by the single-tenant ``("pan_tail", ...)`` plan and
-        the serve plane's ``("pan_tail_mb", ...)`` lanes."""
-        spec, be = self.spec, self.backend
-
-        def body(series_pad, q0, n_valid0):
-            return pan_tail_sweep(series_pad, ladder, q0, Qb,
-                                  block=spec.block, backend=be,
-                                  znorm=spec.znorm, n_valid=n_valid0)
-        return body
-
-    def _pan_tail_mb_plan(self, ladder: tuple, Lb: int, Qb: int,
-                          B: int):
-        """(stack (B, Lb), q0 (B,), n_valid0 (B,)) ->
-            (rd2 (B, R, Qb), rngh, cd2 (B, R, n_pad), cngh).
-
-        Cross-tenant micro-batched pan append: ``B`` same-ladder,
-        same-bucket tail sweeps in one dispatch, each lane the exact
-        single-tenant carried-QT body with its own ``q0`` / base-rung
-        count (``lax.map`` — bit-identical to ``("pan_tail", ...)``).
-        """
-        body = self._pan_tail_body(ladder, Qb)
-
-        def build():
-            def fn(stack, q0, n_valid0):
-                self.stats.traces += 1
-                return lax.map(lambda t: body(t[0], t[1], t[2]),
-                               (stack, q0, n_valid0))
-            return fn
-        return self._get_plan(("pan_tail_mb", ladder, Lb, Qb, B),
-                              build)
-
-    def _pan_tail_sharded_plan(self, ladder: tuple, Lb: int, Qb: int):
-        """Sharded pan append: same contract as ``_pan_tail_plan`` but
-        the *candidates* are sharded — each device carries the QT for
-        the tail queries against only the candidate id range it owns,
-        per-device row minima are min-folded globally and the
-        per-device column slices concatenate back to the full grid.
-        No znorm guard: the pan body computes raw distances natively
-        from the carried QT.
-        """
-        spec, be = self.spec, self.backend
-        mesh = self._resolve_mesh()
-        ndev = int(mesh.devices.size)
-        n_pad, per, n_sh = self._shard_geom(ladder[0], Lb, ndev)
-
-        def build():
-            from jax.sharding import PartitionSpec as P
-            from .distributed import AXIS
-
-            def shard_body(series_pad, q0, n_valid0):
-                dev = lax.axis_index(AXIS)
-                peng = PanEngine(series_pad, ladder, block=spec.block,
-                                 backend=be, znorm=spec.znorm,
-                                 n_valid=n_valid0[0], n_pad=n_sh)
-                qids = q0[0] + jnp.arange(Qb, dtype=jnp.int32)
-                rd2, rng, cd2, cng = peng.tail(qids, dev * per, per)
-                return rd2[None], rng[None], cd2, cng
-
-            sweep = jax.shard_map(
-                shard_body, mesh=mesh,
-                in_specs=(P(None), P(None), P(None)),
-                out_specs=(P(AXIS, None, None), P(AXIS, None, None),
-                           P(None, AXIS), P(None, AXIS)),
-                check_vma=False)
-
-            def fn(series_pad, q0, n_valid0):
-                self.stats.traces += 1
-                rm, ra, cm, ca = sweep(
-                    series_pad, jnp.full((1,), q0, jnp.int32),
-                    jnp.full((1,), n_valid0, jnp.int32))
-                sel = jnp.argmin(rm, axis=0)[None]    # global min-fold
-                row_d2 = jnp.take_along_axis(rm, sel, axis=0)[0]
-                row_ngh = jnp.take_along_axis(ra, sel, axis=0)[0]
-                return row_d2, row_ngh, cm[:, :n_pad], ca[:, :n_pad]
-            return fn
-        return self._get_plan(("pan_tail_ring", ladder, Lb, Qb,
-                               (ndev,)), build)
-
-    def _pan_base_plan(self, s0: int, Lb: int):
-        """(series_pad (Lb,), n_valid0) -> (qt (n_pad, n_pad), d2, ngh).
-
-        Rung 0 of the sequential LB-abandoning schedule: pays the
-        full-width base dot tiles once and *returns* the carried QT so
-        the ``("pan_step", ...)`` plans can extend it across plan
-        invocations (the host decides between steps whether the next
-        rung is worth evaluating at all).
-        """
-        spec, be = self.spec, self.backend
-        n_pad = self._n_pad(s0, Lb)
-
-        def build():
-            def fn(series_pad, n_valid0):
-                self.stats.traces += 1
-                peng = PanEngine(series_pad, (s0,), block=spec.block,
-                                 backend=be, znorm=spec.znorm,
-                                 n_valid=n_valid0, n_pad=n_pad)
-                return peng.carry_rows()
-            return fn
-        return self._get_plan(("pan_base", s0, Lb), build)
-
-    def _pan_step_plan(self, sub_ladder: tuple, Lb: int, n_pad: int):
-        """(series_pad, qt (n_pad, n_pad), n_valid_from) ->
-        (qt', d2, ngh).
-
-        One evaluated step of the sequential schedule: extends the
-        carried QT from ``sub_ladder[0]`` (the last evaluated rung)
-        through every intermediate — possibly skipped — width to
-        ``sub_ladder[-1]``, accumulating the extension dots in exactly
-        the full ladder sweep's order (so evaluated profiles match it
-        whether or not the rungs in between were evaluated), and
-        applies Eq. (3) only at the final rung.  ``n_valid_from`` is
-        the window count at ``sub_ladder[0]``; ``n_pad`` is the *base*
-        rung's grid (the carried QT's geometry), not this sub-ladder's.
-        """
-        spec, be = self.spec, self.backend
-
-        def build():
-            def fn(series_pad, qt, n_valid_from):
-                self.stats.traces += 1
-                peng = PanEngine(series_pad, sub_ladder,
-                                 block=spec.block, backend=be,
-                                 znorm=spec.znorm, n_valid=n_valid_from,
-                                 n_pad=n_pad)
-                return peng.carry_rows(qt)
-            return fn
-        return self._get_plan(("pan_step", sub_ladder, Lb, n_pad),
-                              build)
-
-    def _pan_each(self, ladder: tuple, sub, n_valid0):
-        """Per-series ladder sweep of a (b, Lb) stack — the batching
-        rule of ``_profile_each`` applied to the pan body: vmapped
-        into one sweep on ``xla``; scanned elsewhere (pallas_call /
-        pure_callback don't batch)."""
-        spec, be = self.spec, self.backend
-
-        def one(x):
-            peng = PanEngine(x, ladder, block=spec.block, backend=be,
-                             znorm=spec.znorm, n_valid=n_valid0)
-            return peng.profile()
-
-        if be == "xla":
-            return jax.vmap(one)(sub)
-        return lax.map(one, sub)
-
-    def _pan_batched_plan(self, ladder: tuple, B: int, Lb: int):
-        """(stack (B, Lb), n_valid0) -> (d2 (B, R, n_pad), ngh).
-
-        The (B, ladder) plan: every series of the batch pays one
-        ladder sweep, batched by ``_pan_each``'s backend rule.
-        """
-        def build():
-            def fn(stack, n_valid0):
-                self.stats.traces += 1
-                return self._pan_each(ladder, stack, n_valid0)
-            return fn
-        return self._get_plan(("pan_batched", ladder, B, Lb), build)
-
-    def _pan_batched_sharded_plan(self, ladder: tuple, Bp: int,
-                                  Lb: int):
-        """(stack (Bp, Lb), n_valid (1,)) -> (d2 (Bp, R, n_pad), ngh).
-
-        Series-parallel level of the two-level batched pan layout:
-        the batch is sharded across devices, each device runs the
-        local (b, ladder) sweep over its own sub-batch.
-        """
-        mesh = self._resolve_mesh()
-        ndev = int(mesh.devices.size)
-
-        def build():
-            from jax.sharding import PartitionSpec as P
-            from .distributed import AXIS
-
-            def shard_body(sub, n_valid):
-                return self._pan_each(ladder, sub, n_valid[0])
-
-            sweep = jax.shard_map(
-                shard_body, mesh=mesh,
-                in_specs=(P(AXIS, None), P(None)),
-                out_specs=(P(AXIS, None, None), P(AXIS, None, None)),
-                check_vma=False)
-
-            def fn(stack, n_valid):
-                self.stats.traces += 1
-                return sweep(stack, n_valid)
-            return fn
-        return self._get_plan(("pan_batched_ring", ladder, Bp, Lb,
-                               (ndev,)), build)
 
     # -- searches ------------------------------------------------------
     def search(self, series, **kw
@@ -1378,7 +1101,7 @@ class DiscordEngine:
                 grid = self._profile_grid(s, Lb)
                 span.set_metadata(bucket=Lb, n=n_true, grid=grid)
                 xp = _bucket_pad(x, Lb)
-                plan = self._profile_plan(s, Lb)
+                plan = self.plan("profile", s, Lb)
             with TraceAnnotation("engine.dispatch"):
                 d2, _arg = plan(jnp.asarray(xp), np.int32(n_true))
             with TraceAnnotation("engine.wait"):
@@ -1491,7 +1214,7 @@ class DiscordEngine:
         nv = np.int32(n_true)
         plan, bound_lanes = bound_plan_lanes(s, Lb)
         lo, hi = plan(xp, nv)
-        rplan = self._qsweep_refine_plan(s, Lb)
+        rplan = self.plan("qsweep_refine", s, Lb)
         ncalls = 0
 
         def refine_many(bs):
@@ -1535,7 +1258,7 @@ class DiscordEngine:
         t0 = time.perf_counter()
 
         def bound_plan_lanes(s_, Lb):
-            return (self._qsweep_plan(s_, Lb),
+            return (self.plan("qsweep", s_, Lb),
                     self._n_pad(s_, Lb) ** 2)
 
         with TraceAnnotation("engine.search", kind="qsweep"):
@@ -1556,7 +1279,7 @@ class DiscordEngine:
         ``ndev`` devices).  Returns ``(d2, arg, lanes, ndev)``; the
         caller owns the stats fold."""
         ndev = int(self._resolve_mesh().devices.size)
-        d2, arg = self._ring_plan(s, Lb)(series_pad, n_valid)
+        d2, arg = self.plan("ring", s, Lb)(series_pad, n_valid)
         _, per, n_sh = self._shard_geom(s, Lb, ndev)
         return d2, arg, n_sh * per * ndev, ndev
 
@@ -1635,7 +1358,7 @@ class DiscordEngine:
             n_pad = self._n_pad(s_, Lb)
             q_sh = (ceil_div(n_pad // spec.block, ndev) * ndev
                     * spec.block)
-            return self._qsweep_sharded_plan(s_, Lb), q_sh * n_pad
+            return self.plan("qsweep_ring", s_, Lb), q_sh * n_pad
 
         out = self._qsweep_exec(series, s, bound_plan_lanes)
         if out is None:      # single-block bucket: exact outright
@@ -1780,11 +1503,11 @@ class DiscordEngine:
             xp = _bucket_pad(x, Lb)
             ndev = self.ndev if self.sharded else 1
             if self.sharded:
-                plan = self._pan_sharded_plan(lad, Lb)
+                plan = self.plan("pan_ring", lad, Lb)
                 n_pad, nb_p = self._pan_row_geom(lad, Lb, ndev)
                 cells = nb_p * spec.block * n_pad
             else:
-                plan = self._pan_plan(lad, Lb)
+                plan = self.plan("pan", lad, Lb)
                 cells = self._pan_cells(s0, Lb)
             # neighbor ids stay on device: PanResult carries no neighbor
             # info, so only the d2 profiles cross to the host
@@ -1923,7 +1646,7 @@ class DiscordEngine:
         cells = n_pad * n_pad
         stats_cache: dict = {}
 
-        qt, d2_0, ngh_0 = self._pan_base_plan(lad[0], Lb)(
+        qt, d2_0, ngh_0 = self.plan("pan_base", lad[0], Lb)(
             xp, np.int32(n0))
         evaluated = {0: (np.asarray(d2_0, np.float64),
                          np.asarray(ngh_0, np.int64))}
@@ -1940,7 +1663,7 @@ class DiscordEngine:
             if ok:
                 skipped.append(r)
                 continue
-            step = self._pan_step_plan(tuple(lad[last:r + 1]), Lb,
+            step = self.plan("pan_step", tuple(lad[last:r + 1]), Lb,
                                        n_pad)
             qt, d2_r, ngh_r = step(xp, qt,
                                    np.int32(L - lad[last] + 1))
@@ -1972,7 +1695,7 @@ class DiscordEngine:
             # from scratch through the cached single-length plan
             skipped.remove(bad)
             s_b = lad[bad]
-            d2_b, ngh_b = self._profile_plan(s_b, Lb)(
+            d2_b, ngh_b = self.plan("profile", s_b, Lb)(
                 xp, np.int32(L - s_b + 1))
             evaluated[bad] = (np.asarray(d2_b, np.float64),
                               np.asarray(ngh_b, np.int64))
@@ -2051,7 +1774,7 @@ class DiscordEngine:
             n_true = L - s + 1
             Lb = length_bucket(L)
             xbp = _bucket_pad(xb, Lb)
-            d2b, _argb = self._batched_plan(s, B, Lb)(jnp.asarray(xbp),
+            d2b, _argb = self.plan("batched", s, B, Lb)(jnp.asarray(xbp),
                                                       np.int32(n_true))
             profs = np.sqrt(np.asarray(d2b, np.float64)[:, :n_true])
             elapsed = time.perf_counter() - t0
@@ -2133,7 +1856,7 @@ class DiscordEngine:
         Lb = length_bucket(L)
         Bp = ceil_div(B, ndev) * ndev
         xbp = _bucket_pad(xb, Lb, rows=Bp)
-        d2b, _argb = self._batched_sharded_plan(s, Bp, Lb)(
+        d2b, _argb = self.plan("batched_ring", s, Bp, Lb)(
             jnp.asarray(xbp), jnp.full((1,), n_true, jnp.int32))
         profs = np.sqrt(np.asarray(d2b, np.float64)[:B, :n_true])
         elapsed = time.perf_counter() - t0
@@ -2193,13 +1916,13 @@ class DiscordEngine:
         if self.sharded:
             Bp = ceil_div(B, ndev) * ndev
             xbp = _bucket_pad(xb, Lb, rows=Bp)
-            d2b, _argb = self._pan_batched_sharded_plan(lad, Bp, Lb)(
+            d2b, _argb = self.plan("pan_batched_ring", lad, Bp, Lb)(
                 jnp.asarray(xbp), jnp.full((1,), n0, jnp.int32))
             layout = "series-parallel"
             n_swept = Bp
         else:
             xbp = _bucket_pad(xb, Lb)
-            d2b, _argb = self._pan_batched_plan(lad, B, Lb)(
+            d2b, _argb = self.plan("pan_batched", lad, B, Lb)(
                 jnp.asarray(xbp), np.int32(n0))
             layout = "local"
             n_swept = B
@@ -2447,16 +2170,16 @@ class DiscordStream:
                     op["s"], op["Lb"], jnp.asarray(op["xp"]),
                     np.int32(op["n_new"]))
                 return d2, arg
-            return eng._profile_plan(op["s"], op["Lb"])(
+            return eng.plan("profile", op["s"], op["Lb"])(
                 jnp.asarray(op["xp"]), np.int32(op["n_new"]))
         if op["kind"] == "qtail":
-            return eng._qsweep_tail_plan(op["s"], op["Lb"],
+            return eng.plan("qsweep_tail", op["s"], op["Lb"],
                                          op["Qb"])(
                 jnp.asarray(op["xp"]), np.int32(op["q0"]),
                 np.int32(op["n_new"]))
-        plan = (eng._tail_sharded_plan(op["s"], op["Lb"], op["Qb"])
+        plan = (eng.plan("tail_ring", op["s"], op["Lb"], op["Qb"])
                 if self._sharded
-                else eng._tail_plan(op["s"], op["Lb"], op["Qb"]))
+                else eng.plan("tail", op["s"], op["Lb"], op["Qb"]))
         return plan(jnp.asarray(op["xp"]), np.int32(op["q0"]),
                     np.int32(op["n_new"]))
 
@@ -2500,7 +2223,7 @@ class DiscordStream:
         must not widen the criterion) — excluded blocks sit strictly
         above every live row minimum, so the first-min fold over the
         refined subset (ascending block order) equals the full
-        ``argmin(rm, axis=0)`` fold of ``_tail_body``, neighbor
+        ``argmin(rm, axis=0)`` fold of ``_op_tail``, neighbor
         tie-breaks included.  Column side: candidate ``j`` can only
         improve an old nnd when its certified lower bound undercuts
         the current profile (``clo[j] < d2[j]``); a skipped block's
@@ -2515,7 +2238,7 @@ class DiscordStream:
         nb = rlo.shape[0]
         xp = jnp.asarray(op["xp"])
         q0, nv = np.int32(op["q0"]), np.int32(n_new)
-        rplan = eng._qsweep_tail_refine_plan(s, Lb, Qb)
+        rplan = eng.plan("qsweep_tail_refine", s, Lb, Qb)
         refined: dict = {}
         ncalls = 0
 
@@ -2726,12 +2449,12 @@ class PanStream:
         """Run a staged op through the single-tenant plans."""
         eng, lad = self.engine, self.ladder
         if op["kind"] == "pan_fill":
-            plan = (eng._pan_sharded_plan(lad, op["Lb"])
-                    if self._sharded else eng._pan_plan(lad, op["Lb"]))
+            plan = (eng.plan("pan_ring", lad, op["Lb"])
+                    if self._sharded else eng.plan("pan", lad, op["Lb"]))
             return plan(jnp.asarray(op["xp"]), np.int32(op["n_new"]))
-        plan = (eng._pan_tail_sharded_plan(lad, op["Lb"], op["Qb"])
+        plan = (eng.plan("pan_tail_ring", lad, op["Lb"], op["Qb"])
                 if self._sharded
-                else eng._pan_tail_plan(lad, op["Lb"], op["Qb"]))
+                else eng.plan("pan_tail", lad, op["Lb"], op["Qb"]))
         return plan(jnp.asarray(op["xp"]), np.int32(op["q0"]),
                     np.int32(op["n_new"]))
 
@@ -2827,6 +2550,8 @@ class PanStream:
 class PlanKindAudit:
     """One plan-cache kind at a pinned, representative audit geometry.
 
+    ``geom`` is the kind's geometry, so ``DiscordEngine.plan(kind,
+    *geom)`` builds it, and ``avals`` its abstract inputs.
     ``pattern`` is the expected ordered ``dot_general`` decomposition
     of the traced plan body on the ``xla`` backend: one ``(cells,
     width)`` entry per dot site in program order, where ``cells`` is
@@ -2840,7 +2565,7 @@ class PlanKindAudit:
 
     with ``macs_g = sum(cells_i * width_i)`` over the group's sites.
     ``units`` is the number of independent per-series accounting units
-    (the batch width of ``batched``/``*_mb`` kinds — their runtime
+    (the batch width of the ``mb``/batched layouts — their runtime
     accounting applies the ceil per series, then multiplies).
     ``lanes`` is the ``tile_lanes`` the runtime call site books for
     the same geometry on ``xla`` (``pallas`` books the live blocks of
@@ -2849,12 +2574,7 @@ class PlanKindAudit:
     ``model_lanes()`` of the traced dots equals ``lanes``.
     """
     kind: str
-    family: str          # "local" | "mb" | "ring"
-    pan: bool            # pan-ladder kind (multi-width dot pattern)
-    spec_template: str   # "mp" | "pan" | "ring" | "mp_ndev" |
-    #                      "pan_ndev" | "qsweep" | "qsweep_ndev"
-    builder: str         # DiscordEngine plan-builder method name
-    build_args: tuple    # builder arguments at the pinned geometry
+    geom: tuple          # DiscordEngine.plan geometry
     avals: tuple         # ((shape, dtype-name), ...) abstract inputs
     pattern: tuple       # ((cells, width), ...) expected dot sites
     groups: tuple        # (((site_idx, ...), s_norm), ...)
@@ -2876,16 +2596,19 @@ def plan_kind_registry(*, s: int = 24, ladder=(16, 24, 32),
                        block: int = 32, length: int = 90,
                        Qb: int = 32, batch: int = 2, ndev: int = 1
                        ) -> "OrderedDict[str, PlanKindAudit]":
-    """Every registered plan-cache kind at one pinned geometry.
+    """Every :data:`PLAN_KINDS` kind at one pinned geometry.
 
     The IR auditor (``repro.analysis.irlint``) *discovers* plan kinds
-    here instead of hard-coding them — a new plan builder without a
-    registry entry fails the auditor's coverage test, and each entry
-    carries the expected dot decomposition + runtime lane formula of
-    its family so the static FLOP/lane cross-audit stays honest.  The
-    geometry knobs mirror the sanitizer's defaults (length 90 buckets
-    to 256 so most of every tile row is padding); ``ndev`` shapes the
-    ``*_ring`` entries and must match the mesh the auditor builds.
+    here, and each entry carries the expected dot decomposition and
+    runtime lane formula so the static FLOP/lane cross-audit stays
+    honest.  Entries are derived from the table: each op's facts for
+    one series, times its layout's multiplicity (the ``mb`` and
+    batched layouts stack ``B`` series, the series-parallel layout
+    ``Bp``).  The sharded kinds whose geometry is their own are
+    written out.  The geometry knobs mirror the sanitizer's defaults
+    (length 90 buckets to 256 so most of every tile row is padding);
+    ``ndev`` shapes the sharded entries and must match the mesh the
+    auditor builds.
     """
     lad = canonical_ladder(ladder)
     if len(lad) < 2:
@@ -2898,7 +2621,7 @@ def plan_kind_registry(*, s: int = 24, ladder=(16, 24, 32),
     n_pad = plan_pad_geom(s, Lb, block)
     _, per, n_sh = plan_shard_geom(s, Lb, block, ndev)
     p_pad = plan_pad_geom(lad[0], Lb, block)
-    _, p_per, p_sh = plan_shard_geom(lad[0], Lb, block, ndev)
+    _, _, p_sh = plan_shard_geom(lad[0], Lb, block, ndev)
     _, nb_p = plan_pan_row_geom(lad, Lb, block, ndev)
     # quantized-sweep row geometry: the sharded bound pass pads the
     # query blocks to a device multiple (q_sh rows total)
@@ -2908,132 +2631,71 @@ def plan_kind_registry(*, s: int = 24, ladder=(16, 24, 32),
     #: then each rung's extension
     widths = (lad[0],) + tuple(lad[r] - lad[r - 1] for r in range(1, R))
     f32, i32 = "float32", "int32"
-
-    def pan_pattern(rows, cols, mult=1):
-        return tuple((mult * rows * cols, w) for w in widths)
-
+    x, n, pair = ((Lb,), f32), ((), i32), ((2,), i32)
+    one = (((0,), s),)
     per_rung = tuple(((r,), lad[r]) for r in range(R))
 
-    entries = (
-        PlanKindAudit(
-            "profile", "local", False, "mp", "_profile_plan",
-            (s, Lb), (((Lb,), f32), ((), i32)),
-            ((n_pad * n_pad, s),), (((0,), s),), 1, n_pad ** 2),
-        PlanKindAudit(
-            "batched", "local", False, "mp", "_batched_plan",
-            (s, B, Lb), (((B, Lb), f32), ((), i32)),
-            ((B * n_pad * n_pad, s),), (((0,), s),), B,
-            B * n_pad ** 2),
-        PlanKindAudit(
-            "tail", "local", False, "mp", "_tail_plan",
-            (s, Lb, Qb), (((Lb,), f32), ((), i32), ((), i32)),
-            ((Qb * n_pad, s),), (((0,), s),), 1, Qb * n_pad),
-        PlanKindAudit(
-            "qsweep", "local", False, "qsweep", "_qsweep_plan",
-            (s, Lb), (((Lb,), f32), ((), i32)),
-            ((n_pad * n_pad, s),), (((0,), s),), 1, n_pad ** 2),
-        PlanKindAudit(
-            "qsweep_refine", "local", False, "qsweep",
-            "_qsweep_refine_plan",
-            (s, Lb), (((Lb,), f32), ((2,), i32), ((), i32)),
-            ((2 * block * n_pad, s),), (((0,), s),), 1,
-            2 * block * n_pad),
-        PlanKindAudit(
-            "qsweep_tail", "local", False, "qsweep",
-            "_qsweep_tail_plan",
-            (s, Lb, Qb), (((Lb,), f32), ((), i32), ((), i32)),
-            ((Qb * n_pad, s),), (((0,), s),), 1, Qb * n_pad),
-        PlanKindAudit(
-            "qsweep_tail_refine", "local", False, "qsweep",
-            "_qsweep_tail_refine_plan",
-            (s, Lb, Qb),
-            (((Lb,), f32), ((), i32), ((), i32), ((2,), i32)),
-            ((2 * Qb * block, s),), (((0,), s),), 1, 2 * Qb * block),
-        PlanKindAudit(
-            "pan", "local", True, "pan", "_pan_plan",
-            (lad, Lb), (((Lb,), f32), ((), i32)),
-            pan_pattern(p_pad, p_pad), per_rung, 1,
-            pan_lanes(lad, p_pad, p_pad)),
-        PlanKindAudit(
-            "pan_tail", "local", True, "pan", "_pan_tail_plan",
-            (lad, Lb, Qb), (((Lb,), f32), ((), i32), ((), i32)),
-            pan_pattern(Qb, p_pad), per_rung, 1,
-            int(sum(pan_rung_shares(lad, Qb, p_pad)))),
-        PlanKindAudit(
-            "pan_base", "local", True, "pan", "_pan_base_plan",
-            (lad[0], Lb), (((Lb,), f32), ((), i32)),
-            ((p_pad * p_pad, lad[0]),), (((0,), lad[0]),), 1,
-            p_pad ** 2),
-        PlanKindAudit(
-            "pan_step", "local", True, "pan", "_pan_step_plan",
-            (lad, Lb, p_pad),
-            (((Lb,), f32), ((p_pad, p_pad), f32), ((), i32)),
-            tuple((p_pad * p_pad, w) for w in widths[1:]),
-            # the LB schedule accounts one evaluated step as a single
-            # extension at the step's final width (docs/cps.md)
-            ((tuple(range(R - 1)), lad[-1]),), 1,
-            ceil_div(p_pad * p_pad * (lad[-1] - lad[0]), lad[-1])),
-        PlanKindAudit(
-            "pan_batched", "local", True, "pan", "_pan_batched_plan",
-            (lad, B, Lb), (((B, Lb), f32), ((), i32)),
-            pan_pattern(p_pad, p_pad, B), per_rung, B,
-            B * pan_lanes(lad, p_pad, p_pad)),
-        PlanKindAudit(
-            "profile_mb", "mb", False, "mp", "_profile_mb_plan",
-            (s, Lb, B), (((B, Lb), f32), ((B,), i32)),
-            ((B * n_pad * n_pad, s),), (((0,), s),), B,
-            B * n_pad ** 2),
-        PlanKindAudit(
-            "tail_mb", "mb", False, "mp", "_tail_mb_plan",
-            (s, Lb, Qb, B), (((B, Lb), f32), ((B,), i32), ((B,), i32)),
-            ((B * Qb * n_pad, s),), (((0,), s),), B, B * Qb * n_pad),
-        PlanKindAudit(
-            "pan_mb", "mb", True, "pan", "_pan_mb_plan",
-            (lad, Lb, B), (((B, Lb), f32), ((B,), i32)),
-            pan_pattern(p_pad, p_pad, B), per_rung, B,
-            B * pan_lanes(lad, p_pad, p_pad)),
-        PlanKindAudit(
-            "pan_tail_mb", "mb", True, "pan", "_pan_tail_mb_plan",
-            (lad, Lb, Qb, B),
-            (((B, Lb), f32), ((B,), i32), ((B,), i32)),
-            pan_pattern(Qb, p_pad, B), per_rung, B,
-            B * int(sum(pan_rung_shares(lad, Qb, p_pad)))),
-        PlanKindAudit(
-            "ring", "ring", False, "ring", "_ring_plan",
-            (s, Lb), (((Lb,), f32), ((), i32)),
-            ((n_sh * per * ndev, s),), (((0,), s),), 1,
-            n_sh * per * ndev),
-        PlanKindAudit(
-            "batched_ring", "ring", False, "mp_ndev",
-            "_batched_sharded_plan",
-            (s, Bp, Lb), (((Bp, Lb), f32), ((1,), i32)),
-            ((Bp * n_pad * n_pad, s),), (((0,), s),), Bp,
-            Bp * n_pad ** 2),
-        PlanKindAudit(
-            "tail_ring", "ring", False, "mp_ndev", "_tail_sharded_plan",
-            (s, Lb, Qb), (((Lb,), f32), ((), i32), ((), i32)),
-            ((Qb * n_sh, s),), (((0,), s),), 1, Qb * n_sh),
-        PlanKindAudit(
-            "qsweep_ring", "ring", False, "qsweep_ndev",
-            "_qsweep_sharded_plan",
-            (s, Lb), (((Lb,), f32), ((), i32)),
-            ((q_sh * n_pad, s),), (((0,), s),), 1, q_sh * n_pad),
-        PlanKindAudit(
-            "pan_ring", "ring", True, "pan_ndev", "_pan_sharded_plan",
-            (lad, Lb), (((Lb,), f32), ((), i32)),
-            pan_pattern(nb_p * block, p_pad), per_rung, 1,
-            pan_lanes(lad, nb_p * block, p_pad)),
-        PlanKindAudit(
-            "pan_tail_ring", "ring", True, "pan_ndev",
-            "_pan_tail_sharded_plan",
-            (lad, Lb, Qb), (((Lb,), f32), ((), i32), ((), i32)),
-            pan_pattern(Qb, p_sh), per_rung, 1,
-            int(sum(pan_rung_shares(lad, Qb, p_sh)))),
-        PlanKindAudit(
-            "pan_batched_ring", "ring", True, "pan_ndev",
-            "_pan_batched_sharded_plan",
-            (lad, Bp, Lb), (((Bp, Lb), f32), ((1,), i32)),
-            pan_pattern(p_pad, p_pad, Bp), per_rung, Bp,
-            Bp * pan_lanes(lad, p_pad, p_pad)),
-    )
-    return OrderedDict((e.kind, e) for e in entries)
+    def pan_pattern(rows, cols):
+        return tuple((rows * cols, w) for w in widths)
+
+    # (geom, avals, pattern, groups, lanes): each op for one series,
+    # and the sharded kinds with a geometry of their own
+    facts = {
+        "profile": ((s, Lb), (x, n), ((n_pad * n_pad, s),), one,
+                    n_pad ** 2),
+        "tail": ((s, Lb, Qb), (x, n, n), ((Qb * n_pad, s),), one,
+                 Qb * n_pad),
+        "qsweep": ((s, Lb), (x, n), ((n_pad * n_pad, s),), one,
+                   n_pad ** 2),
+        "qsweep_refine": ((s, Lb), (x, pair, n),
+                          ((2 * block * n_pad, s),), one,
+                          2 * block * n_pad),
+        "qsweep_tail": ((s, Lb, Qb), (x, n, n), ((Qb * n_pad, s),),
+                        one, Qb * n_pad),
+        "qsweep_tail_refine": ((s, Lb, Qb), (x, n, n, pair),
+                               ((2 * Qb * block, s),), one,
+                               2 * Qb * block),
+        "pan": ((lad, Lb), (x, n), pan_pattern(p_pad, p_pad), per_rung,
+                pan_lanes(lad, p_pad, p_pad)),
+        "pan_tail": ((lad, Lb, Qb), (x, n, n), pan_pattern(Qb, p_pad),
+                     per_rung, int(sum(pan_rung_shares(lad, Qb, p_pad)))),
+        "pan_base": ((lad[0], Lb), (x, n), ((p_pad * p_pad, lad[0]),),
+                     (((0,), lad[0]),), p_pad ** 2),
+        "pan_step": ((lad, Lb, p_pad), (x, ((p_pad, p_pad), f32), n),
+                     tuple((p_pad * p_pad, w) for w in widths[1:]),
+                     # the LB schedule accounts one evaluated step as a
+                     # single extension at the step's final width
+                     # (docs/cps.md)
+                     ((tuple(range(R - 1)), lad[-1]),),
+                     ceil_div(p_pad * p_pad * (lad[-1] - lad[0]),
+                              lad[-1])),
+        "ring": ((s, Lb), (x, n), ((n_sh * per * ndev, s),), one,
+                 n_sh * per * ndev),
+        "tail_ring": ((s, Lb, Qb), (x, n, n), ((Qb * n_sh, s),), one,
+                      Qb * n_sh),
+        "qsweep_ring": ((s, Lb), (x, n), ((q_sh * n_pad, s),), one,
+                        q_sh * n_pad),
+        "pan_ring": ((lad, Lb), (x, n), pan_pattern(nb_p * block, p_pad),
+                     per_rung, pan_lanes(lad, nb_p * block, p_pad)),
+        "pan_tail_ring": ((lad, Lb, Qb), (x, n, n),
+                          pan_pattern(Qb, p_sh), per_rung,
+                          int(sum(pan_rung_shares(lad, Qb, p_sh)))),
+    }
+    out: "OrderedDict[str, PlanKindAudit]" = OrderedDict()
+    for kind, pk in PLAN_KINDS.items():
+        geom, avals, pattern, groups, lanes = facts.get(kind,
+                                                        facts[pk.op])
+        m = 1
+        if pk.layout == "mb":
+            m = B
+            geom = geom + (B,)
+            avals = tuple(((B,) + shape, dt) for shape, dt in avals)
+        elif pk.layout in ("batched", "series_sharded"):
+            m = B if pk.layout == "batched" else Bp
+            geom = (geom[0], m, geom[1])
+            avals = (((m,) + avals[0][0], f32),
+                     avals[1] if pk.layout == "batched" else ((1,), i32))
+        out[kind] = PlanKindAudit(
+            kind, geom, avals, tuple((m * c, w) for c, w in pattern),
+            groups, m, m * lanes)
+    return out

@@ -17,7 +17,7 @@ of concurrent tenants (the ROADMAP's "millions of users" shape):
       counters surface in :class:`ServeStats`;
     * **cross-stream micro-batching** — pending appends whose specs
       map to the same plan key are coalesced into one
-      ``("tail_mb"/"pan_tail_mb"/"profile_mb"/"pan_mb", B, ...)``
+      ``("tail_mb"/"pan_tail_mb"/"profile_mb"/"pan_mb", ..., B)``
       dispatch instead of ``B`` device round-trips.  Each lane runs
       the exact single-tenant plan body under ``lax.map``, so results
       are **bit-identical** to per-tenant sequential appends — the
@@ -335,20 +335,12 @@ class DiscordServer:
             np.int32))
         self._counters.coalesced += n
         self._counters.padded_lanes += pad
-        if kind == "fill":
-            return eng._profile_mb_plan(op0["s"], op0["Lb"], B)(stack,
-                                                                nv)
-        if kind == "pan_fill":
-            return eng._pan_mb_plan(op0["ladder"], op0["Lb"], B)(stack,
-                                                                 nv)
-        q0 = jnp.asarray(np.array(
-            [op["q0"] for _, op in chunk] + [op0["q0"]] * pad,
-            np.int32))
-        if kind == "tail":
-            return eng._tail_mb_plan(op0["s"], op0["Lb"], op0["Qb"],
-                                     B)(stack, q0, nv)
-        return eng._pan_tail_mb_plan(op0["ladder"], op0["Lb"],
-                                     op0["Qb"], B)(stack, q0, nv)
+        lanes = (nv,)
+        if kind in ("tail", "pan_tail"):
+            lanes = (jnp.asarray(np.array(
+                [op["q0"] for _, op in chunk] + [op0["q0"]] * pad,
+                np.int32)), nv)
+        return eng.plan(*self._group_geom(op0), B)(stack, *lanes)
 
     def _finish_group(self, chunk, out) -> None:
         """Response path: fold each lane's outputs into its tenant's

@@ -13,7 +13,7 @@
   4. SURFACE — importing ``repro.analysis`` and running the lint +
      static-speckey CLI never initializes jax; exit codes gate on
      findings; ``launch/discord.py --selfcheck`` is wired up.
-  5. IRLINT — ``plan_kind_registry`` covers every ``*_plan`` builder;
+  5. IRLINT — ``plan_kind_registry`` covers every ``PLAN_KINDS`` kind;
      the static lane/FLOP model equals the runtime formulas (all 23
      kinds, 1/2/4 devs) and the *executed* ``tile_lanes`` deltas
      (quantized kinds decompose into bound + refinement lanes); the
@@ -319,6 +319,27 @@ def test_static_audit_catches_nonliteral_key():
     assert any(f.rule == "plan-key-sites" for f in findings)
 
 
+def test_static_audit_catches_duplicate_plan_kind():
+    # a dict literal keeps the last of two equal keys: a second
+    # "profile" entry would silently replace the first
+    src = ENGINE_PATH.read_text()
+    line = '    "tail": _PlanKind("tail", "local"),\n'
+    assert line in src
+    broken = src.replace(line, line.replace('"tail"', '"profile"', 1))
+    findings = static_audit(engine_source=broken)
+    assert any(f.rule == "plan-key-sites" and "duplicate" in f.message
+               for f in findings)
+
+
+def test_static_audit_catches_key_without_mesh_shape():
+    src = ENGINE_PATH.read_text()
+    broken = src.replace("            key += ((ndev,),)\n", "")
+    assert broken != src
+    findings = static_audit(engine_source=broken)
+    assert any(f.rule == "plan-key-sites" and "mesh shape" in f.message
+               for f in findings)
+
+
 def test_runtime_audit_clean_on_repo():
     from repro.analysis.speckey import runtime_audit
     assert runtime_audit(backend="numpy") == []
@@ -473,34 +494,53 @@ def _fake_cell(fn, *, backend="pallas", znorm=True,
                avals=(((8,), "float32"),), const_bytes=None,
                **overrides):
     """Run _audit_cell on an arbitrary traced fn by grafting it onto
-    a registry entry (the builder is looked up on the 'engine')."""
+    a registry entry (the 'engine' plans it for every kind)."""
     import dataclasses
     from types import SimpleNamespace
 
     from repro.analysis.irlint import DEFAULT_CONST_BYTES, _audit_cell
     from repro.core.engine import plan_kind_registry
     entry = dataclasses.replace(
-        plan_kind_registry()["profile"], builder="fake_plan",
-        build_args=(), avals=tuple(avals), **overrides)
-    eng = SimpleNamespace(fake_plan=lambda: fn)
+        plan_kind_registry()["profile"], geom=(), avals=tuple(avals),
+        **overrides)
+    eng = SimpleNamespace(plan=lambda kind, *geom: fn)
     return _audit_cell(entry, eng, backend, znorm,
                        const_bytes=const_bytes or DEFAULT_CONST_BYTES)
 
 
 def test_plan_kind_registry_covers_every_builder():
+    # the registry is derived from the table: one entry per kind, in
+    # table order, each building through plan(); no builder method
+    # exists beside the table
     from repro.analysis.irlint import coverage_audit
-    from repro.core.engine import DiscordEngine, plan_kind_registry
+    from repro.core.engine import (PLAN_KINDS, DiscordEngine,
+                                   plan_kind_registry)
     reg = plan_kind_registry()
     assert len(reg) == 23
+    assert list(reg) == list(PLAN_KINDS)
     for kind in ("qsweep", "qsweep_refine", "qsweep_tail",
                  "qsweep_tail_refine", "qsweep_ring"):
         assert kind in reg, f"registry lost quantized kind {kind}"
-    builders = {n for n in dir(DiscordEngine)
+        assert PLAN_KINDS[kind].quant
+    assert not [n for n in dir(DiscordEngine)
                 if n.endswith("_plan") and n.startswith("_")
-                and not n.startswith(("_get", "_require"))
-                and callable(getattr(DiscordEngine, n))}
-    assert {e.builder for e in reg.values()} == builders
+                and n not in ("_get_plan", "_require_profile_plan")]
     assert coverage_audit() == []
+
+
+def test_coverage_audit_catches_unregistered_kind(monkeypatch):
+    # a table kind with no registry entry, and one whose layout has
+    # no method, are both coverage findings
+    from repro.analysis import irlint
+    from repro.core import engine
+    kinds = dict(engine.PLAN_KINDS,
+                 fake=engine._PlanKind("profile", "nowhere"))
+    monkeypatch.setattr(irlint, "PLAN_KINDS", kinds)
+    found = {(f.path, f.rule) for f in irlint.coverage_audit()}
+    assert found == {("core/engine.py::fake", "ir-kind-coverage")}
+    msgs = [f.message for f in irlint.coverage_audit()]
+    assert any("no plan_kind_registry entry" in m for m in msgs)
+    assert any("_layout_nowhere" in m for m in msgs)
 
 
 def test_lane_model_matches_runtime_formula_every_kind():

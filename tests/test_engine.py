@@ -15,6 +15,10 @@
   4. REPORTING — batched results carry the true per-batch wall clock
      and total tile-op counts; the deprecated wrappers warn and agree
      with the session API.
+  5. PLAN TABLE — every ``PLAN_KINDS`` kind builds through
+     ``DiscordEngine.plan`` under its pinned cache key and traces once;
+     each lane of a stacked kind equals its op's local plan bit for
+     bit.
 """
 import numpy as np
 import pytest
@@ -426,3 +430,126 @@ def test_monitor_wrapped_buffer_rebuild_is_capped():
     rep3 = mon.scan_metric("loss")
     assert mon.engine.stats.tile_lanes > lanes
     assert rep3.any_flagged
+
+
+# ---------------------------------------------------------------------
+# 5. PLAN TABLE
+# ---------------------------------------------------------------------
+#: every plan kind's cache key at the audit registry's geometry (s 24,
+#: ladder (16, 24, 32), block 32, length 90 -> bucket 256, Qb 32,
+#: B 2, one device), as its builder keyed it before the kinds came
+#: from one table
+PINNED_KEYS = {
+    "profile": ("profile", 24, 256),
+    "batched": ("batched", 24, 2, 256),
+    "tail": ("tail", 24, 256, 32),
+    "qsweep": ("qsweep", 24, 256),
+    "qsweep_refine": ("qsweep_refine", 24, 256),
+    "qsweep_tail": ("qsweep_tail", 24, 256, 32),
+    "qsweep_tail_refine": ("qsweep_tail_refine", 24, 256, 32),
+    "pan": ("pan", (16, 24, 32), 256),
+    "pan_tail": ("pan_tail", (16, 24, 32), 256, 32),
+    "pan_base": ("pan_base", 16, 256),
+    "pan_step": ("pan_step", (16, 24, 32), 256, 256),
+    "pan_batched": ("pan_batched", (16, 24, 32), 2, 256),
+    "profile_mb": ("profile_mb", 24, 256, 2),
+    "tail_mb": ("tail_mb", 24, 256, 32, 2),
+    "pan_mb": ("pan_mb", (16, 24, 32), 256, 2),
+    "pan_tail_mb": ("pan_tail_mb", (16, 24, 32), 256, 32, 2),
+    "ring": ("ring", 24, 256, (1,)),
+    "batched_ring": ("batched_ring", 24, 2, 256, (1,)),
+    "tail_ring": ("tail_ring", 24, 256, 32, (1,)),
+    "qsweep_ring": ("qsweep_ring", 24, 256, (1,)),
+    "pan_ring": ("pan_ring", (16, 24, 32), 256, (1,)),
+    "pan_tail_ring": ("pan_tail_ring", (16, 24, 32), 256, 32, (1,)),
+    "pan_batched_ring": ("pan_batched_ring", (16, 24, 32), 2, 256, (1,)),
+}
+
+
+def _kind_engine(kind, backend="xla"):
+    """A one-device engine of the spec family the IR audit gives
+    ``kind``."""
+    from repro.analysis.irlint import _Engines, _template
+    return _Engines(s=24, ladder=(16, 24, 32), block=32,
+                    ndev=1).get(_template(kind), backend, True)
+
+
+@pytest.mark.parametrize("kind", list(PINNED_KEYS))
+def test_plan_kind_builds_once_under_its_key(kind):
+    import jax
+
+    from repro.core.engine import PLAN_KINDS, plan_kind_registry
+    assert list(PINNED_KEYS) == list(PLAN_KINDS)
+    key = PINNED_KEYS[kind]
+    geom = key[1:-1] if PLAN_KINDS[kind].sharded else key[1:]
+    entry = plan_kind_registry(ndev=1)[kind]
+    assert entry.geom == geom
+    eng = _kind_engine(kind)
+    fn = eng.plan(kind, *geom)
+    assert list(eng._plans) == [eng._plan_key(key)]
+    avals = [jax.ShapeDtypeStruct(shape, np.dtype(dt))
+             for shape, dt in entry.avals]
+    jax.eval_shape(fn, *avals)
+    assert (eng.stats.plans, eng.stats.traces) == (1, 1)
+    assert eng.plan(kind, *geom) is fn          # same geometry: a hit
+    jax.eval_shape(fn, *avals)
+    assert (eng.stats.plans, eng.stats.traces) == (1, 1)
+    assert len(eng._plans) == 1
+
+
+#: stacked kinds -> (op geometry, base window, tail op)
+_LANE_OPS = {
+    "profile": ((24, 256), 24, False),
+    "tail": ((24, 256, 32), 24, True),
+    "pan": (((16, 24, 32), 256), 16, False),
+    "pan_tail": (((16, 24, 32), 256, 32), 16, True),
+}
+
+
+@pytest.mark.parametrize("kind,backend", [
+    ("profile_mb", "xla"), ("profile_mb", "pallas"), ("tail_mb", "xla"),
+    ("pan_mb", "xla"), ("pan_tail_mb", "xla"), ("batched", "xla"),
+    ("pan_batched", "xla"), ("batched_ring", "xla"),
+    ("pan_batched_ring", "xla")])
+def test_plan_kind_lanes_match_local_plan(kind, backend):
+    """Each lane of a stacked kind equals its op's local plan on that
+    lane's series: the ``mb`` lanes (``lax.map``) with their own valid
+    counts (and tail starts) bit for bit, the batched ones with the
+    shared count.  On ``xla`` the batched layouts vmap: the profile
+    lanes still match bit for bit, while the vmapped ladder sweep
+    batches its extension dots and may round its profile values a few
+    ulps apart (its neighbours stay equal)."""
+    import jax.numpy as jnp
+
+    from repro.core.engine import PLAN_KINDS, _bucket_pad
+    pk = PLAN_KINDS[kind]
+    geom, w0, tail = _LANE_OPS[pk.op]
+    mb = pk.layout == "mb"
+    rng = np.random.default_rng(11)
+    lengths = (90, 77, 128) if mb else (90, 90, 90)
+    xs = np.stack([_bucket_pad(np.sin(0.29 * np.arange(n))
+                               + 0.2 * rng.normal(size=n), 256)
+                   for n in lengths])
+    nv = [n - w0 + 1 for n in lengths]
+    q0 = [v - 20 for v in nv]
+    lanes = [(q, v) if tail else (v,) for q, v in zip(q0, nv)]
+    eng = _kind_engine(kind, backend)
+    if mb:
+        out = eng.plan(kind, *geom, 3)(
+            jnp.asarray(xs), *(jnp.asarray(c, jnp.int32)
+                               for c in zip(*lanes)))
+    else:
+        n_valid = (np.int32(nv[0]) if pk.layout == "batched"
+                   else jnp.full((1,), nv[0], jnp.int32))
+        out = eng.plan(kind, geom[0], 3, geom[1])(jnp.asarray(xs),
+                                                  n_valid)
+    local = eng.plan(pk.op, *geom)
+    vmapped_ladder = not mb and pk.op == "pan"
+    for b, args in enumerate(lanes):
+        want = local(jnp.asarray(xs[b]), *(np.int32(a) for a in args))
+        for i, (got_i, want_i) in enumerate(zip(out, want)):
+            got_i, want_i = np.asarray(got_i)[b], np.asarray(want_i)
+            if vmapped_ladder and i == 0:
+                np.testing.assert_allclose(got_i, want_i, rtol=1e-5)
+            else:
+                assert np.array_equal(got_i, want_i), (kind, b, i)

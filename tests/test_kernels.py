@@ -125,7 +125,7 @@ def test_mpblock_live_grid_matches_full_grid(n_valid, grid, monkeypatch):
                 s=MP_S, k=1, method="matrix_profile", backend="pallas",
                 block=MP_BLOCK))
             assert eng._profile_grid(MP_S, L) == grid
-            return eng._profile_mb_plan(MP_S, L, len(nvs))(
+            return eng.plan("profile_mb", MP_S, L, len(nvs))(
                 jnp.asarray(np.stack([x] * len(nvs)), jnp.float32),
                 jnp.asarray(nvs, jnp.int32))
 

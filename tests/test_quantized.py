@@ -111,10 +111,10 @@ def test_bound_pass_brackets_f32_refinement(backend, znorm, precision,
     n_pad = eng._n_pad(s, Lb)
     xp = jnp.asarray(_bucket_pad(np.asarray(x, np.float64), Lb))
     nv = np.int32(n_true)
-    lo, hi = eng._qsweep_plan(s, Lb)(xp, nv)
+    lo, hi = eng.plan("qsweep", s, Lb)(xp, nv)
     lo = np.asarray(lo, np.float64)
     hi = np.asarray(hi, np.float64)
-    rplan = eng._qsweep_refine_plan(s, Lb)
+    rplan = eng.plan("qsweep_refine", s, Lb)
     nb = n_pad // block
     d2 = np.empty(n_pad)
     for i in range(0, nb, 2):

@@ -53,7 +53,7 @@ for tag, block in (("aligned", 256), ("unaligned", 64)):
     xp = np.zeros(4096, np.float32)
     xp[:x.size] = x
     n = x.size - s + 1
-    d2_l, ngh_l = local._profile_plan(s, 4096)(xp, np.int32(n))
+    d2_l, ngh_l = local.plan("profile", s, 4096)(xp, np.int32(n))
     prof_l = np.sqrt(np.asarray(d2_l, np.float64)[:n])
     out[f"prof_close_{tag}"] = bool(np.allclose(prof_r, prof_l,
                                                 rtol=1e-4, atol=1e-4))

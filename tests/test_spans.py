@@ -115,8 +115,8 @@ def test_untraced_search_answers_as_before(traced, i):
     assert list(r.positions) == list(t.positions)
     assert list(r.nnds) == list(t.nnds)
     n, Lb = len(x) - S + 1, length_bucket(len(x))
-    d2, _ = eng._profile_plan(S, Lb)(jnp.asarray(_bucket_pad(x, Lb)),
-                                     np.int32(n))
+    d2, _ = eng.plan("profile", S, Lb)(jnp.asarray(_bucket_pad(x, Lb)),
+                                       np.int32(n))
     prof = np.sqrt(np.asarray(d2, np.float64)[:n])
     pos, vals = topk_nonoverlapping(
         np.where(np.isfinite(prof), prof, -np.inf), 2, S)
