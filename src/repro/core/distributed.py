@@ -5,13 +5,20 @@ module is the framework's beyond-paper contribution on Plane A.  Two
 sweeps, both exact:
 
 1. The ring matrix profile — the SCAMP-class full profile, distributed.
-   Every device owns one contiguous *query* block of windows and one
-   *candidate* block.  The candidate blocks travel around the ring with
-   ``lax.ppermute`` while each device folds the visiting block into its
-   queries' running (min, argmin).  After ``ndev`` hops every pair has
-   been examined exactly once.  This is DADD's disk-page model mapped to
-   a TPU pod: the "disk" is the other devices' HBM (DESIGN.md §7.5), and
-   the permute traffic overlaps with the local MXU tile work.
+   Every device owns one *query* shard of windows and one *candidate*
+   shard, the same window set: its window blocks are dealt to the
+   devices block-cyclically (device ``d`` holds blocks ``d, d + ndev,
+   ...``; ``TileEngine.window_set``), so a record's live windows spread
+   evenly over the ring, whatever its length in the bucket.  The
+   candidate shards travel around the ring with ``lax.ppermute`` while
+   each device folds the visiting shard into its queries' running (min,
+   argmin).  After ``ndev`` hops every pair has been examined exactly
+   once.  On ``pallas`` each hop sweeps only the shards' leading live
+   blocks, ``ceil(live blocks / ndev)`` a side on every device, so the
+   hops stay in step and the shard padding is never swept.  This is
+   DADD's disk-page model mapped to a TPU pod: the "disk" is the other
+   devices' HBM (DESIGN.md §7.5), and the permute traffic overlaps
+   with the local MXU tile work.
 
    Since the session fold-in (docs/ARCHITECTURE.md) the ring sweep is
    a first-class *plan kind* of :class:`repro.core.engine.DiscordEngine`
@@ -90,16 +97,19 @@ def _pack_blocks(series: np.ndarray, s: int, ndev: int):
 # 1) ring matrix profile
 # ----------------------------------------------------------------------
 def _ring_mp_shard(qbody, qmu, qsig, qid, s: int, n: int, ndev: int,
-                   backend: str, block: int):
+                   backend: str, block: int, hop_blocks):
     """Per-shard body: local queries fixed; candidates orbit the ring.
-    The arguments are the shard of a ``TileEngine.window_set``."""
+    The arguments are the shard of a ``TileEngine.window_set``;
+    ``hop_blocks`` (int32, may be traced) bounds each hop's grid to
+    the leading blocks of both shards on ``pallas``, and must cover
+    every live block of every shard."""
     q = (qbody, qmu, qsig, qid)
     perm = [(i, (i + 1) % ndev) for i in range(ndev)]
 
     def hop(carry, _):
         c, best, barg = carry
         tmin, targ = set_row_mins(q, c, s=s, n_valid=n, block=block,
-                                  backend=backend)
+                                  backend=backend, live=hop_blocks)
         take = tmin < best
         best = jnp.where(take, tmin, best)
         barg = jnp.where(take, targ, barg)

@@ -34,12 +34,13 @@ Mesh-sharded plan family (the ring fold-in, docs/ARCHITECTURE.md):
     ``(kind, s, length-bucket, mesh-shape)``.  The plan builds
     length-bucketed ``TileEngine`` window blocks, pads the window count
     so every per-device shard stays a multiple of ``spec.block``
-    (MXU-aligned), and runs the same ``ppermute`` hop body as the
-    standalone module under ``shard_map`` — so repeated sharded
-    searches hit zero new traces exactly like local ones.  Sharded
-    engines also route ``search_batched`` through a two-level layout
-    (series-parallel across devices; ring per series past
-    ``REPRO_RING_SERIES_THRESHOLD`` windows) and ``DiscordStream``
+    (MXU-aligned), deals the blocks to the devices block-cyclically so
+    a record's live windows spread evenly, and runs the same
+    ``ppermute`` hop body as the standalone module under ``shard_map``
+    — so repeated sharded searches hit zero new traces exactly like
+    local ones.  Sharded engines also route ``search_batched`` through
+    a two-level layout (series-parallel across devices; ring per series
+    past ``REPRO_RING_SERIES_THRESHOLD`` windows) and ``DiscordStream``
     appends through a sharded tail plan in which each device sweeps
     only its own candidate shard against the new tail windows and the
     per-shard minima are min-folded globally.
@@ -107,8 +108,10 @@ identifiers are stats: ``kind`` (``profile``, ``ring``, ``qsweep``,
 ``search`` (the ``stats.searches`` index the search takes), ``bucket``
 and ``n``; on the profile path ``grid`` too (``triangle`` or
 ``square``, ``DiscordEngine._profile_grid``), on the ring path
-``ndev``.  An entry that calls another nests a second
-``engine.search`` inside its own; readers take the outermost.
+``ndev`` and ``hop_blocks`` (the window blocks a side of each hop's
+grid, ``DiscordEngine._ring_hop_blocks``).  An entry that calls
+another nests a second ``engine.search`` inside its own; readers take
+the outermost.
 
 On the profile and ring paths the span holds, in order and without
 overlap:
@@ -156,7 +159,7 @@ from .pan import (PanEngine, canonical_ladder, cross_length_ub,
 from .result import DiscordResult, PanResult
 from .spec import SearchSpec, length_bucket
 from .tiles import (TileEngine, exact_pair_d2, self_join_grid,
-                    self_join_lanes, topk_nonoverlapping)
+                    self_join_lanes, topk_nonoverlapping, window_order)
 from .windows import sliding_stats
 
 __all__ = ["DiscordEngine", "DiscordStream", "PanStream", "EngineStats",
@@ -939,28 +942,39 @@ class DiscordEngine:
         return run
 
     def _layout_ring(self, op: str, geom: tuple, ndev: int):
-        """(series_pad (Lb,), n_valid) -> (d2 (n_sh,), neighbor).
+        """(series_pad (Lb,), n_valid) -> (d2 (n_sh,), neighbor), over
+        the window set dealt to the devices block-cyclically
+        (:func:`repro.core.tiles.window_order` puts a host copy back in
+        window order).
 
-        The ring matrix profile: every device owns one block-aligned
-        shard of query windows; candidate shards orbit the ring via
-        ``ppermute`` (the hop body shared with ``core/distributed``)
-        while each device min-folds the visiting shard into its
-        queries.  Masking is carried entirely by the window ids, so
-        one compiled plan serves every series in the bucket.
+        The ring matrix profile: every device builds its own shard of
+        the window set (blocks ``d, d + ndev, ...``) from the
+        replicated series and holds it as its queries; candidate
+        shards orbit the ring via ``ppermute`` (the hop body shared
+        with ``core/distributed``) while each device min-folds the
+        visiting shard into its queries.  Each hop's grid stops at the
+        shards' leading ``ceil(live blocks / ndev)`` blocks, the same
+        bound on every device (``pallas``).  Masking is carried
+        entirely by the window ids, so one compiled plan serves every
+        series in the bucket.
         """
-        from .distributed import _ring_mp_shard
+        from .distributed import AXIS, _ring_mp_shard
         self._require_znorm("the ring plan")
         s, Lb = geom
         _, per, n_sh = self._shard_geom(s, Lb, ndev)
-        sweep = self._shard(
-            functools.partial(_ring_mp_shard, s=s, n=n_sh, ndev=ndev,
-                              backend=self.backend,
-                              block=self.spec.block),
-            (0, 0, 0, 0), (0, 0))
+        hop = functools.partial(_ring_mp_shard, s=s, n=n_sh, ndev=ndev,
+                                backend=self.backend,
+                                block=self.spec.block)
+
+        def shard_body(series_pad, n_valid):
+            eng = self._tiles(series_pad, s, n_valid[0])
+            return hop(*eng.window_set(n_sh, ndev, lax.axis_index(AXIS)),
+                       hop_blocks=ceil_div(eng.live_blocks(), ndev))
+
+        sweep = self._shard(shard_body, (None, None), (0, 0))
 
         def run(series_pad, n_valid):
-            return sweep(*self._tiles(series_pad, s,
-                                      n_valid).window_set(n_sh))
+            return sweep(series_pad, jnp.full((1,), n_valid, jnp.int32))
         return run
 
     def _layout_tail_ring(self, op: str, geom: tuple, ndev: int):
@@ -1273,23 +1287,49 @@ class DiscordEngine:
                 runtime_s=time.perf_counter() - t0, tile_lanes=bl,
                 extra=extra)
 
+    def _ring_hop_blocks(self, s: int, Lb: int, n_valid: int,
+                         ndev: int) -> int:
+        """Window blocks a side of each hop's grid in the ring plan: on
+        ``pallas`` the shards' leading ``ceil(live / ndev)``, ``live``
+        the blocks that hold a window of the record (as the plan's
+        ``TileEngine.live_blocks``), elsewhere the whole shard."""
+        block = self.spec.block
+        if self.backend != "pallas":
+            return self._shard_geom(s, Lb, ndev)[1] // block
+        return ceil_div(ceil_div(int(n_valid), block), ndev)
+
+    def _ring_lanes(self, s: int, Lb: int, n_valid: int, ndev: int) -> int:
+        """The ring plan's swept lanes, the single source of the mesh
+        lane formula: ``ndev`` devices, ``ndev`` hops each, a square of
+        :meth:`_ring_hop_blocks` blocks a hop (Eq. 3 lanes)."""
+        side = self._ring_hop_blocks(s, Lb, n_valid, ndev) * self.spec.block
+        return ndev * ndev * side * side
+
     def _ring_exec(self, s: int, Lb: int, series_pad, n_valid):
-        """One ring-plan invocation — the single source of the mesh
-        lane formula (``per^2`` per device per hop, ``ndev`` hops,
-        ``ndev`` devices).  Returns ``(d2, arg, lanes, ndev)``; the
-        caller owns the stats fold."""
+        """One ring-plan invocation: ``(d2, arg, lanes, ndev)``, the
+        device arrays over the dealt window set (:meth:`_ring_fetch`
+        takes them to the host in window order); the caller owns the
+        stats fold."""
         ndev = int(self._resolve_mesh().devices.size)
         d2, arg = self.plan("ring", s, Lb)(series_pad, n_valid)
-        _, per, n_sh = self._shard_geom(s, Lb, ndev)
-        return d2, arg, n_sh * per * ndev, ndev
+        return d2, arg, self._ring_lanes(s, Lb, n_valid, ndev), ndev
+
+    def _ring_fetch(self, d2, arg, n_true: int):
+        """The ring plan's outputs on the host, the first ``n_true``
+        windows in window order: squared nnds (f64) and neighbours
+        (i64)."""
+        ndev = int(self._resolve_mesh().devices.size)
+        block = self.spec.block
+        return (window_order(d2, ndev, block, n_true).astype(np.float64),
+                window_order(arg, ndev, block, n_true).astype(np.int64))
 
     def _ring_d2(self, series, s: int, span=None):
         """Mesh-sharded exact squared nnd and neighbour of every true
         window, through the plan cache, in the ``engine.prepare`` to
         ``engine.fetch`` spans of the module docstring; ``span``, the
-        caller's ``engine.search``, is given ``bucket``, ``n`` and
-        ``ndev``.  Returns ``(d2, ngh, lanes, Lb, ndev, n_true)``, the
-        arrays on the host (f64 and i64)."""
+        caller's ``engine.search``, is given ``bucket``, ``n``,
+        ``ndev`` and ``hop_blocks``.  Returns ``(d2, ngh, lanes, Lb,
+        ndev, n_true)``, the arrays on the host (f64 and i64)."""
         with TraceAnnotation("engine.prepare"):
             x = np.asarray(series, np.float64).ravel()
             L = x.shape[0]
@@ -1304,12 +1344,13 @@ class DiscordEngine:
             d2, arg, lanes, ndev = self._ring_exec(s, Lb, jnp.asarray(xp),
                                                    np.int32(n_true))
         if span is not None:
-            span.set_metadata(bucket=Lb, n=n_true, ndev=ndev)
+            span.set_metadata(bucket=Lb, n=n_true, ndev=ndev,
+                              hop_blocks=self._ring_hop_blocks(
+                                  s, Lb, n_true, ndev))
         with TraceAnnotation("engine.wait"):
             jax.block_until_ready((d2, arg))
         with TraceAnnotation("engine.fetch"):
-            d2 = np.asarray(d2, np.float64)[:n_true]
-            ngh = np.asarray(arg, np.int64)[:n_true]
+            d2, ngh = self._ring_fetch(d2, arg, n_true)
         self.stats.tile_lanes += lanes
         return d2, ngh, lanes, Lb, ndev, n_true
 
@@ -2135,8 +2176,7 @@ class DiscordStream:
         ndev = eng.ndev if self._sharded else 1
         if n_old == 0:                # first fill: one full-profile plan
             if self._sharded:
-                _, per, n_sh = eng._shard_geom(s, Lb, ndev)
-                lanes = n_sh * per * ndev
+                lanes = eng._ring_lanes(s, Lb, n_new, ndev)
             else:
                 lanes = eng._profile_lanes(s, Lb, n_new)
             return {"kind": "fill", "s": s, "Lb": Lb, "xp": xp,
@@ -2189,8 +2229,11 @@ class DiscordStream:
         n_new = op["n_new"]
         if op["kind"] == "fill":
             d2, arg = out
-            self._d2 = np.asarray(d2, np.float64)[:n_new]
-            self._ngh = np.asarray(arg, np.int64)[:n_new]
+            if self._sharded:
+                self._d2, self._ngh = eng._ring_fetch(d2, arg, n_new)
+            else:
+                self._d2 = np.asarray(d2, np.float64)[:n_new]
+                self._ngh = np.asarray(arg, np.int64)[:n_new]
         elif op["kind"] == "qtail":   # quantized tail: bound + refine
             self._qtail_fold(op, out)
         else:                         # tail sweep only
@@ -2569,9 +2612,10 @@ class PlanKindAudit:
     accounting applies the ceil per series, then multiplies).
     ``lanes`` is the ``tile_lanes`` the runtime call site books for
     the same geometry on ``xla`` (``pallas`` books the live blocks of
-    the profile kinds, ``_profile_lanes``); ``repro.analysis.irlint``
-    asserts the traced IR reproduces ``pattern`` exactly and that
-    ``model_lanes()`` of the traced dots equals ``lanes``.
+    the profile kinds, ``_profile_lanes``, and of the ring's hops,
+    ``_ring_lanes``); ``repro.analysis.irlint`` asserts the traced IR
+    reproduces ``pattern`` exactly and that ``model_lanes()`` of the
+    traced dots equals ``lanes``.
     """
     kind: str
     geom: tuple          # DiscordEngine.plan geometry

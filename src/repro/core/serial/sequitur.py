@@ -158,6 +158,12 @@ class Grammar:
         self._remove(a)
         self._remove(b)
         self._insert_after(left, nt)
+        # an overlapping run (xxx) indexes one of its two digrams; if
+        # that one went, the survivor beside the edit takes its place
+        for s in (left.prev, nt.next):
+            k = self._digram_key(s)
+            if k is not None:
+                self.digrams.setdefault(k, s)
         if not left.is_guard:
             self._check_digram(left)
         if not nt.next.is_guard and self._digram_key(nt) is not None:
@@ -177,6 +183,10 @@ class Grammar:
         for s in rule.symbols():
             c = _Symbol(value=s.value, rule=s.rule)
             self._insert_after(prev, c)
+            if prev is not left:
+                # the rule's own digrams move with its symbols: index
+                # them at their new place, or a later repeat is missed
+                self.digrams[self._digram_key(prev)] = prev
             prev = c
         if not left.is_guard:
             self._check_digram(left)

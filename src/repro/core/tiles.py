@@ -36,7 +36,8 @@ from ..kernels.registry import (available_backends, get_backend,
 
 __all__ = [
     "TileBlock", "TileMins", "TileEngine", "tile_d2", "tile_mins",
-    "set_row_mins", "pair_d2", "exact_pair_d2", "topk_nonoverlapping",
+    "set_row_mins", "pair_d2", "exact_pair_d2", "window_order",
+    "topk_nonoverlapping",
     "batched_profile", "self_join_grid", "self_join_lanes",
     "resolve_backend", "available_backends", "register_backend",
 ]
@@ -76,13 +77,20 @@ def tile_mins(d2: jnp.ndarray, qids, cids) -> TileMins:
 
 
 def set_row_mins(q: tuple, c: tuple, *, s: int, n_valid, block: int,
-                 backend: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                 backend: str, live=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Row (min d2, neighbor id) of window set ``q`` against window set
-    ``c``, both slices of :meth:`TileEngine.window_set` built on the
-    (resolved) ``backend``.  Eq. (3) distances only."""
+    ``c``, both shards of :meth:`TileEngine.window_set` built on the
+    (resolved) ``backend``.  Eq. (3) distances only.
+
+    ``live`` (int32, may be traced; None = every block) bounds the
+    ``pallas`` grid to the leading ``live`` blocks of each side, the
+    square ``mp_block_pallas`` sweeps; the blocks past it must hold
+    padding ids only, and their rows come back (+inf, 0).  Other
+    backends sweep every block."""
     if backend == "pallas":
         from ..kernels.mpblock.kernel import mp_block_pallas
         return mp_block_pallas(*q, *c, s=s, n_valid=n_valid, block=block,
+                               nq=live, nc=live,
                                interpret=default_interpret())
     m = tile_mins(tile_d2(TileBlock(*q), TileBlock(*c), s=s,
                           n_valid=n_valid, backend=backend), q[3], c[3])
@@ -142,6 +150,16 @@ def exact_pair_d2(wa, wb) -> np.ndarray:
     wa = np.asarray(wa, np.float64)
     wb = np.asarray(wb, np.float64)
     return np.sum((wa - wb) ** 2, axis=1)
+
+
+def window_order(a, ndev: int, block: int, n: int) -> np.ndarray:
+    """The first ``n`` windows' entries, in window order, of a host
+    array over a window set dealt to ``ndev`` shards
+    (:meth:`TileEngine.window_set`): only the shards' leading blocks
+    that hold those windows are copied."""
+    a = np.asarray(a).reshape(ndev, -1, block)
+    lead = ceil_div(ceil_div(n, block), ndev)
+    return a[:, :lead].swapaxes(0, 1).reshape(-1)[:n]
 
 
 def topk_nonoverlapping(profile: np.ndarray, k: int, s: int
@@ -304,25 +322,43 @@ class TileEngine:
                          self._mask_ids(jnp.arange(n_pad,
                                                    dtype=jnp.int32)))
 
-    def window_set(self, pad_to: int) -> tuple:
-        """Every window as a ``(body, mu, sig, ids)`` set for
+    def window_set(self, pad_to: int, ndev: int = 1, shard=0) -> tuple:
+        """Shard ``shard`` (an int32 index, may be traced) of every
+        window as a ``(body, mu, sig, ids)`` set for
         :func:`set_row_mins`, padded with masked lanes (ids -1) to
-        ``pad_to`` windows, a multiple of ``block`` (ring shards split
-        it evenly).  ``body`` is the per-block series chunks of
-        :meth:`block_chunks` on ``pallas`` and the materialized window
-        rows elsewhere; callers only pass the set on."""
-        n_pad = self.mu_pad.shape[0]
-        pad = pad_to - n_pad
+        ``pad_to`` windows, a multiple of ``ndev * block``, and dealt
+        to ``ndev`` ring shards block-cyclically: shard ``d`` holds the
+        ``pad_to / ndev`` windows of blocks ``d, d + ndev, d + 2 ndev,
+        ...`` in increasing order, so the record's live blocks lead
+        every shard and shards differ by at most one live block (with
+        ``ndev`` 1, the whole set in window order).  Ids stay global;
+        host arrays over the shards, one after another, go back to
+        window order by :func:`window_order`.  ``body`` is the
+        per-block series chunks of :meth:`block_chunks` on ``pallas``
+        and the materialized window rows elsewhere; callers only pass
+        the set on."""
+        blk = self.block
+        g = shard + jnp.arange(pad_to // (ndev * blk), dtype=jnp.int32) * ndev
+        live = g < self.nb
+        g = jnp.minimum(g, self.nb - 1)
+        wid = (g[:, None] * blk + jnp.arange(blk, dtype=jnp.int32)).ravel()
+        inb = jnp.repeat(live, blk)
+
+        def rows(v, fill):
+            return jnp.where(live[:, None], v.reshape(self.nb, blk)[g],
+                             fill).ravel()
         if self.backend == "pallas":
-            body = jnp.pad(self.block_chunks(),
-                           ((0, pad // self.block), (0, 0)))
+            from ..kernels.mpblock.kernel import block_chunks
+            body = jnp.where(live[:, None],
+                             block_chunks(self.series_pad, self.nb, blk,
+                                          self.s, rows=g), 0.0)
         else:
-            body = jnp.pad(self.all_windows().win, ((0, pad), (0, 0)))
-        ids = self._mask_ids(jnp.arange(n_pad, dtype=jnp.int32))
-        return (body,
-                jnp.pad(self.mu_pad, (0, pad)),
-                jnp.pad(self.sig_pad, (0, pad), constant_values=1.0),
-                jnp.pad(ids, (0, pad), constant_values=-1))
+            body = jnp.where(inb[:, None],
+                             self.series_pad[wid[:, None]
+                                             + jnp.arange(self.s)[None, :]],
+                             0.0)
+        return (body, rows(self.mu_pad, 0.0), rows(self.sig_pad, 1.0),
+                jnp.where(inb, self._mask_ids(wid), jnp.int32(-1)))
 
     # -- tile ops ------------------------------------------------------
     def d2(self, q: TileBlock, c: TileBlock,

@@ -22,7 +22,10 @@ past them is wholly padding (ids -1 or >= n_valid) and could only
 fold +inf into a row, never take, so the live rows are the same bit
 for bit; the query rows past ``nq`` come back (+inf, 0), as a padding
 row of the full grid does.  The ring's hops, which join two different
-window sets, run it.
+window sets, run it: the ring deals the window blocks to its shards
+block-cyclically (``TileEngine.window_set``), so every shard's live
+blocks lead it, and each hop's grid stops at the same live count on
+every chip, ``ceil(live blocks / chips)``.
 
 The self-join uses d(a, b) = d(b, a): ``mp_tri_pallas`` sweeps the
 upper triangle of tiles in ascending rows, each pair once, and keeps a
@@ -78,14 +81,17 @@ def reversed_chunk(seg, width: int):
     return pad_to(seg, width)[:width][::-1]
 
 
-def block_chunks(series, nb: int, block: int, s: int):
+def block_chunks(series, nb: int, block: int, s: int, rows=None):
     """(nb, W) reversed chunks of ``nb`` consecutive window blocks:
     row ``b`` is ``series[b*block : b*block + W]`` reversed, with zeros
-    past the end of ``series``."""
+    past the end of ``series``.  ``rows`` (int32 block indices below
+    ``nb``, may be traced) picks the chunks of those blocks alone, in
+    its order."""
     width = chunk_width(block, lane_pad(s))
     x = jnp.pad(series, (0, max(0, nb * block + width - series.shape[0])))
-    idx = (jnp.arange(nb)[:, None] * block
-           + jnp.arange(width - 1, -1, -1)[None, :])
+    if rows is None:
+        rows = jnp.arange(nb)
+    idx = rows[:, None] * block + jnp.arange(width - 1, -1, -1)[None, :]
     return x[idx]
 
 

@@ -7,10 +7,10 @@ ring-per-series layout are profiled, and the ``.xplane.pb`` is read
 back with ``jax.profiler.ProfileData``.  Each ring search must show one
 ``engine.search`` (``kind`` ring) with the five phases of the profile
 path nested inside it in order and without overlap, and the stats
-``search``, ``bucket``, ``n`` and ``ndev``; the batched entry nests
-its ring searches inside its own span.  Outside a trace the same
-searches answer exactly as the ring plan called directly does, with
-the same ``EngineStats``.
+``search``, ``bucket``, ``n``, ``ndev`` and ``hop_blocks``; the
+batched entry nests its ring searches inside its own span.  Outside a
+trace the same searches answer exactly as the ring plan called
+directly does, with the same ``EngineStats``.
 """
 import json
 
@@ -104,7 +104,7 @@ for x in xs:
     n, Lb = len(x) - S + 1, length_bucket(len(x))
     d2, arg, ln, _ = eng3._ring_exec(S, Lb, jnp.asarray(_bucket_pad(x, Lb)),
                                      np.int32(n))
-    prof = np.sqrt(np.asarray(d2, np.float64)[:n])
+    prof = np.sqrt(eng3._ring_fetch(d2, arg, n)[0])
     pos, vals = topk_nonoverlapping(
         np.where(np.isfinite(prof), prof, -np.inf), 2, S)
     direct.append([list(map(int, pos)), list(map(float, vals))])
@@ -171,6 +171,8 @@ def test_search_span_stats(result, i):
     assert stats["n"] == LENGTHS[i] - S + 1
     assert stats["bucket"] == 2048
     assert stats["ndev"] == 4
+    # on xla each hop sweeps the whole shard: 8 blocks of 256 over 4
+    assert stats["hop_blocks"] == 2
 
 
 def test_batched_ring_per_series_nests(result):

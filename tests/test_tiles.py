@@ -22,7 +22,7 @@ from repro.core import find_discords, find_discords_batched
 from repro.core.hst_jax import NND_INIT, _scatter_min
 from repro.core.tiles import (TileEngine, available_backends, pair_d2,
                               resolve_backend, set_row_mins, tile_d2,
-                              tile_mins, topk_nonoverlapping)
+                              tile_mins, topk_nonoverlapping, window_order)
 
 BACKENDS = ("numpy", "xla", "pallas")
 
@@ -173,39 +173,90 @@ def test_profile_backend_matches_brute(backend):
     assert np.all(np.abs(arg - np.arange(eng.n)) >= s)
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
-def test_window_set_shards_fold_to_profile(backend):
-    """The ring's shape: halves of a padded ``window_set`` (chunks on
-    pallas, window rows elsewhere) swept against each other with
-    ``set_row_mins`` and min-folded give the exact profile, and each
-    neighbor realizes its distance."""
-    from repro.core.serial.brute import exact_nnd_profile
-    x = _series(5, 450)
-    s, block = 24, 128
-    n = len(x) - s + 1
-    eng = TileEngine(x, s, block=block, backend=backend, n_valid=n)
-    n_sh = 6 * block                   # 4 tile blocks padded to 2 x 3
-    ws = eng.window_set(n_sh)
-    shards = [tuple(a[:a.shape[0] // 2] for a in ws),
-              tuple(a[a.shape[0] // 2:] for a in ws)]
+def _fold_window_set(shards, *, s, n_sh, block, backend, live=None):
+    """Each shard's rows min-folded over every shard, as the ring does
+    over its hops."""
     d2, arg = [], []
     for q in shards:
-        (da, aa), (db, ab) = (
-            set_row_mins(q, c, s=s, n_valid=n_sh, block=block,
-                         backend=eng.backend) for c in shards)
-        take = db < da
-        d2.append(np.where(take, db, da))
-        arg.append(np.where(take, ab, aa))
-    d = np.sqrt(np.concatenate(d2)[:n])
-    arg = np.concatenate(arg)[:n]
+        best, barg = None, None
+        for c in shards:
+            tm, ta = (np.asarray(v) for v in set_row_mins(
+                q, c, s=s, n_valid=n_sh, block=block, backend=backend,
+                live=live))
+            if best is None:
+                best, barg = tm, ta
+            else:
+                take = tm < best
+                best, barg = np.where(take, tm, best), np.where(take, ta,
+                                                                barg)
+        d2.append(best)
+        arg.append(barg)
+    return np.concatenate(d2), np.concatenate(arg)
+
+
+def _assert_profile(x, s, d, arg):
+    """``d`` is the exact nnd profile, each neighbor realizes it and
+    sits at least ``s`` away."""
+    from repro.core.serial.brute import exact_nnd_profile
     xf = np.asarray(x, np.float64)
     prof = exact_nnd_profile(xf, s)
+    n = prof.shape[0]
     assert np.allclose(d, prof, atol=2e-3)
     assert np.all(np.abs(arg - np.arange(n)) >= s)
     w = np.lib.stride_tricks.sliding_window_view(xf, s)
     z = (w - w.mean(1, keepdims=True)) / w.std(1, keepdims=True)
     assert np.allclose(np.linalg.norm(z - z[arg], axis=1), prof,
                        atol=2e-3)
+
+
+#: dealt window sets: series lengths inside one 420-point pad (13 tile
+#: blocks of 32, dealt to 4 shards of 4), whose live blocks are 12, 9,
+#: 10, 11 (0-3 mod 4) and 3, fewer than the shards
+DEALT_LENGTHS = (393, 303, 323, 368, 113)
+
+
+@pytest.mark.parametrize("backend,length", [
+    *(pytest.param(b, None, id=b) for b in BACKENDS),
+    *(pytest.param(b, L, id=f"{b}-dealt-{L}") for b in BACKENDS
+      for L in DEALT_LENGTHS)])
+def test_window_set_shards_fold_to_profile(backend, length):
+    """The ring's shape: shards of a padded ``window_set`` (chunks on
+    pallas, window rows elsewhere) swept against each other with
+    ``set_row_mins`` and min-folded give the exact profile, and each
+    neighbor realizes its distance.  Halves of the set in window order
+    with the whole grid; or the set dealt to 4 shards block-cyclically
+    with each grid bounded to ``ceil(live / 4)`` blocks, which must
+    cover every live block of every shard, and the profile taken back
+    to window order."""
+    s = 24
+    if length is None:
+        x = _series(5, 450)
+        block = 128
+        n = len(x) - s + 1
+        eng = TileEngine(x, s, block=block, backend=backend, n_valid=n)
+        n_sh = 6 * block                   # 4 tile blocks padded to 2 x 3
+        ws = eng.window_set(n_sh)
+        shards = [tuple(a[:a.shape[0] // 2] for a in ws),
+                  tuple(a[a.shape[0] // 2:] for a in ws)]
+        d2, arg = _fold_window_set(shards, s=s, n_sh=n_sh, block=block,
+                                   backend=eng.backend)
+        _assert_profile(x, s, np.sqrt(d2[:n]), arg[:n])
+        return
+    ndev, block = 4, 32
+    x = _series(5, 450)[:length]
+    n = length - s + 1
+    eng = TileEngine(np.pad(x, (0, 420 - length)), s, block=block,
+                     backend=backend, n_valid=n)
+    n_sh = 16 * block
+    shards = [eng.window_set(n_sh, ndev, jnp.int32(d))
+              for d in range(ndev)]
+    assert all(sh[3].shape == (n_sh // ndev,) for sh in shards)
+    hop = -(-int(eng.live_blocks()) // ndev)
+    d2, arg = _fold_window_set(shards, s=s, n_sh=n_sh, block=block,
+                               backend=eng.backend,
+                               live=jnp.int32(hop))
+    _assert_profile(x, s, np.sqrt(window_order(d2, ndev, block, n)),
+                    window_order(arg, ndev, block, n))
 
 
 @pytest.mark.parametrize("backend", BACKENDS)

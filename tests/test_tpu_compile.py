@@ -7,7 +7,8 @@ too much VMEM.  These tests compile each main-path kernel with
 ``interpret=False`` against a ``v5e:2x2`` topology description — the
 TPU compiler ships with jaxlib, no chip is needed — at the length
 bucket of a 120k-point recording (n_pad = 2^17), window s = 300
-(384 MXU lanes) and block 256.
+(384 MXU lanes) and block 256; and the ring plan whole over the
+topology's four chips, at the same sizes.
 
 The topology is described inside a fixture, never at import time, so
 every pytest-xdist worker collects the same tests and only the worker
@@ -25,11 +26,11 @@ BLOCK = 256
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The described topology's four devices."""
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -40,9 +41,15 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _kernel_case(name):
@@ -126,3 +133,34 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     found = [ln for ln in text.splitlines()
              if KERNEL.search(ln.strip().removeprefix("ROOT "))]
     assert len(found) == (name in ROW_PAIR_KERNELS), found
+
+
+def test_ring_plan_hop_kernel_compiles_for_v5e(v5e_2x2, monkeypatch):
+    """The ring plan over the four chips, as a search runs it: each
+    chip builds its dealt shard, and ``mp_block`` runs once a hop with
+    the traced live bound ``(nq, nc)`` inside ``shard_map`` and
+    ``lax.scan``.  Its custom call keeps the result pair that
+    ``mpblock_roofline`` finds, beside the hop's collective-permute."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from bench.metrics.mpblock_roofline import KERNEL
+    from repro.core import DiscordEngine, SearchSpec, tiles
+    from repro.parallel.sharding import SERIES_AXIS
+
+    monkeypatch.setattr(tiles, "default_interpret", lambda: False)
+    mesh = Mesh(np.array(v5e_2x2), (SERIES_AXIS,))
+    eng = DiscordEngine(SearchSpec(s=S, method="ring", backend="pallas",
+                                   block=BLOCK), mesh=mesh)
+    rep = NamedSharding(mesh, P())
+    plan = eng.plan("ring", S, N_PAD)
+    text = plan.lower(
+        jax.ShapeDtypeStruct((N_PAD,), jnp.float32, sharding=rep),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    ).compile().as_text()
+    found = [ln for ln in text.splitlines()
+             if KERNEL.search(ln.strip().removeprefix("ROOT "))]
+    assert len(found) == 1, found
+    assert "collective-permute" in text
